@@ -6,19 +6,22 @@
 #   bin/run-pipeline.sh --list
 #
 # Environment knobs (all optional):
-#   KEYSTONE_PLATFORM   jax platform to force (e.g. "cpu" for the virtual
-#                       device path; default: whatever the env provides)
+#   JAX_PLATFORMS       jax's own platform switch (e.g. "cpu" for the
+#                       virtual device path; default: the TPU when one
+#                       is attached)
 #   KEYSTONE_NUM_CPU_DEVICES
-#                       with KEYSTONE_PLATFORM=cpu, number of virtual host
+#                       with JAX_PLATFORMS=cpu, number of virtual host
 #                       devices to expose (the LocalSparkContext analogue)
 #   KEYSTONE_MEM        fraction of HBM jax may preallocate, e.g. "0.8".
 #                       NOTE: plays the role of the reference's
 #                       executor-memory knob but takes a fraction in
 #                       (0,1], NOT a JVM size like "4g"
+#   JAX_COMPILATION_CACHE_DIR
+#                       persistent XLA compile-cache dir (default:
+#                       .jax_cache/ in the checkout) — repeat runs of a
+#                       pipeline skip compilation
 #   KEYSTONE_COMPILE_CACHE
-#                       persistent XLA compile-cache dir (default
-#                       ~/.cache/keystone_tpu/xla; "off" disables) —
-#                       repeat runs of a pipeline skip compilation
+#                       "off" disables the persistent compile cache
 #   KEYSTONE_STATE_DIR  saved-pipeline-state dir: materialized prefixes
 #                       persisted by save_pipeline_state are reloaded
 #                       instead of recomputed (SavedStateLoadRule)
@@ -27,9 +30,6 @@ set -euo pipefail
 REPO_ROOT="$(cd "$(dirname "${BASH_SOURCE[0]}")/.." && pwd)"
 export PYTHONPATH="${REPO_ROOT}${PYTHONPATH:+:${PYTHONPATH}}"
 
-if [[ -n "${KEYSTONE_PLATFORM:-}" ]]; then
-  export JAX_PLATFORMS="${KEYSTONE_PLATFORM}"
-fi
 if [[ -n "${KEYSTONE_NUM_CPU_DEVICES:-}" ]]; then
   export XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=${KEYSTONE_NUM_CPU_DEVICES}"
 fi

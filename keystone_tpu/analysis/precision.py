@@ -39,12 +39,10 @@ MODES = ("f32", "bf16", "bf16_apply")
 
 
 def _jaxpr_types():
-    """(ClosedJaxpr, Jaxpr) types without reaching into private jax
-    modules (layout moved across jax versions)."""
-    import jax
+    """(ClosedJaxpr, Jaxpr) — the public ``jax.extend.core`` types."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
-    closed = jax.make_jaxpr(lambda: 0)()
-    return type(closed), type(closed.jaxpr)
+    return ClosedJaxpr, Jaxpr
 
 
 def _iter_eqns(jaxpr, closed_t, jaxpr_t):

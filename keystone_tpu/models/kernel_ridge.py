@@ -120,7 +120,7 @@ class KernelRidgeRegressionEstimator(LabelEstimator):
     kernel column blocks (KernelMatrix.scala § BlockKernelMatrix): the
     fit sweeps through a BlockKernelMatrix LRU, so epochs ≥ 2 reread
     cached blocks (n² HBM) instead of recomputing the ‖x−z‖² gemms.
-    Measured on v5 lite (BASELINE.md "KRR kernel-block cache"): the
+    Measured on v5 lite (rounds 1–5, not re-measured): the
     recompute sweep wins below d≈2·10³ (~4× at d=64, ~1.3× at d=1024) —
     the MXU regenerates blocks faster than HBM rereads them while the
     gemm is small — so recompute stays the default; caching wins for

@@ -88,7 +88,7 @@ class LinearMapEstimator(LabelEstimator):
           objective (1/(2n)‖XW−Y‖² + λ/2‖W‖² ⇒ (XᵀX+λnI)W = XᵀY).  An
           intercept survives the swap (unregularized constant column).
         - size: when the FULL problem is small (n·d below the measured
-          crossover — BASELINE.md "Local vs distributed solve"), pick
+          crossover — rounds 1–5, not re-measured), pick
           :class:`LocalLeastSquaresEstimator`, the unsharded
           single-device solve with no collectives and no mesh padding
           (the reference's collect()+LAPACK path for small data)."""
@@ -250,7 +250,7 @@ LeastSquaresEstimator = LinearMapEstimator
 
 
 #: n·d crossover below which the unsharded local solve beats the sharded
-#: normal-equations path.  Measured on an 8-device mesh (BASELINE.md
+#: normal-equations path.  Measured on an 8-device mesh (rounds 1–5, not re-measured
 #: "Local vs distributed solve"): local wins through n·d = 2²⁰
 #: (4096×256: 49 ms vs 52 ms, and 2.7× at 256×64), the sharded path wins
 #: from n·d = 2²³ up (2.2× at 16384×512); the boundary sits between.

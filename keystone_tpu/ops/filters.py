@@ -52,7 +52,7 @@ def _blur_matrix(extent: int, sigma: float, truncate: float = 3.0) -> np.ndarray
 #: image extent above which the banded-matmul blur falls back to the
 #: conv form: the dense (extent, extent) operator makes the matmul pass
 #: O(extent³) per axis vs the conv's O(k·extent²), and the measured win
-#: (BASELINE.md r4) is at 128 px where the conv emitter's fixed costs
+#: (rounds 1–5, not re-measured) is at 128 px where the conv emitter's fixed costs
 #: dominate.  512 px keeps the matmul pass within ~4 GF/axis/image —
 #: still cheap MXU work — while callers on larger maps (e.g. DAISY on
 #: full-resolution inputs) keep the byte-bound conv (ADVICE r4).

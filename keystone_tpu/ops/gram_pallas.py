@@ -22,7 +22,11 @@ tiles stream from HBM at half width (a bandwidth lever — the row norms
 and all VMEM compute stay f32).  The SOLVER path always streams f32
 (``mxu='f32'``): kernel values feed block Cholesky solves, and the
 precision contract (analysis/precision.py) keeps solver math
-solver-grade under every ``KEYSTONE_MATMUL`` mode.
+solver-grade under every ``KEYSTONE_MATMUL`` mode — f32 tiles are
+multiplied at ``Precision.HIGHEST`` (``_tile_precision``), as the XLA
+chain's ``sdot`` is; at the MXU's default the f32 operands were rounded
+to bf16 first and entries sat 1.0e-4..3.3e-4 off at d=2048 (my chip
+runs, PR 21).
 
 ``gram_block`` is the dispatcher: Pallas on TPU backends
 (``pallas_supported()``, ``KEYSTONE_GRAM_PALLAS=0`` escape hatch), and
@@ -38,8 +42,9 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from keystone_tpu.ops.fisher_pallas import _compiler_params, pallas_supported
+from keystone_tpu.ops.fisher_pallas import pallas_supported
 
 
 def _precision():
@@ -48,14 +53,38 @@ def _precision():
     return precision
 
 
-#: VMEM bytes budgeted per program: two (tile, d) operand tiles plus ~3
-#: (tile_n, tile_m) f32 intermediates (cross, sq, out) live at once.
-_VMEM_BUDGET = 12 << 20
+#: VMEM bytes budgeted per program, under Mosaic's 16 MiB scoped limit
+#: on the v5e (the compiler refuses a kernel whose buffers exceed it).
+_VMEM_BUDGET = 15 << 20
+_MIN_TILE = 128
 
-#: features per row above which the untiled-d operand tiles cannot fit
-#: VMEM even at the 128-row floor — the dispatcher falls back to the
-#: XLA chain rather than asking Mosaic for the impossible.
-GRAM_MAX_D = 8192
+
+def _tile_vmem_bytes(tile: int, d: int) -> int:
+    """What one grid step keeps in VMEM at square ``tile``-row tiles:
+    the pipeline double-buffers BOTH (tile, d) operand tiles and the
+    (tile, tile) f32 output, plus ~3 (tile, tile) f32 intermediates
+    (cross, sq, exp).  Operands are counted at f32 width — a bf16
+    stream halves the buffers but adds the f32 upcast copies, the same
+    total.  ``d`` is lane-padded to 128 as Mosaic lays it out.
+
+    Above the 128-row floor the ``HIGHEST`` multiply keeps split copies
+    of both operand tiles, as much again as the buffers themselves: the
+    v5e compiler's scoped-VMEM need grows by 32·tile bytes per feature
+    at 256 and 512 rows and by 16·tile at 128 (read off its refusals:
+    256 rows passes d=1792 and needs 16.77M at 2048; 512 passes 768 and
+    needs 17.02M at 896; 128 passes 7552 and needs 16.12M at 8192)."""
+    d_pad = -(-d // 128) * 128
+    operand_copies = 2 * 2 if tile <= _MIN_TILE else 2 * 2 * 2
+    return 4 * (operand_copies * tile * d_pad + 2 * tile * tile + 3 * tile * tile)
+
+
+#: features per row up to which the untiled-d operand tiles fit VMEM at
+#: the 128-row floor (compiled for the v5e at exactly this width in
+#: tests/test_tpu_compile.py) — above it the dispatcher takes the XLA
+#: chain rather than asking Mosaic for the impossible.
+GRAM_MAX_D = (
+    (_VMEM_BUDGET - _tile_vmem_bytes(_MIN_TILE, 0)) // (16 * _MIN_TILE) // 128 * 128
+)
 
 
 def _gram_tile(n: int, d: int) -> int:
@@ -63,11 +92,18 @@ def _gram_tile(n: int, d: int) -> int:
     round to a sublane multiple (8); tiled inputs use a 128-multiple so
     the lane-dim layouts stay native."""
     cap = 512
-    while cap > 128 and 4 * (2 * cap * d + 3 * cap * cap) > _VMEM_BUDGET:
+    while cap > _MIN_TILE and _tile_vmem_bytes(cap, d) > _VMEM_BUDGET:
         cap //= 2
     if n <= cap:
         return -(-n // 8) * 8
     return cap
+
+
+def _tile_precision(ref):
+    """f32 tiles are the solver stream: multiply them at true f32 (the
+    MXU's default rounds f32 operands to bf16 first).  bf16 tiles are
+    exact in one pass already."""
+    return jax.lax.Precision.HIGHEST if ref.dtype == jnp.float32 else None
 
 
 def _gram_kernel(x_ref, z_ref, out_ref, *, gamma: float):
@@ -79,7 +115,8 @@ def _gram_kernel(x_ref, z_ref, out_ref, *, gamma: float):
     zn = jnp.sum(z * z, axis=1)[None, :]  # (1, TM)
     # contract d without materializing zᵀ (dot_general, f32 accumulation)
     cross = jax.lax.dot_general(
-        x, z, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, z, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=_tile_precision(x_ref),
     )
     sq = jnp.maximum(xn - 2.0 * cross + zn, 0.0)
     out_ref[:] = jnp.exp(-gamma * sq)
@@ -110,7 +147,7 @@ def gram_block_pallas(
     out = pl.pallas_call(
         functools.partial(_gram_kernel, gamma=float(gamma)),
         grid=(n_tiles, m_tiles),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         in_specs=[
@@ -176,9 +213,9 @@ def gram_block(
     ``use_pallas=None`` resolves via :func:`gram_pallas_enabled`;
     callers inside jitted solver steps resolve it ONCE per fit and pass
     it static.  ``solver_grade`` keeps the XLA chain's contraction on
-    ``sdot`` (true-f32 MXU passes) — the Pallas path is f32-accumulated
-    regardless, and its operand stream width follows ``mxu`` (kept
-    ``'f32'`` by every solver caller)."""
+    ``sdot`` (true-f32 MXU passes) — the Pallas path multiplies f32
+    tiles at true f32 as well, and its operand stream width follows
+    ``mxu`` (kept ``'f32'`` by every solver caller)."""
     if use_pallas is None:
         use_pallas = gram_pallas_enabled(int(x.shape[-1]))
     if use_pallas:
@@ -196,7 +233,8 @@ def _poly_gram_kernel(x_ref, z_ref, out_ref, *, alpha: float, c: float, degree: 
     x = x_ref[:].astype(jnp.float32)  # (TN, d)
     z = z_ref[:].astype(jnp.float32)  # (TM, d)
     cross = jax.lax.dot_general(
-        x, z, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, z, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=_tile_precision(x_ref),
     )
     out_ref[:] = (alpha * cross + c) ** degree
 
@@ -231,7 +269,7 @@ def poly_block_pallas(
             degree=int(degree),
         ),
         grid=(n_tiles, m_tiles),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         in_specs=[

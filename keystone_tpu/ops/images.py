@@ -38,7 +38,7 @@ class Convolver(Transformer):
       better MXU mapping when the patch dim and filter count are both
       MXU-friendly (≥~128) while the conv is small;
     - ``"auto"`` (default) — resolved per shape from the measured
-      crossover (BASELINE.md "Convolver strategy crossover"), pinned to a
+      crossover (rounds 1–5, not re-measured), pinned to a
       concrete form by the optimizer's NodeChoiceRule when it samples.
     """
 
@@ -181,8 +181,8 @@ class Convolver(Transformer):
         return self.apply_batch(x[None])[0]
 
 
-#: measured crossover, TPU v5 lite (BASELINE.md "Convolver strategy
-#: crossover"): the im2col patches tensor per image — (oh·ow) positions
+#: measured crossover, TPU v5 lite (rounds 1–5, not re-measured): the
+#: im2col patches tensor per image — (oh·ow) positions
 #: × (fh·fw·c) patch dim — below this many elements, patch-extract+gemm
 #: beats XLA's conv emitter (its fixed per-conv costs dominate small
 #: convs); above it, materializing patches loses to the fused conv.

@@ -1,10 +1,10 @@
-"""Native host-text fast path (VERDICT r4 item 6; SURVEY §2.10 text
+"""Native host-text fast path (round-4 review item 6; SURVEY §2.10 text
 pipelines, §7(f)).
 
 The per-doc Python chain trim→lower→tokenize→ngram→tf→{vocab CSR | df}
-measured 1.5–3.4k docs/s streaming on this 1-core host (BASELINE.md
-"Host text stage") — the reference's answer to the same problem is
-native code behind JNI.  Here the whole fused chain runs in
+measured 1.5–3.4k docs/s streaming on a 1-core host (rounds 1–5, not
+re-measured) — the reference's answer to the same problem is native
+code behind JNI.  Here the whole fused chain runs in
 ``native/keystone_native.cpp`` (``ks_text_*``): C++ tokenization and
 hashing with the GIL released (ctypes) and a thread pool over docs.
 The Python implementations remain both the fallback (no compiler,

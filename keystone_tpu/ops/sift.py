@@ -35,7 +35,7 @@ from keystone_tpu.utils import precision
 _NUM_ORIENTATIONS = 8
 _GRID = 4  # 4x4 spatial bins -> 128-d descriptors
 
-#: DESCRIPTOR LAYOUT CONTRACT (decided r5, VERDICT r4 item 3).  The
+#: DESCRIPTOR LAYOUT CONTRACT (decided r5, round-4 review item 3).  The
 #: canonical 128-d feature order is (y_bin, x_bin, orientation) —
 #: feature index f = gy·(4·8) + gx·8 + o, matching VLFeat's vl_dsift
 #: layout, produced by an explicit (ky,4,kx,4)→(ky,kx,4,4) transpose
@@ -87,7 +87,7 @@ class SIFTExtractor(Transformer):
         self.smoothing_magnif = float(smoothing_magnif)
         #: "matmul" (default): windowing + bin extraction as two MXU
         #: einsums.  Wall-clock is WITHIN NOISE of the conv path at the
-        #: headline config (BASELINE.md r3 A/B: both ~7 µs/image — the
+        #: headline config (rounds 1–5, not re-measured A/B: both ~7 µs/image — the
         #: conv windowing was device time already overlapped with other
         #: stages); matmul stays default because it removes the
         #: layout-copy stage from the graph and is exactly parity-tested.

@@ -421,7 +421,7 @@ class ColumnSampler(Transformer):
             # sample + slice-to-true-rows + flatten as ONE program: the
             # eager slice/reshape at (n, max_k, d) scale compiled two
             # extra (0.1-1.4 s) programs per sampler per process
-            # (BASELINE.md r5 fit-floor split)
+            # (rounds 1–5, not re-measured fit-floor split)
             flat = _sample_descriptors_flat(
                 arr, ds.mask, self.num_samples, self.seed, n_true=n
             )

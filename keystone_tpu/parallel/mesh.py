@@ -134,11 +134,14 @@ def shard_batch(x, mesh: Optional[Mesh] = None, feature_axis: Optional[int] = No
     import jax.numpy as jnp
 
     mesh = mesh or current_mesh()
-    x = jnp.asarray(x)
+    # a HOST array goes straight to its shards: jnp.asarray would first
+    # land the whole of it on the first device, then reshard from there
+    xp = jnp if isinstance(x, jax.Array) else np
+    x = xp.asarray(x)
     dsize = mesh.shape[DATA_AXIS]
     n = x.shape[0]
     padded = pad_to_multiple(n, dsize)
     if padded != n:
         pad_widths = [(0, padded - n)] + [(0, 0)] * (x.ndim - 1)
-        x = jnp.pad(x, pad_widths)
+        x = xp.pad(x, pad_widths)
     return jax.device_put(x, data_sharding(mesh, x.ndim, feature_axis))

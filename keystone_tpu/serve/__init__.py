@@ -22,6 +22,7 @@ from keystone_tpu.serve.fleet import (  # noqa: F401
     ReplicaSupervisor,
 )
 from keystone_tpu.serve.procfleet import (  # noqa: F401
+    ChipOwnershipError,
     ProcessReplica,
     RemoteApplier,
     WorkerCrashed,

@@ -151,7 +151,7 @@ def recv_batch_frame(
 ) -> Tuple[dict, bytes]:
     """Blocking receive of one batch frame (the CLIENT side — the
     server parses incrementally on its event loop).  Same error
-    taxonomy as ``wire.recv_stream_frame``: ``TimeoutError`` when idle,
+    classification as ``wire.recv_stream_frame``: ``TimeoutError`` when idle,
     ``EOFError`` on a clean close between frames, ``WireError`` on
     anything torn."""
     prefix = wire._recv_exact(sock_, _PREFIX_LEN, timeout)
@@ -200,7 +200,7 @@ def recv_batch_frame(
 
 class IngressError(RuntimeError):
     """A server-side refusal relayed through an error frame.  ``kind``
-    carries the admission taxonomy (``overloaded`` / ``deadline`` /
+    carries the admission classification (``overloaded`` / ``deadline`` /
     ``poison`` / ``unavailable`` / ``closed`` / ``bad_request`` /
     ``error``) so a client can map it without string-matching."""
 
@@ -1029,7 +1029,7 @@ class BinaryClient:
 
     ``predict`` submits a whole ``(n, ...)`` batch in one frame and
     returns the ``(n, ...)`` predictions; server refusals raise
-    :class:`IngressError` with the admission taxonomy in ``.kind``."""
+    :class:`IngressError` with the admission classification in ``.kind``."""
 
     def __init__(
         self,

@@ -71,6 +71,43 @@ logger = logging.getLogger(__name__)
 DEFAULT_READY_TIMEOUT_S = 300.0
 
 
+class ChipOwnershipError(RuntimeError):
+    """This process holds the TPU and was asked to start child processes
+    that need the same chip.  A chip belongs to ONE process at a time: each
+    child would die in its spawn ("The TPU is already in use by process
+    with pid N"), at every start and every heal, so the combination is
+    refused once, at start, instead."""
+
+
+def refuse_chip_children(what: str) -> None:
+    """Raise :class:`ChipOwnershipError` when this process is (or, having
+    loaded a model's device arrays, is about to be) the chip's owner.
+
+    Run on a v5e chip (PR 21): with this refusal taken away, a TPU-backed
+    router's ``workers=1`` ends in :class:`WorkerSpawnError` after 4.0 s —
+    the worker's backend start-up aborts because the router's pid holds the
+    chip; it fails, it does not hang.  The router loads the fitted pipeline
+    — device arrays — before it spawns, and re-clones it on every heal, so
+    it cannot stay off the chip either.  What does work is a router on the
+    CPU backend: pinned with ``JAX_PLATFORMS=cpu`` its workers inherit the
+    pin and serve from the CPU; a worker handed the machine's own TPU
+    environment instead took the chip and served from it (by hand: one
+    worker on one chip, and four on a 2x2 host with one visible chip
+    each) — the program has no way to say that yet (``ROADMAP.md`` R8).  On one chip the thread fleet (``replicas=``) is
+    the serving tier."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise ChipOwnershipError(
+            f"{what}: this process holds the TPU and a chip belongs to one "
+            "process at a time — child processes that need it die in their "
+            "spawn (\"The TPU is already in use\").  Serve with replicas= "
+            "(threads) on this host, run the router with JAX_PLATFORMS=cpu "
+            "(its workers then serve from the CPU too), or place workers on "
+            "other hosts."
+        )
+
+
 class WorkerSpawnError(RuntimeError):
     """The worker process failed to reach ready (payload unreadable,
     import failure, ready timeout).  The spawner kills the child before
@@ -353,7 +390,7 @@ class WorkerHandle:
     @staticmethod
     def _map_error(reply: dict) -> BaseException:
         """Rehydrate the worker's typed failure on the router side,
-        preserving the error taxonomy bisection and breakers key on."""
+        preserving the error classification bisection and breakers key on."""
         from keystone_tpu.utils import guard
 
         kind = reply.get("kind", "content")

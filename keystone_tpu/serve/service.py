@@ -527,6 +527,18 @@ class PipelineService:
                 "workers= owns device placement in the worker processes; "
                 "devices= applies to the thread fleet only"
             )
+        if workers > 0:
+            from keystone_tpu.serve.procfleet import refuse_chip_children
+            from keystone_tpu.utils.hostmap import HostMap, parse_hosts
+
+            # workers that would start on THIS host need this host's chip
+            if hosts is None or any(
+                e.local
+                for e in (
+                    hosts.entries if isinstance(hosts, HostMap) else parse_hosts(hosts)
+                )
+            ):
+                refuse_chip_children(f"workers={workers} on this host")
         if hosts is not None and workers < 1:
             raise ValueError(
                 "hosts= selects the cross-host TCP fleet and needs "
@@ -535,15 +547,15 @@ class PipelineService:
             )
         # the persistent-compile-cache tier of the prime fallback ladder
         # (artifact → cache → compile): auto-enabled for library callers
-        # too, not just the CLI entry points.  Env-gated
-        # (KEYSTONE_COMPILE_CACHE=0 disables) and never clobbers an
-        # already-configured cache dir.
+        # too, not just the CLI entry points.  KEYSTONE_COMPILE_CACHE=0
+        # disables; the directory is JAX_COMPILATION_CACHE_DIR's, or
+        # the fixed one in the checkout (utils/compile_cache.py).
         from keystone_tpu.utils.compile_cache import (
-            ensure_compilation_cache,
+            enable_compilation_cache,
             seed_compile_cache,
         )
 
-        ensure_compilation_cache()
+        enable_compilation_cache()
         if artifacts:
             # the bundle may ship persistent-compile-cache entries
             # (export's pre-seeded rung): install them BEFORE any

@@ -23,7 +23,7 @@ flight):
   pool; the result reference names one in THIS worker's response pool
   (each side owns and unlinks its own slabs).
 - apply failures answer ``{"op": "error", "kind", "etype", "emsg"}``
-  where ``kind`` preserves the repo's error taxonomy across the
+  where ``kind`` preserves the repo's error classification across the
   process boundary — ``deadline`` (a shed-typed
   ``guard.DeadlineExceeded``), ``oserror`` (infrastructure),
   ``memory``, or ``content`` (the bisectable family) — so poison
@@ -61,7 +61,7 @@ HEARTBEAT_INTERVAL_S = 0.25
 
 
 def _classify(exc: BaseException) -> str:
-    """The cross-process error taxonomy (the ``_poison_suspect``
+    """The cross-process error classification (the ``_poison_suspect``
     contract from serve/service.py, serialized): infrastructure rides
     ``oserror``, capacity rides ``memory``, shed rides ``deadline``,
     and everything else is ``content`` — the bisectable family."""
@@ -184,7 +184,7 @@ def build_from_payload(payload: dict, spec: dict, tel=None):
     return applier, installed, primed
 
 
-#: public name for the cross-process error taxonomy (the TCP worker
+#: public name for the cross-process error classification (the TCP worker
 #: relays its apply failures through the same classifier)
 classify_error = _classify
 

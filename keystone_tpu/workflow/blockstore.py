@@ -240,7 +240,7 @@ class _BlockStreamBase:
                 t0 = time.perf_counter()
                 dev = stage(blk)
                 # the dispatch itself does real host work (layout copy +
-                # DMA enqueue; on tunneled backends the RPC) — charge it
+                # DMA enqueue) — charge it
                 # to the same transfer account as the landing wait
                 metrics.observe(
                     "blockstore.stage_wait_seconds",

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 from typing import Any, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -112,7 +113,9 @@ class Dataset:
         JAX arrays are already materialized once computed; this blocks on
         completion so downstream timing/profiling sees real costs.
         """
-        if self._array is not None:
+        # under a trace (the frozen apply lowered as one program) there
+        # is nothing to wait for: a Cacher is an identity there
+        if self._array is not None and not isinstance(self._array, jax.core.Tracer):
             self._array.block_until_ready()
         return self
 

@@ -632,7 +632,7 @@ class ProfiledMaterializeRule(Rule):
     falling back to the structural AutoMaterializeRule when profiling is
     unavailable (no device stats, unexecutable sample, host-only graph).
 
-    This is the promotion VERDICT r1 item 8 asked for: the reference's
+    This is the promotion round-1 review item 8 asked for: the reference's
     AutoCacheRule (sampled profiling + memory-budget greedy placement,
     workflow/AutoCacheRule.scala) is now the DEFAULT path, not a
     hand-wired option."""

@@ -331,7 +331,7 @@ void ks_free(void* p) { free(p); }
 // reference's per-doc Scala maps — here the fused
 // trim→lower→tokenize→n-gram→tf→{vocab-lookup | df} chain runs in C++
 // with the GIL released (ctypes) and a thread pool over docs, replacing
-// the measured 2-3k docs/s pure-Python per-doc loops (BASELINE.md
+// the measured 2-3k docs/s pure-Python per-doc loops (rounds 1–5, not re-measured
 // "Host text stage").
 //
 // Parity contract with keystone_tpu/ops/nlp.py (pinned by

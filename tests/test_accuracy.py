@@ -1,4 +1,4 @@
-"""Accuracy tests that can FAIL (VERDICT r1 item 5).
+"""Accuracy tests that can FAIL (round-1 review item 5).
 
 Round-1's end-to-end tests asserted acc==1.0 on separable synthetic data,
 which cannot catch subtle solver bugs (a wrong λ scaling or a dropped
@@ -423,7 +423,7 @@ def test_imagenet_golden_tar_pixels_and_fit(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# App-level accuracy bands (VERDICT r2 item 6): skewed non-separable
+# App-level accuracy bands (round-2 review item 6): skewed non-separable
 # synthetic through the APP entry points — sensitive enough that
 # perturbing mixture_weight or λ in the app config fails the band.
 # ---------------------------------------------------------------------------

@@ -675,6 +675,24 @@ def test_cli_export_publishes_registry_version(tmp_path, exported):
 
 
 # ------------------------------------------- pre-seeded compile cache tier
+def _point_cache_at(path) -> str:
+    """Place jax's persistent cache at ``path`` for one test.  jax binds
+    ONE cache object to the first directory it initialises, so a change
+    of directory needs a reset; the program itself never moves the cache
+    (utils/compile_cache.py), so the test does."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from keystone_tpu.utils.compile_cache import enable_compilation_cache
+
+    jax.config.update("jax_compilation_cache_dir", None if path is None else str(path))
+    cc.reset_cache()
+    if path is not None:
+        os.makedirs(str(path), exist_ok=True)
+        assert enable_compilation_cache() == str(path)
+    return path
+
+
 def test_export_captures_and_seeds_compile_cache(tmp_path, monkeypatch):
     """With a persistent compile cache active, export_artifacts ships
     the backend-compile cache entries alongside the bucket programs;
@@ -682,15 +700,11 @@ def test_export_captures_and_seeds_compile_cache(tmp_path, monkeypatch):
     cache dir — the ladder's last cold rung."""
     import jax
 
-    from keystone_tpu.utils.compile_cache import (
-        enable_compilation_cache,
-        seed_compile_cache,
-    )
+    from keystone_tpu.utils.compile_cache import seed_compile_cache
 
-    cache_dir = str(tmp_path / "xla-cache")
     prev = jax.config.jax_compilation_cache_dir
     try:
-        enable_compilation_cache(cache_dir)
+        _point_cache_at(tmp_path / "xla-cache")
         # a UNIQUE pipeline (fresh weights → fresh HLO): a program this
         # process already compiled hits jax's in-memory cache and never
         # touches the on-disk cache, so capture finds nothing to ship
@@ -709,9 +723,7 @@ def test_export_captures_and_seeds_compile_cache(tmp_path, monkeypatch):
         shipped = {e["name"]: bundle["blobs"][k] for k, e in cache_ents.items()}
 
         # a "fresh host": empty cache dir — seeding installs the files
-        fresh = str(tmp_path / "fresh-cache")
-        jax.config.update("jax_compilation_cache_dir", fresh)
-        os.makedirs(fresh, exist_ok=True)
+        fresh = str(_point_cache_at(tmp_path / "fresh-cache"))
         n = seed_compile_cache(bundle)
         assert n == len(cache_ents)
         for name, data in shipped.items():
@@ -720,7 +732,7 @@ def test_export_captures_and_seeds_compile_cache(tmp_path, monkeypatch):
         # idempotent: a second seed never clobbers (and writes nothing)
         assert seed_compile_cache(bundle) == 0
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+        _point_cache_at(prev)
 
 
 def test_cache_entries_never_register_as_bucket_programs(tmp_path):
@@ -729,15 +741,13 @@ def test_cache_entries_never_register_as_bucket_programs(tmp_path):
     with pre-seed readers (rows entries unchanged)."""
     import jax
 
-    from keystone_tpu.utils.compile_cache import enable_compilation_cache
-
     prev = jax.config.jax_compilation_cache_dir
     try:
-        enable_compilation_cache(str(tmp_path / "xla-cache"))
+        _point_cache_at(tmp_path / "xla-cache")
         frozen = _pipeline().freeze()
         bundle = frozen.export_artifacts(example=_example(), buckets=BUCKETS)
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+        _point_cache_at(prev)
     target = _pipeline().freeze()
     # identical pipeline params → identical signature; install succeeds
     n = target.install_artifacts(
@@ -752,17 +762,15 @@ def test_registry_roundtrips_cache_entries(tmp_path):
     any other blob (checksummed, corrupt-tolerant)."""
     import jax
 
-    from keystone_tpu.utils.compile_cache import enable_compilation_cache
-
     prev = jax.config.jax_compilation_cache_dir
     try:
-        enable_compilation_cache(str(tmp_path / "xla-cache"))
+        _point_cache_at(tmp_path / "xla-cache")
         pipe = _pipeline(seed=42)  # unique HLO: see the capture test
         bundle = pipe.freeze().export_artifacts(
             example=_example(), buckets=BUCKETS
         )
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+        _point_cache_at(prev)
     n_cache = sum(
         1
         for e in bundle["manifest"]["entries"].values()
@@ -794,7 +802,7 @@ def test_export_without_cache_ships_no_cache_entries(monkeypatch):
             example=_example(), buckets=BUCKETS
         )
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+        _point_cache_at(prev)
     assert not any(
         e.get("kind") == "compile_cache"
         for e in bundle["manifest"]["entries"].values()

@@ -1,4 +1,4 @@
-"""Auto out-of-core: no fit() may OOM the chip (VERDICT r4 item 2).
+"""Auto out-of-core: no fit() may OOM the chip (round-4 review item 2).
 
 The profiled materialization pass holds the footprint estimate; fit()'s
 pre-flight acts on it — auto-spilling large array sources to the

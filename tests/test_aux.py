@@ -410,33 +410,55 @@ def test_profile_graph_static_cost_ranks_heavier_node_higher():
     assert times[-1] > times[0]  # the matmul chain prices above the slice
 
 
-def test_compilation_cache_enable_and_disable(tmp_path, monkeypatch):
+@pytest.mark.parametrize(
+    "case", ["env_dir_left_alone", "unset_goes_to_checkout", "off_switch"]
+)
+def test_compilation_cache_enable_and_disable(tmp_path, monkeypatch, case):
+    """ONE way to place the cache: ``JAX_COMPILATION_CACHE_DIR``.  Set, it
+    is left alone (no code path sets another directory); unset, the cache
+    goes to the fixed directory in the checkout — never ``~`` or a
+    temporary name; ``KEYSTONE_COMPILE_CACHE=off`` only switches it off."""
     import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
-    from keystone_tpu.utils.compile_cache import enable_compilation_cache
+    from keystone_tpu.utils import compile_cache
 
     prev = jax.config.jax_compilation_cache_dir
     prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
     monkeypatch.delenv("KEYSTONE_COMPILE_CACHE", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     try:
-        d = str(tmp_path / "xla-cache")
-        got = enable_compilation_cache(d)
-        assert got == d and os.path.isdir(d)
-        assert jax.config.jax_compilation_cache_dir == d
-
-        monkeypatch.setenv("KEYSTONE_COMPILE_CACHE", "off")
-        assert enable_compilation_cache() is None
-
-        monkeypatch.setenv("KEYSTONE_COMPILE_CACHE", str(tmp_path / "env-cache"))
-        got = enable_compilation_cache()
-        assert got == str(tmp_path / "env-cache") and os.path.isdir(got)
+        if case == "env_dir_left_alone":
+            # jax reads the variable into its config at import; mimic that
+            d = str(tmp_path / "from-env")
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+            jax.config.update("jax_compilation_cache_dir", d)
+            assert compile_cache.enable_compilation_cache() == d
+            assert jax.config.jax_compilation_cache_dir == d
+            assert not os.path.exists(d)  # jax makes it on first write, not us
+        elif case == "unset_goes_to_checkout":
+            jax.config.update("jax_compilation_cache_dir", None)
+            got = compile_cache.enable_compilation_cache()
+            assert got == compile_cache.CACHE_DIR == os.path.join(repo, ".jax_cache")
+            assert os.path.isdir(got)
+            assert jax.config.jax_compilation_cache_dir == got
+            # the same path every time: it is part of the cache key
+            assert compile_cache.enable_compilation_cache() == got
+            assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        else:
+            jax.config.update("jax_compilation_cache_dir", None)
+            monkeypatch.setenv("KEYSTONE_COMPILE_CACHE", "off")
+            assert compile_cache.enable_compilation_cache() is None
+            assert jax.config.jax_compilation_cache_dir is None
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", prev_min)
+        cc.reset_cache()
 
 
 def test_default_optimizer_uses_profiled_materialization():
-    """VERDICT r1 item 8: the HBM-budgeted profiling cache rule is the
+    """round-1 review item 8: the HBM-budgeted profiling cache rule is the
     DEFAULT materialization pass, with the budget read from the device."""
     from keystone_tpu.workflow.optimizer import (
         ProfiledMaterializeRule,

@@ -131,3 +131,104 @@ def test_flops_accounting_tracks_real_descriptor_count():
     total = bench.flops_per_image()
     fv = 4 * 2 * t * bench.PCA_DIMS * bench.GMM_K
     assert fv < total < 3 * fv
+
+
+def test_orchestrator_exits_nonzero_on_a_failed_leg_and_stays_off_jax(tmp_path):
+    """``python bench.py`` (no flags) only orchestrates: every leg is a
+    child that owns the chip in turn, so the parent must never import
+    jax, a failed leg must make the run exit non-zero (it used to print
+    a headline from one sample and exit 0), and the result names the
+    device.  Children are faked; the parent runs for real in a fresh
+    interpreter (this one has jax loaded)."""
+    import json
+    import subprocess
+
+    code = f"""
+import json, subprocess, sys
+import bench
+for name in ("SCALE_LEGS KERNEL_LEGS SERVE_LEGS FLEET_LEGS HEDGE_LEGS ARTIFACT_LEGS "
+             "TENANT_LEGS PROC_LEGS INGRESS_LEGS PLAN_LEGS PRECISION_LEGS").split():
+    setattr(bench, name, 0)
+bench.N_LEGS = 1
+bench._BASELINE_CACHE = {str(tmp_path / "cpu.json")!r}
+DEVICE = {{"device": {{"platform": "tpu", "device_kind": "TPU v5 lite", "device_count": 1}}}}
+RESULTS = {{
+    "--leg": {{"leg_ips": 100.0, "flops_per_image": 1e9, "f32_peak": 4.9e13}},
+    "--leg-ms": {{"leg_ips": 50.0}},
+    "--cpu": {{"cpu_ips": 2.0}},
+}}
+def fake_run(argv, **kw):
+    flag = argv[2]
+    if flag not in RESULTS:  # --leg-fit: the leg that dies
+        return subprocess.CompletedProcess(argv, 1, "", "boom")
+    out = json.dumps(DEVICE) + "\\n" + json.dumps(RESULTS[flag]) + "\\n"
+    return subprocess.CompletedProcess(argv, 0, out, "")
+bench.subprocess.run = fake_run
+sys.argv = ["bench.py"]
+rc = bench.main()
+assert "jax" not in sys.modules, "the orchestrating parent imported jax"
+sys.exit(rc)
+"""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=root, capture_output=True, text=True,
+        timeout=120,
+    )
+    assert proc.returncode == 1, proc.stderr[-2000:]
+    assert "--leg-fit" in proc.stderr
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["failed_legs"] == ["--leg-fit"]
+    assert out["device"] == {
+        "platform": "tpu", "device_kind": "TPU v5 lite", "device_count": 1
+    }
+    assert out["value"] == 100.0 and out["multiscale"]["images_per_sec"] == 50.0
+
+
+def test_measuring_leg_refuses_without_a_tpu():
+    """A leg on a machine with no TPU refuses (exit 2, nothing printed)
+    unless ``--cpu`` says the CPU is meant."""
+    import subprocess
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "bench.py", "--leg-ms"], cwd=root, capture_output=True,
+        text=True, timeout=300, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert proc.returncode == 2 and proc.stdout == ""
+    assert "needs a TPU" in proc.stderr
+
+
+def test_cpu_leg_pins_its_descendants_to_the_cpu():
+    """A ``--cpu`` leg starts children of its own (process workers, the
+    per-arm A/B subprocesses of serve_bench).  The pin must ride the
+    ENVIRONMENT so that they inherit it: a ``jax.config`` setting held
+    only the leg's own process, and on a TPU host its children then went
+    for the one chip.  The leg's body is faked by a child that reports
+    what it inherited; the leg runs in a fresh interpreter that starts
+    with no ``JAX_PLATFORMS`` at all."""
+    import json
+    import subprocess
+
+    code = """
+import json, os, subprocess, sys
+import bench
+def fake_leg(args):
+    child = subprocess.run(
+        [sys.executable, "-c", "import os; print(os.environ.get('JAX_PLATFORMS'))"],
+        capture_output=True, text=True)
+    import jax
+    print(json.dumps({"child": child.stdout.strip(), "backend": jax.default_backend()}))
+bench.run_leg = fake_leg
+sys.argv = ["bench.py", "--leg-serve-procs", "--cpu"]
+sys.exit(bench.main())
+"""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=root, capture_output=True, text=True,
+        timeout=300, env=env,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    first, last = (json.loads(ln) for ln in proc.stdout.strip().splitlines())
+    assert first["device"]["platform"] == "cpu"
+    assert last == {"child": "cpu", "backend": "cpu"}

@@ -65,7 +65,7 @@ def test_augmented_examples_evaluator_unsorted_ids():
 
 
 def test_map_evaluator_hand_computed_multiclass():
-    """Hand-computed 3-class fixture (VERDICT r2 item 6).
+    """Hand-computed 3-class fixture (round-2 review item 6).
 
     Class 0, score order d0>d2>d1, labels [1,0,1]:
       rank1 d0 pos P=1/1; rank2 d2 neg; rank3 d1 pos P=2/3

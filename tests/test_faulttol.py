@@ -1,4 +1,4 @@
-"""Fault injection + recovery (VERDICT r1 item 6; SURVEY §5 "failure
+"""Fault injection + recovery (round-1 review item 6; SURVEY §5 "failure
 detection / elastic recovery").
 
 The reference inherited fault tolerance from Spark (lineage recompute,
@@ -112,7 +112,7 @@ def test_gloo_process_killed_midfit_recovers_from_checkpoint(tmp_path):
 
 
 def test_gloo_process_killed_mid_sparse_lbfgs_resumes(tmp_path):
-    """VERDICT r3 weak-3 + next-4: the sparse L-BFGS fit (the vocab-scale
+    """round-3 review weak-3 + next-4: the sparse L-BFGS fit (the vocab-scale
     text solver) killed mid-fit across 2 Gloo processes resumes from the
     persisted optimizer carry and matches the uninterrupted model."""
     ckpt = str(tmp_path / "ckpt")

@@ -298,3 +298,36 @@ def test_block_kernel_matrix_routes_poly_and_linear(monkeypatch):
         atol=1e-5,
     )
     assert calls == [(1.0, 0.0, 1, "f32")]
+
+
+@pytest.mark.parametrize("kernel", ["gaussian", "polynomial"])
+@pytest.mark.parametrize("mxu,highest", [("f32", True), ("bf16", False)])
+def test_solver_stream_multiplies_at_true_f32(kernel, mxu, highest):
+    """f32 tiles are the solver stream: the in-kernel contraction must ask
+    for ``Precision.HIGHEST`` (the MXU's default rounds f32 operands to
+    bf16 — 1.0e-4..3.3e-4 off the XLA chain at d=2048 on the chip, PR 21).
+    bf16 tiles are exact in one pass and must not pay for six."""
+    import jax
+
+    x, z = _setup()
+    if kernel == "gaussian":
+        fn = lambda a, b: gram_block_pallas(a, b, 0.3, interpret=True, mxu=mxu)  # noqa: E731
+    else:
+        fn = lambda a, b: gram_pallas.poly_block_pallas(  # noqa: E731
+            a, b, 1.0, 1.0, 2, interpret=True, mxu=mxu
+        )
+    assert ("Precision.HIGHEST" in str(jax.make_jaxpr(fn)(x, z))) is highest
+
+
+def test_tile_rule_counts_the_true_f32_multiply():
+    """Above the 128-row floor the HIGHEST multiply keeps split copies of
+    both operand tiles (twice the operand bytes, read off the v5e
+    compiler's refusals); at the floor it does not, so ``GRAM_MAX_D`` is
+    still the widest d the floor compiles."""
+    t = gram_pallas._tile_vmem_bytes
+    assert t(256, 2048) - t(256, 1024) == 32 * 256 * 1024
+    assert t(128, 2048) - t(128, 1024) == 16 * 128 * 1024
+    assert _gram_tile(4096, 440) == 512  # TIMIT width keeps the widest tile
+    assert _gram_tile(4096, 2048) == 128
+    assert t(128, gram_pallas.GRAM_MAX_D) <= gram_pallas._VMEM_BUDGET
+    assert t(128, gram_pallas.GRAM_MAX_D + 128) > gram_pallas._VMEM_BUDGET

@@ -1,4 +1,4 @@
-"""Parallel host text maps (VERDICT r3 weak-5: the host text stage was
+"""Parallel host text maps (round-3 review weak-5: the host text stage was
 single-threaded pure Python).  Threads can't help — the GIL serializes
 pure-Python tokenization (libjpeg's thread pool worked because C decode
 releases the GIL) — so host_map forks processes.  These tests pin

@@ -1,5 +1,5 @@
 """Zero-copy async ingress (serve/ingress.py): batch-frame hardening
-(the wire-v2 taxonomy — garbage magic, version skew, truncation, CRC
+(the wire-v2 classification — garbage magic, version skew, truncation, CRC
 damage, oversize refusal, mid-frame stall — every one a typed verdict,
 never a hang), protocol sniffing (HTTP/JSON on the same port), slab-
 direct admission (preformed flushes, zero admission copies), typed

@@ -1,4 +1,4 @@
-"""L-BFGS mid-fit checkpoint/resume (VERDICT r3 weak-3).
+"""L-BFGS mid-fit checkpoint/resume (round-3 review weak-3).
 
 Both BCD solvers checkpoint per epoch; the L-BFGS family previously had
 no mid-fit checkpoint at all — the one solver family where a kill lost

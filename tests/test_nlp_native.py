@@ -1,6 +1,6 @@
 """Parity: the native fused text chain (ops/nlp_native +
 native/keystone_native.cpp ks_text_*) against the pure-Python
-per-doc chain it replaces (VERDICT r4 item 6).
+per-doc chain it replaces (round-4 review item 6).
 
 The df TIE order is documented as divergent (Python Counter.most_common
 inherits process-salted set iteration; native is deterministic by

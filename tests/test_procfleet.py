@@ -288,6 +288,21 @@ def test_workers_and_replicas_are_exclusive():
         serve(_pipeline(), workers=2, replicas=2)
 
 
+@pytest.mark.parametrize("hosts", [None, "local:2"])
+def test_local_workers_refused_when_router_holds_tpu(monkeypatch, hosts):
+    """One process per chip: on a TPU the router (which has loaded the
+    model's device arrays) must refuse to spawn local workers with a
+    typed error at start — each worker would die in its spawn ("The TPU is
+    already in use"), at every start and every heal."""
+    import jax
+
+    from keystone_tpu.serve import ChipOwnershipError, serve
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ChipOwnershipError, match="one process"):
+        serve(_pipeline(), workers=2, hosts=hosts)
+
+
 # ------------------------------------------- fleet telemetry (ISSUE 18)
 
 

@@ -538,3 +538,24 @@ def test_overload_keeps_accepting_with_bounded_queue():
     assert rep["errors"] == 0
     assert rep["deadline_miss"] == 0  # completed requests beat deadlines
     assert rep["p99_ms"] is not None and rep["p99_ms"] < 500.0
+
+
+def test_serve_bench_counts_an_unavailable_fleet_as_rejected(monkeypatch):
+    """An open breaker answers ``FleetUnavailable`` at admission — a
+    typed refusal like ``Overloaded``.  The offer loop must count it and
+    go on: on the v5e the overload leg died with it one run in two
+    (PR 21), and a leg that dies reports nothing."""
+    from tools import serve_bench
+
+    svc, item_shape = serve_bench.build_service(
+        dim=16, max_batch=8, max_wait_ms=2.0, queue_bound=32, deadline_ms=500.0
+    )
+    try:
+        monkeypatch.setattr(svc._pool, "available", lambda: False)
+        rep = serve_bench.run_bench(
+            svc, item_shape, qps=400.0, duration=0.25, deadline_ms=500.0
+        )
+    finally:
+        svc.close()
+    assert rep["n_requests"] == 100
+    assert rep["rejected"] == 100 and rep["completed"] == 0 and rep["errors"] == 0

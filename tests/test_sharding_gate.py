@@ -1,4 +1,4 @@
-"""Compiled-HLO sharding-semantics gate (VERDICT r3 missing-2).
+"""Compiled-HLO sharding-semantics gate (round-3 review missing-2).
 
 The numerics gates (dryrun mesh-sweep parity, multihost tests) cannot
 distinguish a correctly sharded program from one that silently fell back

@@ -1,4 +1,4 @@
-"""Sparse-gradient text path (VERDICT r1 item 7).
+"""Sparse-gradient text path (round-1 review item 7).
 
 The reference's LBFGS.scala § LeastSquaresSparseGradient computes
 least-squares gradients from CSR without densifying n×d; the TPU
@@ -284,7 +284,7 @@ def _random_csr_rows(rng, n, d, nnz_per_row):
 
 
 def test_bucketed_kills_global_padding_cliff():
-    """One dense row must NOT inflate every row's padding (VERDICT r2):
+    """One dense row must NOT inflate every row's padding (round-2 review):
     bucketed memory stays near Σnnz while global padding blows up n×max."""
     from keystone_tpu.ops.sparse import BucketedSparseRows, PaddedSparseRows
 
@@ -352,7 +352,7 @@ def test_chunked_ops_match_unchunked(monkeypatch):
 
 
 def test_sparse_lbfgs_heavy_tailed_nnz_property():
-    """Property test (VERDICT r2 item 4): a heavy-tailed nnz corpus fits
+    """Property test (round-2 review item 4): a heavy-tailed nnz corpus fits
     through the bucketed path and matches the dense solver."""
     from keystone_tpu.models import DenseLBFGSwithL2, SparseLBFGSwithL2
 
@@ -417,7 +417,7 @@ def test_sparse_lbfgs_intercept_matches_dense():
     )
 
 
-# ------------------------------------- node-choice breadth (VERDICT r2 3)
+# ------------------------------------- node-choice breadth (round-2 review 3)
 
 
 def test_node_choice_local_vs_distributed_ls():

@@ -260,7 +260,7 @@ def test_voc_synthetic_stream_matches_synthetic(mesh):
 
 
 def test_voc_app_stream_matches_inmemory(mesh):
-    """VOCSIFTFisher --stream (the last of the eight apps, VERDICT r3
+    """VOCSIFTFisher --stream (the last of the eight apps, round-3 review
     weak-4): the streamed fit produces the in-memory fit's scores."""
     from keystone_tpu.pipelines.voc_sift_fisher import Config, VOCSIFTFisher
 
@@ -283,7 +283,7 @@ def test_voc_app_stream_matches_inmemory(mesh):
 
 
 def test_imagenet_augmented_eval_composes_with_stream(mesh):
-    """--augmented-eval × --stream (VERDICT r3 next-6): the 10-view
+    """--augmented-eval × --stream (round-3 review next-6): the 10-view
     augmented evaluation must run against a model fit from the streamed
     loader, matching the in-memory augmented run."""
     from keystone_tpu.pipelines.imagenet_sift_lcs_fv import ImageNetSiftLcsFV

@@ -92,7 +92,6 @@ def test_cli_runs_mnist_end_to_end():
     (the bin/run-pipeline.sh path, minus the shell wrapper)."""
     env = dict(
         os.environ,
-        KEYSTONE_PLATFORM="cpu",
         JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
     )

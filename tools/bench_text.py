@@ -1,6 +1,6 @@
 """Host text stage throughput: Python per-doc chain vs the native fused
 path (ops/nlp_native), on the r4 synthetic corpus shape (120 tokens/doc,
-5k-word vocab; BASELINE.md "Host text stage").
+5k-word vocab; rounds 1–5, not re-measured).
 
     python tools/bench_text.py [n_docs] [--python-docs M]
 

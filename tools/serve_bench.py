@@ -214,7 +214,7 @@ def run_bench(
 
     from keystone_tpu import faults
     from keystone_tpu.obs import metrics
-    from keystone_tpu.serve import Overloaded
+    from keystone_tpu.serve import FleetUnavailable, Overloaded
     from keystone_tpu.utils import guard
 
     burst = max(1, int(burst))
@@ -292,7 +292,12 @@ def run_bench(
             t_submit = time.monotonic()
             try:
                 batch_futs = svc.submit_many(group, deadline=deadline_s)
-            except Overloaded:
+            except (Overloaded, FleetUnavailable):
+                # both are typed refusals at admission (a 503 on the
+                # wire).  An open breaker is where this workload can land
+                # — it sits on the overload cliff, and a run of shed
+                # flushes opens it: on the v5e one run in two died here
+                # with FleetUnavailable out of the offer loop (PR 21)
                 with lock:
                     outcomes["rejected"] += len(group)
             else:
@@ -1669,9 +1674,13 @@ def _artifact_arm_subprocess(
     import subprocess
     import tempfile
 
+    from keystone_tpu.serve.procfleet import refuse_chip_children
+
+    # this driver published the registry version, so it has touched JAX
+    refuse_chip_children("the per-arm A/B subprocesses")
     env = dict(os.environ)
     cache = tempfile.mkdtemp(prefix="keystone-ab-xla-")
-    env["KEYSTONE_COMPILE_CACHE"] = cache
+    env["JAX_COMPILATION_CACHE_DIR"] = cache
     try:
         proc = subprocess.run(
             [
