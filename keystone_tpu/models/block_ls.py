@@ -278,10 +278,11 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             xc, yc = x, y
         from keystone_tpu.obs import ledger
 
-        # device_wait: obs-gated sync charging the solve to the ledger's
-        # device-busy account (inert — not even a block — without a run)
-        weights = ledger.device_wait(
-            _bcd_fit(
+        with ledger.span(
+            "solver.fit", solver="bcd", n=int(n),
+            blocks=-(-x.shape[1] // self.block_size),
+        ):
+            weights = _bcd_fit(
                 blockify(xc, self.block_size),
                 yc,
                 nf,
@@ -289,7 +290,6 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                 self.num_iter,
                 obs=ledger.solver_obs(),
             )
-        )
         return finish_block_model(
             weights, xm, ym, x.shape[1], self.block_size, self.fit_intercept
         )
@@ -906,8 +906,7 @@ def _oc_bcd_fit(
                 )
             t_epoch = _time.perf_counter()
             epoch += 1
-    weights = ledger.device_wait(jnp.stack(w))
-    return weights, xm.reshape(-1), ym
+    return jnp.stack(w), xm.reshape(-1), ym
 
 
 def _spill_dir(hint=None):

@@ -172,14 +172,16 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         x = jnp.asarray(x, jnp.float32)
         y = jnp.asarray(y, jnp.float32)
         nf = jnp.float32(n)
-        alpha = class_weights(y, nf, self.mixture_weight)
-        weights, xm, ym = _weighted_bcd_fit(
-            x, y, alpha, nf, self.lam, self.num_iter, self.block_size,
-            self.fit_intercept, obs=ledger.solver_obs(),
-        )
-        # obs-gated sync: charge the solve's wall wait to the ledger's
-        # device-busy account (inert without an active run)
-        weights = ledger.device_wait(weights)
+        # the host's part of the solve (the dispatch); nothing here waits
+        with ledger.span(
+            "solver.fit", solver="bcd.weighted", n=int(n),
+            blocks=-(-x.shape[1] // self.block_size),
+        ):
+            alpha = class_weights(y, nf, self.mixture_weight)
+            weights, xm, ym = _weighted_bcd_fit(
+                x, y, alpha, nf, self.lam, self.num_iter, self.block_size,
+                self.fit_intercept, obs=ledger.solver_obs(),
+            )
         from keystone_tpu.models.block_ls import finish_block_model
 
         return finish_block_model(
@@ -220,11 +222,18 @@ def _weighted_bcd_fit(
         w, p = carry
         a = xb[b] * sa[:, None]  # √α-scaled block: AᵀA = XᵀDX
         wb = w[b]
-        target = (yc - p) * sa[:, None] + a @ wb
-        ata = sharded_gram(a)
-        atr = sharded_matmul(a, target, out_spec=P(None, MODEL_AXIS))
-        wb_new = solve_spd(ata, atr, reg=lam * n)
-        p_new = constrain(p + xb[b] @ (wb_new - wb), DATA_AXIS, MODEL_AXIS)
+        # scopes are metadata only (what an operator reads in a device
+        # trace): the HLO and the compile cache's key do not change
+        with jax.named_scope("bcd.residual"):
+            target = (yc - p) * sa[:, None] + a @ wb
+        with jax.named_scope("bcd.gram"):
+            ata = sharded_gram(a)
+        with jax.named_scope("bcd.cross"):
+            atr = sharded_matmul(a, target, out_spec=P(None, MODEL_AXIS))
+        with jax.named_scope("bcd.solve"):
+            wb_new = solve_spd(ata, atr, reg=lam * n)
+        with jax.named_scope("bcd.residual"):
+            p_new = constrain(p + xb[b] @ (wb_new - wb), DATA_AXIS, MODEL_AXIS)
         return w.at[b].set(wb_new), p_new
 
     def epoch(carry, e):

@@ -275,14 +275,11 @@ class KernelRidgeRegressionEstimator(LabelEstimator):
         else:
             from keystone_tpu.obs import ledger
 
-            # device_wait: obs-gated sync charging the solve to the
-            # ledger's device-busy account (inert without a run)
-            alpha = ledger.device_wait(
-                _krr_fit(
+            with ledger.span("solver.fit", solver="krr", n=int(n), blocks=nb):
+                alpha = _krr_fit(
                     x, y, jnp.float32(n), self.kernel_gen.gamma, self.lam,
                     bs, self.num_epochs, obs=ledger.solver_obs(),
                 )
-            )
         return KernelBlockLinearMapper(self.kernel_gen, x, alpha, bs, n)
 
 
@@ -754,7 +751,7 @@ def _oc_krr_fit(
                 )
             t_epoch = _time.perf_counter()
             epoch += 1
-    return ledger.device_wait(jnp.concatenate(ab, axis=0))
+    return jnp.concatenate(ab, axis=0)
 
 
 @partial(jax.jit, static_argnames=("gamma", "mxu", "use_pallas"))

@@ -459,8 +459,8 @@ class DenseLBFGSwithL2(LabelEstimator):
     def _fit(self, x, y, n):
         from keystone_tpu.obs import ledger
 
-        w, b = ledger.device_wait(
-            _lbfgs_least_squares(
+        with ledger.span("solver.fit", solver="lbfgs", n=int(n), blocks=1):
+            w, b = _lbfgs_least_squares(
                 jnp.asarray(x, jnp.float32),
                 jnp.asarray(y, jnp.float32),
                 jnp.float32(n),
@@ -470,7 +470,6 @@ class DenseLBFGSwithL2(LabelEstimator):
                 self.fit_intercept,
                 obs=ledger.solver_obs(),
             )
-        )
         return LinearMapper(w, b if self.fit_intercept else None)
 
     def fit_checkpointed(

@@ -178,13 +178,9 @@ class NystromFeatures(Estimator):
 
     def _fit_landmarks(self, lmk: np.ndarray) -> NystromFeatureMap:
         lmk = jnp.asarray(lmk, jnp.float32)
-        from keystone_tpu.obs import ledger
-
-        whiten = ledger.device_wait(
-            _nystrom_whiten(
-                lmk,
-                jnp.float32(self.kernel_gen.gamma),
-                jnp.float32(self.reg),
-            )
+        whiten = _nystrom_whiten(
+            lmk,
+            jnp.float32(self.kernel_gen.gamma),
+            jnp.float32(self.reg),
         )
         return NystromFeatureMap(self.kernel_gen, lmk, whiten)

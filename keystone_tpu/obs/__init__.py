@@ -8,11 +8,14 @@ primitives every subsystem reports through:
   histograms (``REGISTRY``), exported as JSON or Prometheus text.
   Always on (a bump is one lock + dict update); ``KEYSTONE_METRICS=0``
   disables recording entirely.
-- :mod:`keystone_tpu.obs.ledger` — a per-run JSONL span/event stream
-  (Dapper-style), activated by ``KEYSTONE_OBS_DIR`` or
-  ``ledger.start_run``; default OFF and inert.  Spans also annotate the
-  jax profiler timeline and sample HBM/RSS watermarks.  Long-lived runs
-  rotate past ``KEYSTONE_OBS_MAX_BYTES`` into keep-N numbered segments.
+- :mod:`keystone_tpu.obs.ledger` — the one span primitive
+  (``ledger.span``): always on, every span in a bounded in-memory ring
+  (``recent_spans()``) and in any jax profiler session's host plane;
+  and its opt-in export, a per-run JSONL span/event stream
+  (Dapper-style) activated by ``KEYSTONE_OBS_DIR`` or
+  ``ledger.start_run``, which also samples HBM/RSS watermarks at the
+  end of root spans.  Long-lived runs rotate past
+  ``KEYSTONE_OBS_MAX_BYTES`` into keep-N numbered segments.
 - :mod:`keystone_tpu.obs.recorder` — the serving path's flight
   recorder: a bounded in-memory ring of recent request traces with
   tail-based retention (shed/error/slow traces pinned), ON by default

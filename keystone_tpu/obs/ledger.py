@@ -1,5 +1,25 @@
-"""Run ledger: a per-run JSONL span/event stream (the Dapper-style
-trace the reference got for free from Spark's event log).
+"""Spans, and the run ledger that exports them: one span primitive for
+the whole program, always on, with three sinks.
+
+:class:`span` is the one way to time a region.  Every span
+
+1. enters ``jax.profiler.TraceAnnotation(name)`` — a flag test while no
+   profiler session runs; while one does (the benchmark's ``--trace 1``,
+   ``utils/tracing.trace``, an operator's TensorBoard capture) the span is
+   in the xplane's host plane on the clock of the device events, whoever
+   started the session;
+2. appends one closed-span :class:`SpanRecord` to a bounded process-wide
+   ring (``recent_spans()``): ``(span_id, parent_id, root_id, name, t0_ns,
+   dur_ns, attrs)`` from ``time.perf_counter_ns()``.  ``root_id`` is the id
+   of the thread's outermost open span, so all spans of one fit or one
+   scoring call share it.  No lock beyond the GIL, no JSON, no I/O, no
+   syscall, no device call; attrs are scalars (or flat lists of scalars),
+   never an array, so the ring pins no device memory;
+3. only while a JSONL **run ledger** is active, writes ``span_start`` /
+   ``span_end`` lines to it.
+
+Observing never synchronises: nothing here waits for the device unless the
+program needs that wait anyway (``device_wait(x, force=True)``).
 
 One **run** = one JSONL file ``run_<run_id>.jsonl`` under the ledger
 directory.  Every line is one event::
@@ -14,7 +34,7 @@ records attempt counts this way).  The schema is flat on purpose:
 ``tools/obs_report.py`` and ad-hoc ``jq`` both read it without a parser
 library.
 
-Activation — default OFF and inert:
+The JSONL export is opt-in:
 
 - ``KEYSTONE_OBS_DIR=<dir>`` activates a process-wide ledger lazily (the
   first ``span``/``event`` call creates it, ``atexit`` closes it) — the
@@ -22,32 +42,30 @@ Activation — default OFF and inert:
 - ``start_run(dir)`` / ``stop_run()`` scope a ledger explicitly
   (bench.py and tests use this; an explicit run wins over the env one).
 
-With neither, every hook in the codebase reduces to one ``None`` check
-(plus one ``os.environ`` lookup) — the disabled-mode zero-event
-guarantee tests pin.
-
-Spans also emit ``jax.profiler.TraceAnnotation`` so ledger stages line
-up by name with device traces captured via ``utils/tracing.py``, and
-sample the device HBM watermark (``memory_stats()``) plus host max-RSS
-at boundaries into the metrics registry (gauge ``hbm.bytes_in_use`` /
+With neither, no file is written and ``active()`` is None: the ring is
+not "active".  While a ledger is active the end of every ROOT span (and
+``close()``) samples the device HBM watermark (``memory_stats()``) plus
+host max-RSS into the metrics registry (gauge ``hbm.bytes_in_use`` /
 ``host.max_rss_bytes``).
 
 Solver telemetry rides :func:`solver_epoch` — host loops call it
 directly; jitted solver scans reach it through ``jax.debug.callback``
 (see ``models/lbfgs.py`` et al., gated by a static ``obs`` flag so the
-compiled program is byte-identical when observability is off).
+compiled program is byte-identical when no ledger is active).
 """
 
 from __future__ import annotations
 
 import atexit
-import contextlib
+import collections
 import itertools
 import json
 import os
 import threading
 import time
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
 
 from keystone_tpu.obs import metrics
 
@@ -76,9 +94,11 @@ ATTR_VOCABULARY = {
     "attempt",
     "attempts",
     "batch",
+    "blocks",
     "bucket",
     "budget_bytes",
     "budget_seconds",
+    "bytes",
     "cache_hits",
     "canary_fraction",
     "checkpoint_save_seconds",
@@ -104,6 +124,7 @@ ATTR_VOCABULARY = {
     "no_memoize_demotions",
     "node",
     "node_id",
+    "nodes",
     "objective",
     "occupancy",
     "outcome",
@@ -127,6 +148,7 @@ ATTR_VOCABULARY = {
     "rows",
     "rule",
     "seconds",
+    "shared",
     "shared_bytes",
     "shared_nodes",
     "shared_stages",
@@ -225,19 +247,133 @@ def _sample_memory() -> Dict[str, float]:
     return out
 
 
-class _Span:
-    """An open span: ``set(**attrs)`` merges attrs reported at close."""
+#: closed spans the process keeps in memory (oldest dropped first)
+RING_SIZE = 32768
 
-    __slots__ = ("span_id", "name", "attrs", "t0")
 
-    def __init__(self, span_id: int, name: str, attrs: Dict[str, Any]):
-        self.span_id = span_id
+class SpanRecord(NamedTuple):
+    """One closed span, as the ring holds it."""
+
+    span_id: int
+    parent_id: Optional[int]
+    root_id: int
+    name: str
+    t0_ns: int
+    dur_ns: int
+    attrs: Dict[str, Any]
+
+
+_RING: collections.deque = collections.deque(maxlen=RING_SIZE)
+_SPAN_IDS = itertools.count(1)  # next() is atomic under the GIL
+_TLS = threading.local()  # .stack: this thread's open spans, outermost first
+_SCALARS = (str, int, float, bool, type(None))
+
+
+def _stack() -> list:
+    st = getattr(_TLS, "stack", None)
+    if st is None:
+        st = _TLS.stack = []
+    return st
+
+
+def _check_attrs(attrs: Dict[str, Any]) -> None:
+    """Span attrs are scalars, or flat lists of scalars (``request_ids``):
+    the ring outlives the span, so an array here would pin its device
+    buffer — and reading one would wait for the device."""
+    for key, v in attrs.items():
+        if isinstance(v, _SCALARS) or (
+            isinstance(v, (list, tuple)) and all(isinstance(x, _SCALARS) for x in v)
+        ):
+            continue
+        raise TypeError(
+            f"span attribute {key!r} is a {type(v).__name__}; spans hold "
+            "str, int, float, bool, None or flat lists of those (pass a "
+            "shape or a byte count, never an array)"
+        )
+
+
+class span:
+    """Timed nested region: ``with ledger.span("executor.stage", node=...)
+    as sp`` — always on (see the module docstring for its three sinks).
+    ``sp.set(**attrs)`` merges attrs reported at close."""
+
+    __slots__ = (
+        "name", "attrs", "span_id", "parent_id", "root_id", "t0_ns", "_ann", "_led",
+    )
+
+    def __init__(self, name: str, **attrs):
+        _check_attrs(attrs)
         self.name = name
         self.attrs = attrs
-        self.t0 = time.perf_counter()
 
     def set(self, **attrs) -> None:
+        _check_attrs(attrs)
         self.attrs.update(attrs)
+
+    def __enter__(self) -> "span":
+        st = _stack()
+        self.span_id = next(_SPAN_IDS)
+        if st:
+            self.parent_id, self.root_id = st[-1].span_id, st[0].span_id
+        else:
+            self.parent_id, self.root_id = None, self.span_id
+        self._led = led = active()
+        if led is not None:
+            led._emit(
+                "span_start", self.name, span=self.span_id, parent=self.parent_id,
+                attrs=self.attrs,
+            )
+        st.append(self)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.t0_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type=None, exc=None, tb=None) -> None:
+        dur_ns = time.perf_counter_ns() - self.t0_ns
+        self._ann.__exit__(exc_type, exc, tb)
+        st = _stack()
+        if st and st[-1] is self:
+            st.pop()
+        _RING.append(tuple.__new__(SpanRecord, (
+            self.span_id, self.parent_id, self.root_id, self.name, self.t0_ns,
+            dur_ns, self.attrs,
+        )))
+        led = self._led
+        if led is not None:
+            end_attrs = self.attrs
+            if self.parent_id is None:  # a root span ends: one memory sample
+                end_attrs = {**end_attrs, **_sample_memory()}
+            led._emit(
+                "span_end", self.name, span=self.span_id, parent=self.parent_id,
+                attrs=end_attrs, seconds=dur_ns / 1e9,
+            )
+
+
+def recent_spans() -> List[SpanRecord]:
+    """A copy of the ring: the process's last ``RING_SIZE`` closed spans,
+    in the order they closed (a child before its parent)."""
+    return list(_RING)
+
+
+def self_seconds(records) -> Dict[int, float]:
+    """``{span_id: seconds}`` — each span's duration less the part of it
+    that its child spans (among ``records``) cover."""
+    children: Dict[int, list] = {}
+    for r in records:
+        if r.parent_id is not None:
+            children.setdefault(r.parent_id, []).append(r)
+    out = {}
+    for r in records:
+        lo, hi = r.t0_ns, r.t0_ns + r.dur_ns
+        covered, cursor = 0, lo
+        for c in sorted(children.get(r.span_id, ()), key=lambda c: c.t0_ns):
+            c_lo, c_hi = max(c.t0_ns, cursor), min(c.t0_ns + c.dur_ns, hi)
+            if c_hi > c_lo:
+                covered += c_hi - c_lo
+                cursor = c_hi
+        out[r.span_id] = (r.dur_ns - covered) / 1e9
+    return out
 
 
 class RunLedger:
@@ -296,17 +432,10 @@ class RunLedger:
         self._lock = threading.RLock()
         self._seq = 0
         self._f = open(self.path, "a", encoding="utf-8")
-        self._tls = threading.local()  # per-thread open-span stack
         self._closed = False
         self._emit("run_start", "run", attrs={"pid": os.getpid()})
 
     # ------------------------------------------------------------ emit
-    def _stack(self):
-        st = getattr(self._tls, "stack", None)
-        if st is None:
-            st = self._tls.stack = []
-        return st
-
     def _emit(
         self,
         kind: str,
@@ -371,50 +500,13 @@ class RunLedger:
         metrics.inc("obs.ledger_rotations")
 
     def event(self, name: str, **attrs) -> None:
-        st = self._stack()
+        st = _stack()
         self._emit(
             "event",
             name,
             parent=st[-1].span_id if st else None,
             attrs=attrs,
         )
-
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs):
-        """Timed nested region.  Emits span_start/span_end, annotates the
-        jax profiler timeline by the same name, and samples memory
-        watermarks at both boundaries."""
-        with self._lock:
-            self._seq += 1
-            span_id = self._seq
-        st = self._stack()
-        parent = st[-1].span_id if st else None
-        sp = _Span(span_id, name, dict(attrs))
-        self._emit("span_start", name, span=span_id, parent=parent, attrs=attrs)
-        _sample_memory()
-        st.append(sp)
-        try:
-            import jax
-
-            ann = jax.profiler.TraceAnnotation(name)
-        except Exception:
-            ann = contextlib.nullcontext()
-        try:
-            with ann:
-                yield sp
-        finally:
-            st.pop()
-            mem = _sample_memory()
-            end_attrs = dict(sp.attrs)
-            end_attrs.update(mem)
-            self._emit(
-                "span_end",
-                name,
-                span=span_id,
-                parent=parent,
-                attrs=end_attrs,
-                seconds=time.perf_counter() - sp.t0,
-            )
 
     def metrics_snapshot(self) -> None:
         """Embed the current registry snapshot as one ``metrics`` line
@@ -424,6 +516,7 @@ class RunLedger:
     def close(self, snapshot: bool = True) -> None:
         if self._closed:
             return
+        _sample_memory()
         if snapshot:
             self.metrics_snapshot()
         self._emit("run_end", "run")
@@ -440,7 +533,7 @@ _ENV_LEDGER: Optional[RunLedger] = None  # lazily created from KEYSTONE_OBS_DIR
 
 
 def active() -> Optional[RunLedger]:
-    """The current ledger, or None (the inert default).  An explicit
+    """The current JSONL ledger, or None (the default: spans stay in memory).  An explicit
     ``start_run``/``attach`` ledger wins; otherwise ``KEYSTONE_OBS_DIR``
     lazily creates one process-wide run."""
     if _ACTIVE is not None:
@@ -494,73 +587,57 @@ def event(name: str, **attrs) -> None:
         led.event(name, **attrs)
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs):
-    """Timed span on the active ledger; yields the span handle (or None
-    when inert) so callers can ``sp.set(...)`` extra attrs."""
-    led = active()
-    if led is None:
-        yield None
-        return
-    with led.span(name, **attrs) as sp:
-        yield sp
-
-
 def capture_context():
     """Snapshot the calling thread's open-span stack (opaque token).
     The span stack is thread-local, so work handed to a worker thread —
     ``utils/guard.run_with_deadline`` watchdogs are the in-repo case —
-    would otherwise emit spans/events with no parent.  Capture on the
-    calling thread, :func:`restore_context` inside the worker, and the
-    worker's spans nest where the caller's would have."""
-    led = active()
-    if led is None:
-        return None
-    return (led, list(led._stack()))
+    would otherwise record spans/events with no parent and a root of
+    their own.  Capture on the calling thread, :func:`restore_context`
+    inside the worker, and the worker's spans nest (and share the root)
+    where the caller's would have."""
+    return list(_stack())
 
 
 def restore_context(token) -> None:
     """Install a :func:`capture_context` snapshot on the CURRENT thread
     (a copy — the originating thread's stack is never shared or
     mutated).  No-op for a None token."""
-    if token is None:
-        return
-    led, stack = token
-    led._tls.stack = list(stack)
+    if token is not None:
+        _TLS.stack = list(token)
 
 
 def device_wait(x, account: str = "device.busy_seconds", force: bool = False):
-    """Block until ``x`` (any pytree of device values) is ready and
-    charge the wait to the device-busy account — ONLY when a ledger is
-    active.  Inert otherwise: no sync, no timing, the dispatch stream is
-    untouched — so programs and async pipelining are byte-for-byte the
-    pre-obs ones when observability is off.  Returns ``x``.
+    """Returns ``x``; with ``force=True``, after waiting for it (any
+    pytree of device values).
 
-    ``force=True`` syncs (and meters) unconditionally — for call sites
-    where the wait is REQUIRED regardless of observability (checkpoint
-    gathers, dispatch-queue flow control) and the metering rides along.
+    Observing never synchronises: without ``force`` this is the identity,
+    ledger or no ledger, so a traced run dispatches exactly as an
+    untraced one.  ``force=True`` is for call sites where the wait is
+    REQUIRED by the program itself (checkpoint gathers, dispatch-queue
+    flow control); the metering rides along: a ``device.wait`` span and
+    the seconds blocked charged to ``account``.
 
     The account is a host-side measure: seconds the host spent BLOCKED
-    on device results at natural drain points (solver finishes, epoch
-    boundaries).  Together with ``blockstore.stage_wait_seconds`` (time
-    blocked on host→device staging) it decomposes a fit's wall clock
-    into device-busy vs transfer vs host overhead —
-    ``tools/obs_report.py`` folds both into the ``dataflow`` summary the
-    bench artifact embeds."""
-    if not force and active() is None:
+    on device results at those waits.  Together with
+    ``blockstore.stage_wait_seconds`` (time blocked on host→device
+    staging) it is what ``tools/obs_report.py`` folds into the
+    ``dataflow`` summary the bench artifact embeds."""
+    if not force:
         return x
     import jax
 
-    t0 = time.perf_counter()
-    jax.block_until_ready(x)
-    metrics.observe(account, time.perf_counter() - t0)
+    with span("device.wait"):
+        t0 = time.perf_counter()
+        jax.block_until_ready(x)
+        metrics.observe(account, time.perf_counter() - t0)
     return x
 
 
 def solver_obs() -> bool:
-    """Should solvers trace per-epoch telemetry?  Resolved at trace time
-    and threaded as a STATIC jit argument, so the compiled program is
-    exactly the pre-obs one when this is False."""
+    """Should solvers trace per-epoch telemetry?  True only while a JSONL
+    ledger is active (the in-memory ring is not "active").  Resolved at
+    trace time and threaded as a STATIC jit argument, so the compiled
+    program is exactly the pre-obs one when this is False."""
     return active() is not None
 
 

@@ -72,6 +72,10 @@ class FisherVector(Transformer):
         )
         return (fp, self.use_pallas)
 
+    # the scope sits AROUND the kernel's own jit, not inside it: the compiler
+    # names the kernel's operation for the innermost name before
+    # `pallas_call`, and that name is how a device trace finds the kernel
+    @jax.named_scope("fv")
     def apply_batch(self, xs, mask=None):
         if xs.ndim == 2:
             xs = xs[None]
@@ -169,6 +173,10 @@ class FusedPcaFisherVector(Transformer):
         fp = cached_fingerprint(self, "_fp", *arrays)
         return (fp, self.sift_normalize, self.use_pallas, self.mean is None)
 
+    # the scope sits AROUND the kernel's own jit, not inside it: the compiler
+    # names the kernel's operation for the innermost name before
+    # `pallas_call`, and that name is how a device trace finds the kernel
+    @jax.named_scope("fv")
     def apply_batch(self, xs, mask=None):
         if xs.ndim == 2:
             xs = xs[None]

@@ -70,6 +70,7 @@ def _box_matrix(extent: int, sub: int) -> np.ndarray:
 
 
 @partial(jax.jit, static_argnames=("step", "sub", "mxu"))
+@jax.named_scope("lcs")
 def _lcs(xs, step, sub, mxu: str = "f32"):
     n, h, w, c = xs.shape
     area = float(sub * sub)

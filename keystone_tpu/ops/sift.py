@@ -228,6 +228,7 @@ def _gradient_orientation_map(imgs):
         "step", "bin_size", "mxu", "sigma", "windowing", "normalize"
     ),
 )
+@jax.named_scope("sift")
 def _dsift(
     imgs,
     step,

@@ -7,9 +7,11 @@ UI timeline, with apps logging coarse stage timings via the Logging trait
 
 - ``trace(logdir)`` / ``start_trace``/``stop_trace``: wrap
   ``jax.profiler`` to capture a device trace viewable in
-  TensorBoard/Perfetto — the Spark-UI-timeline replacement.
-- ``annotate(name)``: a named region (``jax.profiler.TraceAnnotation``)
-  so pipeline stages show up by name inside the trace.
+  TensorBoard/Perfetto — the Spark-UI-timeline replacement.  The
+  program's own spans (``obs/ledger.py § span``: ``pipeline.fit``,
+  ``executor.stage``, ...) are in its host plane by themselves.
+- ``annotate(name)``: one more named region
+  (``jax.profiler.TraceAnnotation``) inside the trace.
 - ``stage_timings(result)``: coarse per-node wall timings of a lazy
   pipeline result (the Logging-trait stage-timings replacement), using
   the executor's profiling mode (device-synchronized per node).

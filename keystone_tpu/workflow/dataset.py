@@ -23,7 +23,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from keystone_tpu.obs import ledger
 from keystone_tpu.parallel import mesh as _mesh
+
+
+def _to_device(arr, shard: bool = True):
+    """``arr`` on the mesh (or as one device array).  A HOST array's put is
+    a ``dataset.upload`` span: the host's time in it (staging copy and
+    enqueue — the transfer itself is asynchronous)."""
+    put = _mesh.shard_batch if shard else jnp.asarray
+    if isinstance(arr, jax.Array):
+        return put(arr)
+    arr = np.asarray(arr)
+    with ledger.span("dataset.upload", bytes=arr.nbytes):
+        return put(arr)
+
+
+def _to_host(arr) -> np.ndarray:
+    """Host copy of a device array, inside a ``dataset.readback`` span (the
+    wait for the array is part of it)."""
+    with ledger.span("dataset.readback", bytes=arr.nbytes):
+        return np.asarray(arr)
 
 
 class Dataset:
@@ -52,7 +72,7 @@ class Dataset:
                 arr = np.stack([np.asarray(a) for a in arr], axis=0)
             true_n = arr.shape[0] if n is None else n
             self._host = None
-            self._array = _mesh.shard_batch(arr) if shard else jnp.asarray(arr)
+            self._array = _to_device(arr, shard)
             self.n = true_n
             self.mask = mask
 
@@ -76,7 +96,7 @@ class Dataset:
 
     def numpy(self) -> np.ndarray:
         """Unpadded host copy."""
-        return np.asarray(self.array)[: self.n]
+        return _to_host(self.array)[: self.n]
 
     @property
     def item_shape(self) -> tuple:
@@ -267,7 +287,7 @@ class StreamDataset(Dataset):
         """Iterate host batches of the mapped values (numpy for device
         streams, lists for host streams)."""
         for arr, _ in self._gen():
-            yield arr if self._host_stream else np.asarray(arr)
+            yield arr if self._host_stream else _to_host(arr)
 
     def map_batches(self, fn, host: Optional[bool] = None) -> "StreamDataset":
         """Lazily compose a per-batch function ``fn(batch, mask)``
@@ -327,13 +347,12 @@ class StreamDataset(Dataset):
             parts = []
             masks = []
             for arr, mask in self._gen():
-                parts.append(np.asarray(arr))
+                parts.append(_to_host(arr))
                 if mask is not None:
-                    masks.append(np.asarray(mask))
-            arr = np.concatenate(parts, axis=0)
-            self._array = _mesh.shard_batch(arr)
+                    masks.append(_to_host(mask))
+            self._array = _to_device(np.concatenate(parts, axis=0))
             if masks:
-                self.mask = _mesh.shard_batch(np.concatenate(masks, axis=0))
+                self.mask = _to_device(np.concatenate(masks, axis=0))
         return self._array
 
     @property

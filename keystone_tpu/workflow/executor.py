@@ -269,14 +269,13 @@ class GraphExecutor:
                 # failed-attempt time is real cost, but it belongs to the
                 # RETRY budget, not the node's compute profile
                 metrics.inc("executor.failed_attempt_seconds", failed_seconds)
-            if sp is not None:
-                # attempts = stage-body executions actually started (0
-                # when the breaker refused the stage outright)
-                sp.set(attempts=attempts_made, retries=max(0, attempts_made - 1))
-                if degraded:
-                    sp.set(degraded=True)
-                if failed_seconds:
-                    sp.set(failed_attempt_seconds=failed_seconds)
+            # attempts = stage-body executions actually started (0
+            # when the breaker refused the stage outright)
+            sp.set(attempts=attempts_made, retries=max(0, attempts_made - 1))
+            if degraded:
+                sp.set(degraded=True)
+            if failed_seconds:
+                sp.set(failed_attempt_seconds=failed_seconds)
             if self.profile:
                 _sync_expr(result)
                 self.timings[target] = time.perf_counter() - t0

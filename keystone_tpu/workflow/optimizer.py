@@ -26,7 +26,7 @@ import jax
 
 from keystone_tpu.workflow import graph as G
 from keystone_tpu.workflow.estimator import Estimator
-from keystone_tpu.workflow.transformer import Cacher, Transformer
+from keystone_tpu.workflow.transformer import Cacher, Transformer, jit_named, mint_span
 
 logger = logging.getLogger(__name__)
 
@@ -63,29 +63,16 @@ class Optimizer:
         self.batches = list(batches)
 
     def execute(self, graph: G.Graph) -> G.Graph:
-        import time
+        from keystone_tpu.obs import ledger
 
-        from keystone_tpu.obs import ledger, metrics
-
-        with ledger.span("optimizer.execute"):
-            for batch in self.batches:
-                for _ in range(batch.strategy.max_iterations):
-                    before = _graph_fingerprint(graph)
-                    for rule in batch.rules:
-                        t0 = time.perf_counter()
+        for batch in self.batches:
+            for _ in range(batch.strategy.max_iterations):
+                before = _graph_fingerprint(graph)
+                for rule in batch.rules:
+                    with ledger.span("optimizer.rule", rule=rule.name, batch=batch.name):
                         graph = rule.apply(graph)
-                        dt = time.perf_counter() - t0
-                        metrics.observe(
-                            "optimizer.rule_seconds", dt, rule=rule.name
-                        )
-                        ledger.event(
-                            "optimizer.rule",
-                            rule=rule.name,
-                            batch=batch.name,
-                            seconds=dt,
-                        )
-                    if _graph_fingerprint(graph) == before:
-                        break
+                if _graph_fingerprint(graph) == before:
+                    break
         return graph
 
 
@@ -394,7 +381,8 @@ class FusedTransformer(Transformer):
             except (TypeError, jax.errors.JAXTypeError):
                 _FUSED_SHARED_CACHE[ckey] = None
         fn = self._jitted.get(mode)
-        if fn is None:
+        minted = fn is None
+        if minted:
             stages = list(self.stages)
 
             def run(arr):
@@ -402,8 +390,9 @@ class FusedTransformer(Transformer):
                     arr = s.apply_batch(arr)
                 return arr
 
-            fn = self._jitted[mode] = jax.jit(run)
-        return fn(xs)
+            fn = self._jitted[mode] = jit_named(run, stages)
+        with mint_span(minted, self.stages, shared=False):
+            return fn(xs)
 
     def _apply_shared(self, ckey, xs):
         """Cross-instance shared jitted chain: stage parameters ride as
@@ -416,7 +405,8 @@ class FusedTransformer(Transformer):
         entry = _FUSED_SHARED_CACHE.get(ckey, sentinel)
         if entry is None:  # memoized untraceable for this chain+signature
             raise TypeError("fused chain memoized untraceable")  # caller falls back
-        if entry is sentinel:
+        minted = entry is sentinel
+        if minted:
             # Bound the cache: chains whose stage params() embed per-fit
             # fingerprints mint a fresh key every refit, and each entry's
             # templates pin that fit's non-traced arrays.  FIFO-evict —
@@ -435,12 +425,13 @@ class FusedTransformer(Transformer):
                     arr = obj.apply_batch(arr)
                 return arr
 
-            entry = _FUSED_SHARED_CACHE[ckey] = jax.jit(run)
+            entry = _FUSED_SHARED_CACHE[ckey] = jit_named(run, self.stages)
         plist = [
             {name: getattr(s, name) for name in type(s).traced_attrs}
             for s in self.stages
         ]
-        return entry(plist, xs)
+        with mint_span(minted, self.stages, shared=True):
+            return entry(plist, xs)
 
 
 class StageFusionRule(Rule):

@@ -266,13 +266,15 @@ class Pipeline(Chainable):
         instead of stalling it forever.  Default None: no watchdog, no
         threads — the pre-deadline behavior exactly.
 
-        Observability: with ``KEYSTONE_OBS_DIR`` set (or a ledger
-        attached via ``obs.ledger.start_run``) the whole fit runs inside
-        a ``pipeline.fit`` span — per-stage executor spans, solver
-        convergence events, and I/O counters land in the run's JSONL
+        Observability: the whole fit runs inside a ``pipeline.fit`` span
+        (``obs/ledger.py``: in memory and in any profiler session, always)
+        with ``pipeline.optimize``, per-stage ``executor.stage`` and
+        ``solver.fit`` spans under it.  With ``KEYSTONE_OBS_DIR`` set (or
+        a ledger attached via ``obs.ledger.start_run``) the spans, solver
+        convergence events, and I/O counters also land in the run's JSONL
         ledger, and a metrics snapshot is flushed at fit end so
         ``tools/obs_report.py`` can summarize a run even if the process
-        later dies.  Unset, all hooks are inert."""
+        later dies."""
         if _validate_requested(validate):
             from keystone_tpu.analysis import validate_fit
 
@@ -293,9 +295,7 @@ class Pipeline(Chainable):
         return fitted_pipe
 
     def _fit_inner(self, deadline=None) -> "FittedPipeline":
-        opt = PipelineEnv.get_optimizer()
-        g = opt.execute(self.graph)
-        g = _auto_out_of_core(g)
+        g = _optimize(self.graph, PipelineEnv.get_optimizer().execute, _auto_out_of_core)
         # ONE executor (and one resolved Deadline) for every estimator
         # in the walk: memoized prefixes and the fit budget are shared
         ex = GraphExecutor(g, deadline=deadline)
@@ -324,7 +324,7 @@ class Pipeline(Chainable):
         # cold scoring run (the fit-overhead split of rounds 1–5, not re-measured).
         from keystone_tpu.workflow.optimizer import StageFusionRule
 
-        g = StageFusionRule().apply(g)
+        g = _optimize(g, StageFusionRule().apply)
         return FittedPipeline(g, self.source, self.sink)
 
     def freeze(self, validate=None, example=None, plan=None) -> "FrozenApplier":
@@ -581,8 +581,7 @@ class FrozenApplier:
             else:
                 self.plan = plan
             planner.install_plan(self.plan, source="freeze")
-        opt = PipelineEnv.get_optimizer()
-        self.graph = opt.execute(pipeline.graph)
+        self.graph = _optimize(pipeline.graph)
         self.source = pipeline.source
         self.sink = pipeline.sink
         #: the PRE-optimizer pipeline: the artifact signature hashes
@@ -1162,10 +1161,7 @@ class PipelineDataset:
         budget for the apply, apportioned per stage by the executor —
         the scoring-path twin of ``Pipeline.fit(deadline=…)``."""
         if self._result is None:
-            opt = PipelineEnv.get_optimizer()
-            g = opt.execute(self.graph)
-            ex = GraphExecutor(g, deadline=deadline)
-            expr = ex.execute(g.sink_dependencies.get(self.sink, self.sink))
+            expr = _apply(self.graph, self.sink, deadline)
             if not isinstance(expr, DatasetExpr):
                 raise TypeError(f"sink produced {type(expr).__name__}, expected dataset")
             self._result = expr.dataset
@@ -1186,9 +1182,7 @@ class PipelineDatum:
 
     def get(self, deadline=None):
         if not self._done:
-            g = PipelineEnv.get_optimizer().execute(self.graph)
-            ex = GraphExecutor(g, deadline=deadline)
-            expr = ex.execute(g.sink_dependencies.get(self.sink, self.sink))
+            expr = _apply(self.graph, self.sink, deadline)
             if not isinstance(expr, DatumExpr):
                 raise TypeError(f"sink produced {type(expr).__name__}, expected datum")
             self._result = expr.value
@@ -1197,6 +1191,30 @@ class PipelineDatum:
 
 
 # ----------------------------------------------------------------- helpers
+def _optimize(graph: G.Graph, *passes) -> G.Graph:
+    """``graph`` through ``passes`` (default: the optimizer's rule batches)
+    inside one ``pipeline.optimize`` span, whose ``nodes`` reads the
+    graph's size before at its start and after at its end."""
+    from keystone_tpu.obs import ledger
+
+    with ledger.span("pipeline.optimize", nodes=len(graph.operators)) as sp:
+        for run in passes or (PipelineEnv.get_optimizer().execute,):
+            graph = run(graph)
+        sp.set(nodes=len(graph.operators))
+    return graph
+
+
+def _apply(graph: G.Graph, sink: G.SinkId, deadline):
+    """Optimize + execute of one lazy result (a scoring call optimizes its
+    graph again every time), inside one ``pipeline.apply`` span."""
+    from keystone_tpu.obs import ledger
+
+    with ledger.span("pipeline.apply"):
+        g = _optimize(graph)
+        ex = GraphExecutor(g, deadline=deadline)
+        return ex.execute(g.sink_dependencies.get(sink, sink))
+
+
 def _splice_input(g: G.Graph, data):
     """Attach ``data`` (literal dataset or lazy PipelineDataset graph) to
     ``g``; returns (graph, dependency id of the data's value)."""

@@ -191,13 +191,18 @@ def _static_node_seconds(graph: G.Graph, ex, n: G.NodeId, op, full_n: int):
     ds = d.dataset
     arr_aval = jax.ShapeDtypeStruct((full_n,) + tuple(ds.array.shape[1:]), ds.array.dtype)
     t = op.transformer
-    if ds.mask is not None:
-        mask_aval = jax.ShapeDtypeStruct(
-            (full_n,) + tuple(ds.mask.shape[1:]), ds.mask.dtype
-        )
-        cost = hlo_stage_cost(lambda a, m: t.apply_batch(a, mask=m), arr_aval, mask_aval)
-    else:
-        cost = hlo_stage_cost(lambda a: t.apply_batch(a), arr_aval)
+    from keystone_tpu.obs import ledger
+
+    # a program traced, lowered and compiled (or loaded) anew on every
+    # optimizer pass — every fit, every scoring call — to be priced, never run
+    with ledger.span("transformer.jit_mint", node=type(t).__name__, shared=False):
+        if ds.mask is not None:
+            mask_aval = jax.ShapeDtypeStruct(
+                (full_n,) + tuple(ds.mask.shape[1:]), ds.mask.dtype
+            )
+            cost = hlo_stage_cost(lambda a, m: t.apply_batch(a, mask=m), arr_aval, mask_aval)
+        else:
+            cost = hlo_stage_cost(lambda a: t.apply_batch(a), arr_aval)
     return cost["seconds_est"] if cost else None
 
 

@@ -15,6 +15,8 @@ than the reference's per-datum formulation.
 
 from __future__ import annotations
 
+import contextlib
+import re
 import weakref
 from typing import Callable, Optional
 
@@ -32,6 +34,37 @@ _JIT_APPLY_CACHE = weakref.WeakKeyDictionary()
 #: (or None = memoized untraceable for that exact signature).  Values
 #: hold parameter-stripped template copies, never fitted arrays.
 _SHARED_APPLY_CACHE: dict = {}
+
+
+def _class_names(stages) -> str:
+    return "_".join(type(s).__name__ for s in stages)
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def jit_named(fn, stages):
+    """``jax.jit(fn)`` under a program name made of ``stages``' CLASS names:
+    ``jit_apply_<Class>`` for one transformer, ``jit_fused_<A>_<B>…`` for a
+    chain, so a device trace and ``module_s`` / ``module_runs`` split by
+    node.  Never of a label's text: the module name is part of the
+    persistent compile cache's key, and a parameter, seed, object id or
+    count in it would cut one shared program into one per instance."""
+    kind = "apply" if len(stages) == 1 else "fused"
+    name = re.sub(r"\W", "_", f"{kind}_{_class_names(stages)}", flags=re.ASCII)[:96]
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
+def mint_span(minted: bool, stages, shared: bool):
+    """A ``transformer.jit_mint`` span for the first call of a wrapper
+    that was just minted (the call that traces, lowers and compiles or
+    loads it); nothing for a cached wrapper's call."""
+    if not minted:
+        return _NO_SPAN
+    from keystone_tpu.obs import ledger
+
+    return ledger.span("transformer.jit_mint", node=_class_names(stages), shared=shared)
 
 
 def stripped_template(t: "Transformer") -> "Transformer":
@@ -393,16 +426,19 @@ class Transformer(Chainable):
         fn = entry.get(sig, sentinel)
         if fn is None:  # memoized "untraceable" FOR THIS SIGNATURE
             return self.apply_batch(xs, mask=mask)
-        if fn is sentinel:
+        minted = fn is sentinel
+        if minted:
             # weak cache, NOT an instance attribute: jitted callables are
             # unpicklable and must not ride along in FittedPipeline.save.
             # The closure holds weakref.ref(self) — closing over self
             # would make the cache VALUE pin its own KEY alive forever.
             self_ref = weakref.ref(self)
-            fn = jax.jit(lambda a, m: self_ref().apply_batch(a, mask=m))
-            entry[sig] = fn
+            fn = entry[sig] = jit_named(
+                lambda a, m: self_ref().apply_batch(a, mask=m), [self]
+            )
         try:
-            return fn(xs, mask)
+            with mint_span(minted, [self], shared=False):
+                return fn(xs, mask)
         except (TypeError, jax.errors.JAXTypeError):
             entry[sig] = None  # don't re-pay a failed trace for this sig
             import logging
@@ -448,7 +484,8 @@ class Transformer(Chainable):
         fn = _SHARED_APPLY_CACHE.get(key, sentinel)
         if fn is None:  # memoized "untraceable" for this exact signature
             return self.apply_batch(xs, mask=mask)
-        if fn is sentinel:
+        minted = fn is sentinel
+        if minted:
             template = stripped_template(self)
 
             def run(p, a, m):
@@ -457,9 +494,10 @@ class Transformer(Chainable):
                     setattr(obj, name, v)
                 return obj.apply_batch(a, mask=m)
 
-            fn = _SHARED_APPLY_CACHE[key] = jax.jit(run)
+            fn = _SHARED_APPLY_CACHE[key] = jit_named(run, [self])
         try:
-            return fn(params, xs, mask)
+            with mint_span(minted, [self], shared=True):
+                return fn(params, xs, mask)
         except (TypeError, jax.errors.JAXTypeError):
             _SHARED_APPLY_CACHE[key] = None
             import logging

@@ -1,0 +1,440 @@
+"""The one span primitive (``obs/ledger.py § span``): always on, in memory,
+in any profiler session, in the JSONL ledger only when one is active; the
+spans the workflow leaves; the names node programs get; and the benchmark's
+readers of both (``benchmark/layers/``).
+"""
+
+import collections
+import glob
+import os
+import sys
+import threading
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark import compile_log, harness  # noqa: E402
+from keystone_tpu.obs import ledger  # noqa: E402
+from keystone_tpu.workflow import Dataset, Pipeline  # noqa: E402
+
+pytestmark = pytest.mark.obs
+
+
+@pytest.fixture(autouse=True)
+def _no_ledger(monkeypatch):
+    monkeypatch.delenv(ledger.ENV_DIR, raising=False)
+    ledger.attach(None)
+    yield
+    ledger.stop_run()
+    ledger.attach(None)
+
+
+def _since(mark: int):
+    """The ring's records newer than span id ``mark``."""
+    return [r for r in ledger.recent_spans() if r.span_id > mark]
+
+
+def _mark() -> int:
+    with ledger.span("test.mark") as sp:
+        pass
+    return sp.span_id
+
+
+def _toy_fit(n=96, d=24, k=3, seed=0):
+    from keystone_tpu.models import BlockWeightedLeastSquaresEstimator
+    from keystone_tpu.ops import LinearRectifier
+
+    rng = np.random.default_rng(seed)
+    x = Dataset(rng.normal(size=(n, d)).astype(np.float32))
+    y = Dataset(np.where(rng.random((n, k)) < 0.3, 1.0, -1.0).astype(np.float32))
+    pipe = Pipeline.of(LinearRectifier(0.0)).and_then(
+        BlockWeightedLeastSquaresEstimator(block_size=8, num_iter=2, lam=1e-3,
+                                           mixture_weight=0.5), x, y
+    )
+    return pipe.fit().block_until_ready()
+
+
+# ------------------------------------------------------------------ the ring
+def test_ring_records_parent_root_and_self_time():
+    mark = _mark()
+    with ledger.span("outer", node="A") as outer:
+        with ledger.span("inner", n=1) as inner:
+            inner.set(rows=2)
+            with ledger.span("leaf"):
+                pass
+        with ledger.span("inner"):
+            pass
+    recs = {r.span_id: r for r in _since(mark)}
+    assert [r.name for r in recs.values()] == ["leaf", "inner", "inner", "outer"]
+    top = recs[outer.span_id]
+    assert top.parent_id is None and top.root_id == top.span_id
+    assert top.attrs == {"node": "A"}
+    first = recs[inner.span_id]
+    assert first.parent_id == top.span_id and first.root_id == top.span_id
+    assert first.attrs == {"n": 1, "rows": 2}
+    leaf = next(r for r in recs.values() if r.name == "leaf")
+    assert leaf.parent_id == first.span_id and leaf.root_id == top.span_id
+    own = ledger.self_seconds(list(recs.values()))
+    inners = [r for r in recs.values() if r.name == "inner"]
+    assert own[top.span_id] == pytest.approx(
+        (top.dur_ns - sum(r.dur_ns for r in inners)) / 1e9
+    )
+    assert own[first.span_id] == pytest.approx((first.dur_ns - leaf.dur_ns) / 1e9)
+    assert own[leaf.span_id] == pytest.approx(leaf.dur_ns / 1e9)
+    assert all(v >= 0 for v in own.values())
+
+
+def test_ring_nests_across_capture_context_threads():
+    mark = _mark()
+    with ledger.span("caller") as caller:
+        token = ledger.capture_context()
+
+        def work():
+            ledger.restore_context(token)
+            with ledger.span("worker.child"):
+                pass
+
+        t = threading.Thread(target=work)
+        t.start()
+        t.join(10)
+        assert not t.is_alive()
+    child = next(r for r in _since(mark) if r.name == "worker.child")
+    assert child.parent_id == caller.span_id and child.root_id == caller.span_id
+    # a thread that restores nothing starts a tree of its own
+    t = threading.Thread(target=lambda: ledger.span("orphan").__enter__().__exit__())
+    t.start()
+    t.join(10)
+    orphan = next(r for r in _since(mark) if r.name == "orphan")
+    assert orphan.parent_id is None and orphan.root_id == orphan.span_id
+
+
+def test_ring_is_bounded():
+    assert ledger._RING.maxlen == ledger.RING_SIZE == 32768
+    for _ in range(ledger.RING_SIZE + 10):
+        with ledger.span("filler"):
+            pass
+    assert len(ledger.recent_spans()) == ledger.RING_SIZE
+
+
+@pytest.mark.parametrize("value", [
+    np.zeros(3, np.float32), jnp.ones(2), {"k": 1}, [np.zeros(2)], object(),
+], ids=["numpy", "device_array", "dict", "list_of_arrays", "object"])
+@pytest.mark.parametrize("how", ["at_open", "at_set"])
+def test_non_scalar_attr_is_refused(value, how):
+    if how == "at_open":
+        with pytest.raises(TypeError, match="span attribute 'n'"):
+            ledger.span("s", n=value)
+        return
+    with ledger.span("s") as sp:
+        with pytest.raises(TypeError, match="span attribute 'n'"):
+            sp.set(n=value)
+    assert ledger.recent_spans()[-1].attrs == {}
+
+
+def test_scalar_attrs_and_request_id_lists_are_kept():
+    with ledger.span("s", node="a", n=3, seconds=0.5, degraded=True, error=None,
+                     request_ids=["r1", None]):
+        pass
+    assert ledger.recent_spans()[-1].attrs == {
+        "node": "a", "n": 3, "seconds": 0.5, "degraded": True, "error": None,
+        "request_ids": ["r1", None],
+    }
+
+
+# ------------------------------------------------------- what a fit leaves
+def test_fit_without_ledger_leaves_one_tree_and_no_file(tmp_path, monkeypatch):
+    monkeypatch.chdir(tmp_path)
+    sampled = []
+    monkeypatch.setattr(ledger, "_sample_memory", lambda: sampled.append(1) or {})
+    mark = _mark()
+    _toy_fit()
+    recs = _since(mark)
+    fits = [r for r in recs if r.name == "pipeline.fit"]
+    assert len(fits) == 1 and fits[0].parent_id is None
+    tree = [r for r in recs if r.root_id == fits[0].span_id]
+    names = collections.Counter(r.name for r in tree)
+    for name in ("pipeline.optimize", "optimizer.rule", "executor.stage", "solver.fit"):
+        assert names[name] >= 1, names
+    assert names["pipeline.optimize"] == 2  # the rule batches, the post-fit re-fusion
+    by_id = {r.span_id: r for r in tree}
+    for r in tree:
+        if r.name == "optimizer.rule":
+            assert by_id[r.parent_id].name == "pipeline.optimize"
+            assert set(r.attrs) == {"rule", "batch"}
+        if r.name == "solver.fit":
+            assert by_id[r.parent_id].name == "executor.stage"
+            assert r.attrs == {"solver": "bcd.weighted", "n": 96, "blocks": 3}
+        if r.name == "executor.stage":
+            assert by_id[r.parent_id].name == "pipeline.fit"
+            assert {"node", "node_id", "attempts", "retries"} <= set(r.attrs)
+    # the fit's data went up before the fit opened: root spans of their own
+    uploads = [r for r in recs if r.name == "dataset.upload" and r.parent_id is None]
+    assert {r.attrs["bytes"] for r in uploads} == {96 * 24 * 4, 96 * 3 * 4}
+    assert os.listdir(tmp_path) == [] and not sampled
+    assert ledger.active() is None and not ledger.solver_obs()
+
+
+def test_apply_leaves_a_pipeline_apply_tree_and_a_readback():
+    fitted = _toy_fit()
+    mark = _mark()
+    held = np.random.default_rng(5).normal(size=(16, 24)).astype(np.float32)
+    out = fitted(Dataset(held)).get().numpy()
+    assert out.shape == (16, 3)
+    recs = _since(mark)
+    roots = [r.name for r in recs if r.parent_id is None]
+    assert roots == ["dataset.upload", "pipeline.apply", "dataset.readback"]
+    apply = next(r for r in recs if r.name == "pipeline.apply")
+    under = collections.Counter(r.name for r in recs if r.root_id == apply.span_id)
+    assert under["pipeline.optimize"] == 1 and under["executor.stage"] >= 2
+    assert next(r for r in recs if r.name == "dataset.readback").attrs == {"bytes": 16 * 3 * 4}
+
+
+def test_jsonl_ledger_gets_the_same_spans_and_root_memory(tmp_path):
+    import json
+
+    led = ledger.start_run(str(tmp_path))
+    mark = _mark()
+    _toy_fit()
+    jax.effects_barrier()
+    path = led.path
+    ledger.stop_run()
+    events = [json.loads(line) for line in open(path)]
+    ends = {e["span"]: e for e in events if e["kind"] == "span_end"}
+    ring = {r.span_id: r for r in _since(mark)}
+    assert set(ring) <= set(ends)
+    for span_id, r in ring.items():
+        e = ends[span_id]
+        assert e["name"] == r.name and e.get("parent") == r.parent_id
+        assert e["seconds"] == pytest.approx(r.dur_ns / 1e9)
+    fit_end = next(e for e in ends.values() if e["name"] == "pipeline.fit")
+    assert "host_max_rss_bytes" in fit_end["attrs"]  # sampled at the ROOT's end only
+    stage_ends = [e for e in ends.values() if e["name"] == "executor.stage"]
+    assert stage_ends and not any("host_max_rss_bytes" in e["attrs"] for e in stage_ends)
+    starts = {e["span"]: e for e in events if e["kind"] == "span_start"}
+    optimize = [s for s in starts.values() if s["name"] == "pipeline.optimize"]
+    # nodes: the graph's size before at the start, after at the end (the
+    # re-fusion after the walk fuses the fitted mapper into its chain)
+    sizes = [(s["attrs"]["nodes"], ends[s["span"]]["attrs"]["nodes"]) for s in optimize]
+    assert len(sizes) == 2 and sizes[1][0] > sizes[1][1], sizes
+
+
+def test_tracing_never_synchronises(tmp_path, monkeypatch):
+    """With a JSONL ledger active ``fit_arrays`` blocks on the device exactly
+    as often as without one."""
+    from keystone_tpu.models import BlockWeightedLeastSquaresEstimator
+
+    rng = np.random.default_rng(7)
+    x = rng.normal(size=(64, 16)).astype(np.float32)
+    y = np.where(rng.random((64, 2)) < 0.5, 1.0, -1.0).astype(np.float32)
+    est = BlockWeightedLeastSquaresEstimator(block_size=8, num_iter=2, lam=1e-3,
+                                             mixture_weight=0.5)
+    calls = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready", lambda v: calls.append(1) or real(v))
+
+    def blocked() -> int:
+        before = len(calls)
+        est.fit_arrays(x, y)
+        return len(calls) - before
+
+    without = blocked()
+    ledger.start_run(str(tmp_path))
+    with_ledger = blocked()
+    jax.effects_barrier()
+    ledger.stop_run()
+    assert with_ledger == without
+    seen = len(calls)
+    assert ledger.device_wait(x) is x and len(calls) == seen  # unforced: the identity
+    mark = _mark()
+    ledger.device_wait(jnp.ones(3), force=True)
+    assert len(calls) == seen + 1 and [r.name for r in _since(mark)] == ["device.wait"]
+
+
+# ---------------------------------------------------- the profiler's clock
+def test_profiler_session_holds_the_programs_spans(tmp_path):
+    from jax.profiler import ProfileData
+
+    _toy_fit(seed=1)  # compiled before the session
+    with jax.profiler.trace(str(tmp_path)):
+        _toy_fit(seed=1)
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    host = [
+        ev for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host")
+        for line in plane.lines for ev in line.events
+    ]
+    fits = [ev for ev in host if ev.name == "pipeline.fit"]
+    stages = [ev for ev in host if ev.name == "executor.stage"]
+    assert len(fits) == 1 and len(stages) >= 3
+    lo, hi = fits[0].start_ns, fits[0].start_ns + fits[0].duration_ns
+    for ev in stages:
+        assert lo <= ev.start_ns and ev.start_ns + ev.duration_ns <= hi
+
+
+# ------------------------------------------------------------ program names
+def _module_name(jitted, *args) -> str:
+    return jitted.lower(*args).as_text().split("module @", 1)[1].split(" ", 1)[0]
+
+
+def test_shared_program_is_named_for_its_class_not_its_parameters():
+    from keystone_tpu.ops import CosineRandomFeatures
+
+    T = sys.modules["keystone_tpu.workflow.transformer"]  # the package exports a function of that name
+
+    a = CosineRandomFeatures.init(12, 32, gamma=0.1, seed=3)
+    b = CosineRandomFeatures.init(12, 32, gamma=0.1, seed=4)
+    data = Dataset(np.random.default_rng(0).normal(size=(40, 12)).astype(np.float32))
+    T._SHARED_APPLY_CACHE.clear()
+    log = compile_log.CompileLog().install()
+    mark = _mark()
+    ya = a(data).numpy()
+    after_first = log.snapshot()["backend_compiles"]
+    yb = b(data).numpy()
+    assert log.snapshot()["backend_compiles"] == after_first  # one program, two seeds
+    assert not np.allclose(ya, yb)
+    mints = [r for r in _since(mark) if r.name == "transformer.jit_mint"]
+    assert [r.attrs for r in mints] == [{"node": "CosineRandomFeatures", "shared": True}]
+    (fn,) = [f for k, f in T._SHARED_APPLY_CACHE.items() if k[0] is CosineRandomFeatures]
+    params = {"w": a.w, "b": a.b}
+    assert _module_name(fn, params, data.array, None) == "jit_apply_CosineRandomFeatures"
+
+
+def test_fused_chain_is_named_for_its_classes():
+    from keystone_tpu.ops import LinearRectifier, NormalizeRows, SignedHellingerMapper
+    from keystone_tpu.workflow.optimizer import FusedTransformer
+
+    chain = FusedTransformer([LinearRectifier(0.5), SignedHellingerMapper(), NormalizeRows()])
+    x = jnp.asarray(np.random.default_rng(1).normal(size=(8, 6)).astype(np.float32))
+    mark = _mark()
+    chain.apply_batch(x)
+    chain.apply_batch(x)
+    mints = [r.attrs["node"] for r in _since(mark) if r.name == "transformer.jit_mint"]
+    assert mints == ["LinearRectifier_SignedHellingerMapper_NormalizeRows"]
+    from keystone_tpu.workflow import optimizer as O
+
+    T = sys.modules["keystone_tpu.workflow.transformer"]
+    named = T.jit_named(lambda v: v, chain.stages)
+    assert _module_name(named, x) == "jit_fused_LinearRectifier_SignedHellingerMapper_NormalizeRows"
+    long = T.jit_named(lambda v: v, chain.stages * 12)
+    assert long.__name__ == ("fused_" + "_".join(type(s).__name__ for s in chain.stages * 12))[:96]
+    assert "jit_" + long.__name__.rstrip("_") == _module_name(long, x)
+    assert O._FUSED_SHARED_CACHE or chain._jitted  # the chain's wrapper is cached
+
+
+# ---------------------------------------------------------------- the readers
+def _rec(span_id, parent_id, root_id, name, t0_ms, dur_ms, **attrs):
+    return ledger.SpanRecord(span_id, parent_id, root_id, name, int(t0_ms * 1e6),
+                             int(dur_ms * 1e6), attrs)
+
+
+def _fit_tree(base: int, t0: float):
+    """One hand-made fit of 100 ms: optimize 10 + 4 ms (its rule samples one
+    node for 2 ms), two stages of 30 and 20 ms holding a mint of 6 ms and a
+    solver dispatch of 5 ms."""
+    fit = base
+    return [
+        _rec(base + 8, base + 2, fit, "executor.stage", t0 + 2, 2, node="A", node_id=1),
+        _rec(base + 2, base + 1, fit, "optimizer.rule", t0 + 1, 8, rule="r", batch="b"),
+        _rec(base + 1, fit, fit, "pipeline.optimize", t0, 10, nodes=5),
+        _rec(base + 4, base + 3, fit, "transformer.jit_mint", t0 + 12, 6, node="A", shared=False),
+        _rec(base + 3, fit, fit, "executor.stage", t0 + 11, 30, node="A", node_id=1),
+        _rec(base + 6, base + 5, fit, "solver.fit", t0 + 45, 5, solver="bcd", n=8, blocks=2),
+        _rec(base + 5, fit, fit, "executor.stage", t0 + 42, 20, node="B", node_id=2),
+        _rec(base + 7, fit, fit, "pipeline.optimize", t0 + 70, 4, nodes=3),
+        _rec(fit, None, fit, "pipeline.fit", t0, 100),
+    ]
+
+
+def _call_tree(base: int, t0: float):
+    """One hand-made scoring call: upload 20 ms, then an apply of 200 ms with
+    12 ms of optimizer and a 3 ms put inside it, then the readback."""
+    apply = base + 1
+    return [
+        _rec(base, None, base, "dataset.upload", t0, 20, bytes=1000),
+        _rec(base + 2, apply, apply, "pipeline.optimize", t0 + 21, 12, nodes=9),
+        _rec(base + 4, base + 3, apply, "dataset.upload", t0 + 40, 3, bytes=10),
+        _rec(base + 3, apply, apply, "executor.stage", t0 + 35, 100, node="A", node_id=1),
+        _rec(apply, None, apply, "pipeline.apply", t0 + 20, 200),
+        _rec(base + 5, None, base + 5, "dataset.readback", t0 + 220, 5, bytes=64),
+    ]
+
+
+_FIT_RING = (
+    _fit_tree(100, 0) + [_rec(150, None, 150, "dataset.upload", 100, 1, bytes=8)]  # set-up
+    + _fit_tree(200, 200) + _fit_tree(300, 400)  # the window's two fits
+    + _call_tree(400, 600)  # the check's apply, after the window
+)
+_SCORE_RING = _call_tree(100, 0) + _call_tree(200, 300) + _call_tree(300, 600)
+_TRACE = {
+    "devices": 1,
+    "module_s": {"jit_fused_PixelScaler_GrayScaler_SIFTExtractor": 0.8,
+                 "jit_apply_SIFTExtractor": 0.2, "jit_fused_PixelScaler_LCSExtractor": 0.5,
+                 "jit__weighted_bcd_fit": 3.0},
+    "module_runs": {"jit_apply_A": 6, "jit_fused_A_B": 4, "jit__weighted_bcd_fit": 2,
+                    "jit_concatenate": 30},
+}
+_FIT_COUNTERS = {"units": 2, "elapsed": 1.0}
+_SCORE_COUNTERS = {"units": 2000, "calls": 2, "elapsed": 1.0}
+
+READERS = [
+    ("fit_optimize_s", _FIT_RING, _FIT_COUNTERS, 0.014),
+    ("fit_jit_mints", _FIT_RING, _FIT_COUNTERS, 1.0),
+    ("fit_stage_host_s", _FIT_RING, _FIT_COUNTERS, 0.024 + 0.015),
+    ("fit_node_launches", _FIT_RING, _FIT_COUNTERS, 5.0),
+    ("sift_device_us_per_image", _SCORE_RING, _SCORE_COUNTERS, 500.0),
+    ("lcs_device_us_per_image", _SCORE_RING, _SCORE_COUNTERS, 250.0),
+    ("score_optimize_s", _SCORE_RING, _SCORE_COUNTERS, 0.012),
+    ("score_upload_host_s", _SCORE_RING, _SCORE_COUNTERS, 0.023),
+]
+_FROM_TRACE = {"fit_node_launches", "sift_device_us_per_image", "lcs_device_us_per_image"}
+
+
+def _ctx(counters, trace=_TRACE):
+    return types.SimpleNamespace(counters=dict(counters), trace=trace)
+
+
+@pytest.mark.parametrize("metric,ring,counters,want", READERS, ids=[r[0] for r in READERS])
+def test_reader_on_a_hand_made_ring_and_reduction(monkeypatch, metric, ring, counters, want):
+    monkeypatch.setattr(ledger, "_RING", collections.deque(ring, maxlen=ledger.RING_SIZE))
+    assert harness.load_reader(metric).read(_ctx(counters)) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("metric,ring,counters,want", READERS, ids=[r[0] for r in READERS])
+def test_reader_finds_nothing_to_read(monkeypatch, metric, ring, counters, want):
+    read = harness.load_reader(metric).read
+    if metric in _FROM_TRACE:
+        # no device plane (a CPU rehearsal), or a program whose node programs
+        # are still called jit__lambda_ and jit_run (the parent commit)
+        assert read(_ctx(counters, trace={"devices": 0})) is None
+        old = {"devices": 1, "module_s": {"jit__lambda_": 1.0, "jit_run": 2.0},
+               "module_runs": {"jit__lambda_": 3, "jit_run": 4}}
+        assert read(_ctx(counters, trace=old)) is None
+        return
+    monkeypatch.setattr(ledger, "_RING", collections.deque(maxlen=ledger.RING_SIZE))
+    assert read(_ctx(counters)) is None  # an empty ring
+    # a ring that wrapped inside the window: exactly full, and no older root
+    # of the name shows that the first root's descendants are all still here
+    n = counters.get("calls", counters["units"])
+    tail = [r for r in ring if r.span_id >= (300 - 100 * (n - 1))]
+    monkeypatch.setattr(ledger, "_RING", collections.deque(tail, maxlen=len(tail)))
+    monkeypatch.setattr(ledger, "RING_SIZE", len(tail))
+    assert read(_ctx(counters)) is None
+    # a program without the ring (the parent commit): nothing, and no raise
+    monkeypatch.delattr(ledger, "recent_spans")
+    assert read(_ctx(counters)) is None
+
+
+def test_new_metrics_are_declared_with_their_readers():
+    bench = harness.load_json(ROOT, "BENCHMARK.json")
+    declared = {m["name"]: m for m in bench["per_layer"]}
+    for metric, *_ in READERS:
+        assert metric in declared and os.path.isfile(
+            os.path.join(ROOT, "benchmark", "layers", metric + ".py")
+        )
+    assert declared["score_upload_host_s"]["layer"] == "host link"
+    assert declared["sift_device_us_per_image"]["unit"] == "us"

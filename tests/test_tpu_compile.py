@@ -133,3 +133,31 @@ def test_compiled_fv_program_holds_the_custom_call(one_chip, no_persistent_cache
         .as_text()
     )
     assert "tpu_custom_call" in text
+
+
+def test_fv_scope_leaves_the_kernels_operation_name(one_chip, no_persistent_cache):
+    """The FV node's ``jax.named_scope("fv")`` is in the operation's metadata
+    and the kernel keeps the name a device trace finds it by
+    (``benchmark/layers/fv_kernel_roofline.py``): a scope INSIDE the kernel's
+    jit would rename the operation to ``fv.N``."""
+    import re
+
+    import numpy as np
+
+    from keystone_tpu.models.gmm import GaussianMixtureModel
+    from keystone_tpu.ops import fisher
+
+    gmm = GaussianMixtureModel(
+        np.full(K, 1.0 / K, np.float32), np.zeros((K, D_PCA), np.float32),
+        np.ones((K, D_PCA), np.float32),
+    )
+    node = fisher.FisherVector(gmm, use_pallas=True)
+    text = (
+        jax.jit(lambda a: node.apply_batch(a))
+        .lower(_f32(one_chip, 8, T, D_PCA))
+        .compile()
+        .as_text()
+    )
+    (line,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert line.strip().startswith("%fisher_encode_pallas")
+    assert "/fv/" in re.search(r'op_name="([^"]*)"', line).group(1)

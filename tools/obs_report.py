@@ -13,6 +13,8 @@ Sections (each only when the run recorded it):
 
 - **stages**: top executor stages by total span seconds, with attempt /
   retry counts and failed-attempt time;
+- **optimizer**: seconds and runs per optimizer rule (``optimizer.rule``
+  spans);
 - **retries**: retry totals across executor, durable I/O, blockstore,
   and streams;
 - **convergence**: per-solver epoch series (objective / grad norm /
@@ -126,6 +128,15 @@ def summarize(path: str, top_k: int = 10) -> dict:
         for st in top
     ]
 
+    # --------------------------------------------------------- optimizer
+    optimizer: Dict[str, dict] = {}
+    for e in events:
+        if e.get("kind") == "span_end" and e.get("name") == "optimizer.rule":
+            rule = str((e.get("attrs") or {}).get("rule", "?"))
+            st = optimizer.setdefault(rule, {"seconds": 0.0, "count": 0})
+            st["seconds"] += float(e.get("seconds") or 0.0)
+            st["count"] += 1
+
     # ------------------------------------------------------- convergence
     convergence: Dict[str, List[dict]] = {}
     for e in events:
@@ -176,12 +187,13 @@ def summarize(path: str, top_k: int = 10) -> dict:
 
     # -------------------------------------------------------- dataflow
     # the fit-path dataflow accounts (host-side measures): seconds the
-    # host spent BLOCKED on device results (obs-gated solver syncs,
-    # ledger.device_wait) vs blocked on host→device staging
-    # (blockstore.iter_device_blocks).  The busy fraction is the
-    # tentpole metric of the async-feed work: a starved device shows a
-    # fraction near zero; a fed one approaches the solver's share of
-    # wall time.
+    # host spent BLOCKED on device results at the waits the program needs
+    # anyway (ledger.device_wait(force=True): flow control, checkpoint
+    # gathers — observing adds no wait) vs blocked on host→device staging
+    # (blockstore.iter_device_blocks).  The fraction is the blocked share
+    # of the ledger's wall time; the DEVICE's busy and idle share come
+    # from a device trace (the benchmark's device_idle_pct.*), not from
+    # here.
     def _hist_sum(name: str) -> float:
         return sum(
             float(h.get("sum") or 0.0)
@@ -197,8 +209,8 @@ def summarize(path: str, top_k: int = 10) -> dict:
         "hbm_peak_bytes_in_use": gauges.get("hbm.peak_bytes_in_use"),
         "host_max_rss_bytes": gauges.get("host.max_rss_bytes"),
     }
-    # span-boundary samples are watermarks too (a run killed before its
-    # snapshot still has per-span samples)
+    # root-span samples are watermarks too (a run killed before its
+    # snapshot still has one sample per finished fit or apply)
     for e in events:
         if e.get("kind") == "span_end":
             attrs = e.get("attrs") or {}
@@ -338,6 +350,7 @@ def summarize(path: str, top_k: int = 10) -> dict:
         "events": len(events),
         "wall_seconds": wall,
         "stage_top": stage_top,
+        "optimizer": optimizer,
         "retries": retries,
         "convergence": convergence,
         "io": io,
@@ -384,6 +397,13 @@ def render(summary: dict) -> str:
                 f"{st['retries']:>7}  {st['failed_attempt_seconds']:>8.3f}  "
                 f"{st['node']}"
             )
+
+    if summary.get("optimizer"):
+        out.append("\n== optimizer rules ==")
+        for rule, st in sorted(
+            summary["optimizer"].items(), key=lambda kv: -kv[1]["seconds"]
+        ):
+            out.append(f"  {st['seconds']:>9.3f}  {st['count']:>4}  {rule}")
 
     r = summary.get("retries") or {}
     if any(v for v in r.values()):
