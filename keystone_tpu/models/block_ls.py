@@ -32,7 +32,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from keystone_tpu.models.common import constrain, solve_spd
-from keystone_tpu.parallel.collectives import sharded_gram, sharded_matmul
+from keystone_tpu.parallel.collectives import (
+    gram_panels,
+    sharded_gram,
+    sharded_matmul,
+)
 from jax.sharding import PartitionSpec as P
 from keystone_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from keystone_tpu.workflow.dataset import Dataset
@@ -281,6 +285,7 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         with ledger.span(
             "solver.fit", solver="bcd", n=int(n),
             blocks=-(-x.shape[1] // self.block_size),
+            gram_panels=gram_panels(self.block_size),
         ):
             weights = _bcd_fit(
                 blockify(xc, self.block_size),

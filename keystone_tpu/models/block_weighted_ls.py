@@ -28,7 +28,11 @@ from jax import lax
 
 from keystone_tpu.models.block_ls import BlockLinearMapper, blockify
 from keystone_tpu.models.common import constrain, solve_spd
-from keystone_tpu.parallel.collectives import sharded_gram, sharded_matmul
+from keystone_tpu.parallel.collectives import (
+    gram_panels,
+    sharded_gram,
+    sharded_matmul,
+)
 from jax.sharding import PartitionSpec as P
 from keystone_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from keystone_tpu.workflow.dataset import Dataset
@@ -176,6 +180,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         with ledger.span(
             "solver.fit", solver="bcd.weighted", n=int(n),
             blocks=-(-x.shape[1] // self.block_size),
+            gram_panels=gram_panels(self.block_size),
         ):
             alpha = class_weights(y, nf, self.mixture_weight)
             weights, xm, ym = _weighted_bcd_fit(

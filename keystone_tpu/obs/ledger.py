@@ -113,6 +113,7 @@ ATTR_VOCABULARY = {
     "from_replica",
     "from_version",
     "grad_norm",
+    "gram_panels",
     "host",
     "instances",
     "it",

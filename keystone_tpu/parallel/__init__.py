@@ -33,6 +33,7 @@ from keystone_tpu.parallel.mesh import (  # noqa: F401
     use_mesh,
 )
 from keystone_tpu.parallel.collectives import (  # noqa: F401
+    gram_panels,
     pmean,
     psum,
     sharded_gram,

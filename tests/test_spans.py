@@ -169,7 +169,9 @@ def test_fit_without_ledger_leaves_one_tree_and_no_file(tmp_path, monkeypatch):
             assert set(r.attrs) == {"rule", "batch"}
         if r.name == "solver.fit":
             assert by_id[r.parent_id].name == "executor.stage"
-            assert r.attrs == {"solver": "bcd.weighted", "n": 96, "blocks": 3}
+            assert r.attrs == {
+                "solver": "bcd.weighted", "n": 96, "blocks": 3, "gram_panels": 1,
+            }
         if r.name == "executor.stage":
             assert by_id[r.parent_id].name == "pipeline.fit"
             assert {"node", "node_id", "attempts", "retries"} <= set(r.attrs)

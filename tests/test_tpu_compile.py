@@ -1,4 +1,5 @@
-"""The main path's Pallas kernels, compiled for a described TPU v5e.
+"""The main path's Pallas kernels and the solver's panelled Gramian,
+compiled for a described TPU v5e.
 
 The TPU compiler is installed here and compiles for a chip that is
 described and not attached (``v5e:2x2``): it refuses what the chip
@@ -19,10 +20,12 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from keystone_tpu.ops.gram_pallas import GRAM_MAX_D
+from keystone_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -142,8 +145,6 @@ def test_fv_scope_leaves_the_kernels_operation_name(one_chip, no_persistent_cach
     jit would rename the operation to ``fv.N``."""
     import re
 
-    import numpy as np
-
     from keystone_tpu.models.gmm import GaussianMixtureModel
     from keystone_tpu.ops import fisher
 
@@ -161,3 +162,86 @@ def test_fv_scope_leaves_the_kernels_operation_name(one_chip, no_persistent_cach
     (line,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " custom-call(" in ln]
     assert line.strip().startswith("%fisher_encode_pallas")
     assert "/fv/" in re.search(r'op_name="([^"]*)"', line).group(1)
+
+
+def _v5e_mesh(topo, chips):
+    return Mesh(np.array(topo.devices[:chips]).reshape(chips, 1), (DATA_AXIS, MODEL_AXIS))
+
+
+def _rows_over(mesh, *shape):
+    return _f32(NamedSharding(mesh, P(DATA_AXIS, None)), *shape)
+
+
+@pytest.mark.parametrize("n", [4096, 8192, 16384])
+def test_panelled_gram_compiles_for_v5e(topo, no_persistent_cache, n):
+    """The solver's Gramian at block width 4096 and the three cells' row
+    counts (a chip): the upper block triangle in sixteen panels is under six
+    tenths of the one dot's flops by the TPU compiler's own count, and
+    needs no buffer beside its 64 MB result."""
+    from keystone_tpu.parallel.collectives import (
+        gram_panels,
+        sharded_gram,
+        sharded_matmul,
+    )
+
+    mesh = _v5e_mesh(topo, 1)
+    a = _rows_over(mesh, n, GRAM_BLOCK)
+    assert gram_panels(GRAM_BLOCK) == 16
+    panelled = jax.jit(lambda x: sharded_gram(x, mesh=mesh)).lower(a).compile()
+    one_dot = jax.jit(lambda x: sharded_matmul(x, x, mesh=mesh)).lower(a).compile()
+    assert panelled.cost_analysis()["flops"] <= 0.6 * one_dot.cost_analysis()["flops"]
+    memory = panelled.memory_analysis()
+    assert memory.output_size_in_bytes == GRAM_BLOCK * GRAM_BLOCK * 4
+    assert memory.temp_size_in_bytes == 0
+
+
+def test_panelled_gram_all_reduces_its_strips_across_four_chips(topo, no_persistent_cache):
+    """Rows over the 2x2 host: the strips' partial sums are all-reduced (136
+    of 256 tiles), never a whole ``f32[4096,4096]``, and the mirror is local."""
+    import re
+
+    from keystone_tpu.parallel.collectives import sharded_gram
+
+    mesh = _v5e_mesh(topo, 4)
+    text = (
+        jax.jit(lambda x: sharded_gram(x, mesh=mesh))
+        .lower(_rows_over(mesh, 4 * 4096, GRAM_BLOCK))
+        .compile()
+        .as_text()
+    )
+    reduced = [
+        [int(d) for d in shape.split(",")]
+        for ln in text.splitlines()
+        for call in (" all-reduce(", " all-reduce-start(")
+        if call in ln
+        for shape in re.findall(r"f32\[(\d+,\d+)\]", ln.split(call)[0])
+    ]
+    assert reduced and [GRAM_BLOCK, GRAM_BLOCK] not in reduced
+    tile = GRAM_BLOCK // 16
+    assert sum(r * c for r, c in reduced) == 136 * tile * tile
+
+
+def test_weighted_bcd_program_keeps_its_name_and_panels(topo, no_persistent_cache):
+    """``bcd_roofline`` finds the solver's device time by the program name
+    ``weighted_bcd_fit``; the panelled Gramian stays inside that program
+    (sixteen ``highest`` products where there was one) under ``bcd.gram``."""
+    from keystone_tpu.models import block_weighted_ls as bw
+    from keystone_tpu.parallel import use_mesh
+
+    mesh = _v5e_mesh(topo, 1)
+    n, blocks, k = 1024, 2, 16
+    with use_mesh(mesh):
+        lowered = bw._weighted_bcd_fit.lower(
+            _rows_over(mesh, n, blocks * GRAM_BLOCK), _rows_over(mesh, n, k),
+            _f32(NamedSharding(mesh, P()), n),
+            jnp.float32(n), 1e-4, num_iter=1, block_size=GRAM_BLOCK, fit_intercept=True,
+        )
+    lines = lowered.as_text(debug_info=True).splitlines()
+    assert any(ln.startswith("module @") and "weighted_bcd_fit" in ln for ln in lines)
+    assert "weighted_bcd_fit" in lowered.compile().as_text().splitlines()[0]
+    gram_locs = [ln.split(" = ")[0] for ln in lines if ln.startswith("#loc") and "bcd.gram/dot_general" in ln]
+    gram_dots = [
+        ln for ln in lines
+        if "stablehlo.dot_general" in ln and any(ln.endswith(f"loc({loc})") for loc in gram_locs)
+    ]
+    assert len(gram_dots) == 16 and all("[HIGHEST, HIGHEST]" in ln for ln in gram_dots)

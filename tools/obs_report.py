@@ -15,6 +15,9 @@ Sections (each only when the run recorded it):
   retry counts and failed-attempt time;
 - **optimizer**: seconds and runs per optimizer rule (``optimizer.rule``
   spans);
+- **solvers**: fits, host seconds and the static shape of each solver's
+  ``solver.fit`` spans (``n``, ``blocks``, and ``gram_panels`` — how many
+  column panels the block Gramian was split into; 1 is the one dot);
 - **retries**: retry totals across executor, durable I/O, blockstore,
   and streams;
 - **convergence**: per-solver epoch series (objective / grad norm /
@@ -136,6 +139,18 @@ def summarize(path: str, top_k: int = 10) -> dict:
             st = optimizer.setdefault(rule, {"seconds": 0.0, "count": 0})
             st["seconds"] += float(e.get("seconds") or 0.0)
             st["count"] += 1
+
+    # ----------------------------------------------------------- solvers
+    solvers: Dict[str, dict] = {}
+    for e in events:
+        if e.get("kind") == "span_end" and e.get("name") == "solver.fit":
+            attrs = dict(e.get("attrs") or {})
+            st = solvers.setdefault(
+                str(attrs.pop("solver", "?")), {"seconds": 0.0, "count": 0}
+            )
+            st["seconds"] += float(e.get("seconds") or 0.0)
+            st["count"] += 1
+            st.update(attrs)  # static per fit: the last fit's shape
 
     # ------------------------------------------------------- convergence
     convergence: Dict[str, List[dict]] = {}
@@ -351,6 +366,7 @@ def summarize(path: str, top_k: int = 10) -> dict:
         "wall_seconds": wall,
         "stage_top": stage_top,
         "optimizer": optimizer,
+        "solvers": solvers,
         "retries": retries,
         "convergence": convergence,
         "io": io,
@@ -404,6 +420,16 @@ def render(summary: dict) -> str:
             summary["optimizer"].items(), key=lambda kv: -kv[1]["seconds"]
         ):
             out.append(f"  {st['seconds']:>9.3f}  {st['count']:>4}  {rule}")
+
+    if summary.get("solvers"):
+        out.append("\n== solver fits ==")
+        for solver, st in sorted(summary["solvers"].items()):
+            shape = "  ".join(
+                f"{k}={v}" for k, v in st.items() if k not in ("seconds", "count")
+            )
+            out.append(
+                f"  {st['seconds']:>9.3f}  {st['count']:>4}  {solver}  {shape}"
+            )
 
     r = summary.get("retries") or {}
     if any(v for v in r.values()):
