@@ -179,6 +179,22 @@ def _kernel_cifar():
     )
 
 
+def _kernel_ridge_timit():
+    from keystone_tpu.loaders.timit import TimitFeaturesDataLoader
+    from keystone_tpu.pipelines.kernel_ridge_timit import KernelRidgeTimitPipeline
+
+    cfg = KernelRidgeTimitPipeline.Config(
+        block_size=64, num_classes=8, synthetic_n=256
+    )
+    train = TimitFeaturesDataLoader.synthetic(
+        cfg.synthetic_n, cfg.num_classes, seed=1
+    )
+    return (
+        KernelRidgeTimitPipeline.build(cfg, train.data, train.labels),
+        train.data,
+    )
+
+
 _BUILDERS = {
     "MnistRandomFFT": _mnist,
     "LinearPixels": _linear_pixels,
@@ -190,6 +206,7 @@ _BUILDERS = {
     "AmazonReviewsPipeline": _amazon,
     "KernelTimitPipeline": _kernel_timit,
     "KernelCifarPipeline": _kernel_cifar,
+    "KernelRidgeTimitPipeline": _kernel_ridge_timit,
 }
 
 BUNDLED = tuple(_BUILDERS)

@@ -25,6 +25,7 @@ _PIPELINE_MODULES = {
     "AmazonReviewsPipeline": "keystone_tpu.pipelines.amazon_reviews",
     "KernelTimitPipeline": "keystone_tpu.pipelines.kernel_timit",
     "KernelCifarPipeline": "keystone_tpu.pipelines.kernel_cifar",
+    "KernelRidgeTimitPipeline": "keystone_tpu.pipelines.kernel_ridge_timit",
 }
 
 

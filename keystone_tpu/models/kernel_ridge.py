@@ -99,6 +99,14 @@ class KernelBlockLinearMapper(Transformer):
     test×train kernel never fully materializes
     (KernelBlockLinearMapper.scala)."""
 
+    # the model IS the train rows and α (462 MB at n=196,608): traced
+    # arguments of one class-shared program, never constants embedded in
+    # a program per fit (Transformer.traced_attrs)
+    traced_attrs = ("train_x", "alpha")
+
+    def jit_static(self):
+        return (float(self.kernel_gen.gamma), self.block_size)
+
     def __init__(self, kernel_gen, train_x, alpha, block_size: int, train_n: int):
         self.kernel_gen = kernel_gen
         self.train_x = train_x  # (n_rows, d), padded
@@ -120,11 +128,14 @@ class KernelRidgeRegressionEstimator(LabelEstimator):
     kernel column blocks (KernelMatrix.scala § BlockKernelMatrix): the
     fit sweeps through a BlockKernelMatrix LRU, so epochs ≥ 2 reread
     cached blocks (n² HBM) instead of recomputing the ‖x−z‖² gemms.
-    Measured on v5 lite (rounds 1–5, not re-measured): the
-    recompute sweep wins below d≈2·10³ (~4× at d=64, ~1.3× at d=1024) —
-    the MXU regenerates blocks faster than HBM rereads them while the
-    gemm is small — so recompute stays the default; caching wins for
-    wide features (~2.2× at d=4096, n=8k) when K fits HBM."""
+    Whether rereading beats recomputing at any width has NO number on
+    the v5e: the crossover once quoted here (d ≈ 2·10³) was never
+    re-measured, and the cached sweep runs in no benchmark cell.  What
+    was read at d = 440 (my chip runs, PR 25): one 196,608 × 4096 column
+    block takes 27.0 ms on the XLA chain and 27.9 ms through the Pallas
+    kernel (a six-pass f32 distance gemm either way), and rereading its
+    3.2 GB from HBM would take 3.9 ms — but K is n² (155 GB at that n),
+    so the in-core sweep recomputes, and recompute stays the default."""
 
     # class-level default for pre-option pickles
     kernel_cache_dir = None
@@ -261,31 +272,49 @@ class KernelRidgeRegressionEstimator(LabelEstimator):
         if nb * bs != n_rows:
             x = jnp.pad(x, ((0, nb * bs - n_rows), (0, 0)))
             y = jnp.pad(y, ((0, nb * bs - n_rows), (0, 0)))
-        if self.cache_kernel_blocks:
-            alpha = _krr_fit_cached(
-                x,
-                y,
-                n,
-                self.kernel_gen,
-                self.lam,
-                bs,
-                self.num_epochs,
-                cache_dir=self.kernel_cache_dir,
-            )
-        else:
-            from keystone_tpu.obs import ledger
+        from keystone_tpu.obs import ledger
+        from keystone_tpu.ops.gram_pallas import gram_pallas_enabled
 
-            with ledger.span("solver.fit", solver="krr", n=int(n), blocks=nb):
+        # the gram_pallas gate, resolved once per fit (the cached sweep's
+        # BlockKernelMatrix asks the same gate for its tiles)
+        use_pallas = gram_pallas_enabled(int(x.shape[1]))
+        attrs = dict(
+            n=int(n), blocks=nb, block_size=bs, epochs=self.num_epochs,
+            gram="pallas" if use_pallas else "xla",
+        )
+        if self.cache_kernel_blocks:
+            with ledger.span("solver.fit", solver="krr.cached", **attrs) as sp:
+                alpha, cache_hits = _krr_fit_cached(
+                    x,
+                    y,
+                    n,
+                    self.kernel_gen,
+                    self.lam,
+                    bs,
+                    self.num_epochs,
+                    cache_dir=self.kernel_cache_dir,
+                )
+                sp.set(cache_hits=cache_hits)
+        else:
+            with ledger.span("solver.fit", solver="krr", **attrs):
                 alpha = _krr_fit(
-                    x, y, jnp.float32(n), self.kernel_gen.gamma, self.lam,
+                    x, y, jnp.float32(n), float(self.kernel_gen.gamma), self.lam,
                     bs, self.num_epochs, obs=ledger.solver_obs(),
+                    use_pallas=use_pallas,
                 )
         return KernelBlockLinearMapper(self.kernel_gen, x, alpha, bs, n)
 
 
-@partial(jax.jit, static_argnames=("bs", "num_epochs", "obs"))
-def _krr_fit(x, y, n, gamma, lam, bs, num_epochs, obs=False):
+@partial(
+    jax.jit, static_argnames=("gamma", "bs", "num_epochs", "obs", "use_pallas")
+)
+def _krr_fit(x, y, n, gamma, lam, bs, num_epochs, obs=False, use_pallas=False):
     """The in-core sweep as one XLA program.
+
+    ``gamma`` and ``use_pallas`` are static (one fit = one γ = one
+    compile, as in the out-of-core steps): the column block comes from
+    the ``ops/gram_pallas`` dispatcher, whose ``gram_pallas`` gate the
+    estimator resolves once per fit.
 
     ``obs`` (static): emit a per-epoch ``solver.epoch`` convergence
     point (dual residual objective ½‖Y−F‖²/n) to the active run ledger
@@ -293,12 +322,13 @@ def _krr_fit(x, y, n, gamma, lam, bs, num_epochs, obs=False):
     adds the host callback, and is resolved at trace time so the inert
     program carries no callbacks at all (pinned byte-identical, like
     the other solvers)."""
+    from keystone_tpu.ops.gram_pallas import gram_block
+
     n_rows = x.shape[0]
     nb = n_rows // bs
     row_ok = (jnp.arange(n_rows) < n).astype(jnp.float32)
     x = constrain(x, DATA_AXIS)
     y = y * row_ok[:, None]
-    kern = GaussianKernelGenerator(gamma)
 
     alpha0 = jnp.zeros_like(y)
     f0 = jnp.zeros_like(y)
@@ -307,17 +337,23 @@ def _krr_fit(x, y, n, gamma, lam, bs, num_epochs, obs=False):
         alpha, f = carry
         xb = lax.dynamic_slice_in_dim(x, b * bs, bs)
         ok_b = lax.dynamic_slice_in_dim(row_ok, b * bs, bs)
-        # kernel column block K(:, b): (n_rows, bs); mask padding rows/cols
-        kcol = kern(x, xb) * row_ok[:, None] * ok_b[None, :]
+        # kernel column block K(:, b): (n_rows, bs), the one large array
+        # of the step.  It is never masked as a whole: the padding
+        # columns meet zero rows of Δα (α is masked), the padding rows
+        # are masked on the (n_rows, k) product, and K_bb on its own
+        # (bs, bs) slice — no second pass over n_rows × bs
+        kcol = gram_block(x, xb, gamma, use_pallas=use_pallas)
         kbb = lax.dynamic_slice_in_dim(kcol, b * bs, bs)
         # make the pad diagonal identity so the solve stays PD
-        kbb = kbb + jnp.diag(1.0 - ok_b)
+        kbb = kbb * ok_b[:, None] * ok_b[None, :] + jnp.diag(1.0 - ok_b)
         ab = lax.dynamic_slice_in_dim(alpha, b * bs, bs)
         yb = lax.dynamic_slice_in_dim(y, b * bs, bs)
         fb = lax.dynamic_slice_in_dim(f, b * bs, bs)
-        target = yb - fb + kbb @ ab
+        # both products enter block solves (this one's target, the later
+        # blocks' through F): solver grade, like the distance gemm
+        target = yb - fb + sdot(kbb, ab)
         ab_new = solve_spd(kbb, target, reg=lam * n) * ok_b[:, None]
-        f_new = f + kcol @ (ab_new - ab)
+        f_new = f + sdot(kcol, ab_new - ab) * row_ok[:, None]
         alpha_new = lax.dynamic_update_slice_in_dim(alpha, ab_new, b * bs, axis=0)
         return alpha_new, f_new
 
@@ -348,11 +384,10 @@ def _krr_fit(x, y, n, gamma, lam, bs, num_epochs, obs=False):
 def _cached_block_update(kcol, kbb, row_ok, ok_b, ab, yb, fb, lam_n):
     """One Gauss–Seidel block update from a PRE-COMPUTED kernel column
     block (same math as the inlined sweep in _krr_fit)."""
-    kcol = kcol * row_ok[:, None] * ok_b[None, :]
     kbb = kbb * ok_b[:, None] * ok_b[None, :] + jnp.diag(1.0 - ok_b)
-    target = yb - fb + kbb @ ab
+    target = yb - fb + sdot(kbb, ab)
     ab_new = solve_spd(kbb, target, reg=lam_n) * ok_b[:, None]
-    return ab_new, kcol @ (ab_new - ab)
+    return ab_new, sdot(kcol, ab_new - ab) * row_ok[:, None]
 
 
 def _krr_fit_cached(x, y, n, kern, lam, bs, num_epochs, cache_dir=None):
@@ -364,7 +399,9 @@ def _krr_fit_cached(x, y, n, kern, lam, bs, num_epochs, cache_dir=None):
     When K exceeds the HBM budget the cache goes TIERED: a partial HBM
     LRU backed by disk-persisted column blocks (the reference spilled
     cached RDDs to executor disk/memory the same way), so the cached
-    mode no longer silently requires K ≲ HBM."""
+    mode no longer silently requires K ≲ HBM.
+
+    Returns (α, the kernel cache's hits over the whole fit)."""
     import shutil
     import tempfile
 
@@ -438,7 +475,7 @@ def _krr_fit_cached(x, y, n, kern, lam, bs, num_epochs, cache_dir=None):
         if tmp_dir is not None:
             jax.block_until_ready(alpha)
             shutil.rmtree(tmp_dir, ignore_errors=True)
-    return alpha
+    return alpha, km.cache_hits
 
 
 @partial(jax.jit, static_argnames=("bs",))
@@ -517,13 +554,13 @@ def _oc_krr_diag_step(xb, fb, ab, yb, ok_b, lam_n, gamma, use_pallas=False):
     behind to bound its dispatch-queue lead."""
     kbb = _oc_gram(xb, xb, gamma, use_pallas)
     kbb = kbb * ok_b[:, None] * ok_b[None, :] + jnp.diag(1.0 - ok_b)
-    target = yb - fb + kbb @ ab
+    target = yb - fb + sdot(kbb, ab)
     ab_new = solve_spd(kbb, target, reg=lam_n) * ok_b[:, None]
     dab = ab_new - ab
     # diag(1−ok)·Δα is zero row-by-row (Δα is masked), so using the
     # solve-regularized kbb here matches _krr_fit's unregularized kcol
     # tile exactly
-    fb_new = fb + kbb @ dab
+    fb_new = fb + sdot(kbb, dab)
     return ab_new, fb_new, dab, ab_new[:1, :1]
 
 
@@ -536,7 +573,7 @@ def _oc_krr_offdiag_step(fi, xi, xb, dab, ok_i, ok_b, gamma, use_pallas=False):
     streamed ``xi`` is not (it frees by refcount when the loop drops
     it), and ``dab`` is read by every off-diag step of the block."""
     kib = _oc_gram(xi, xb, gamma, use_pallas) * ok_i[:, None] * ok_b[None, :]
-    fi_new = fi + kib @ dab
+    fi_new = fi + sdot(kib, dab)
     return fi_new, fi_new[:1, :1]
 
 

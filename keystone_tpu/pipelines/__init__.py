@@ -16,6 +16,7 @@ from keystone_tpu.pipelines.voc_sift_fisher import VOCSIFTFisher  # noqa: F401
 from keystone_tpu.pipelines.amazon_reviews import AmazonReviewsPipeline  # noqa: F401
 from keystone_tpu.pipelines.kernel_timit import KernelTimitPipeline  # noqa: F401
 from keystone_tpu.pipelines.kernel_cifar import KernelCifarPipeline  # noqa: F401
+from keystone_tpu.pipelines.kernel_ridge_timit import KernelRidgeTimitPipeline  # noqa: F401
 
 ALL_PIPELINES = {
     "MnistRandomFFT": MnistRandomFFT,
@@ -28,4 +29,5 @@ ALL_PIPELINES = {
     "AmazonReviewsPipeline": AmazonReviewsPipeline,
     "KernelTimitPipeline": KernelTimitPipeline,
     "KernelCifarPipeline": KernelCifarPipeline,
+    "KernelRidgeTimitPipeline": KernelRidgeTimitPipeline,
 }

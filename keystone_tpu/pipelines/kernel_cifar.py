@@ -1,11 +1,13 @@
-"""KernelCifarPipeline — kernel CIFAR via Nyström: raw pixels →
-ImageVectorizer → StandardScaler → NystromFeatures → BlockLeastSquares
-→ MaxClassifier.
+"""KernelCifarPipeline — the Nyström BASELINE of arXiv:1602.05310 on
+CIFAR: raw pixels → ImageVectorizer → StandardScaler → NystromFeatures
+→ BlockLeastSquares → MaxClassifier.
 
 The kernel counterpart of ``pipelines/linear_pixels.py``: same input
 plumbing, but the linear solve runs in the m-dimensional Nyström
-feature space of a Gaussian kernel over scaled pixels — the scenario
-family the kernel BCD line (arXiv:1602.05310) evaluates.  ``--stream``
+feature space of a Gaussian kernel over scaled pixels.  The paper's
+headline method, exact kernel ridge regression swept block by block
+over the dual, is not here: its entry is
+``pipelines/kernel_ridge_timit.py`` (``KernelRidgeTimitPipeline``).  ``--stream``
 keeps CIFAR records out of core."""
 
 from __future__ import annotations

@@ -1,13 +1,16 @@
-"""KernelTimitPipeline — the kernel-methods variant of the TIMIT
-scenario (arXiv:1602.05310 evaluates kernel systems on TIMIT): MFCC
-frames → StandardScaler → NystromFeatures (seeded landmark sampling +
-whitening solve; K_nm streams at apply time) → BlockLeastSquares (147
-classes) → MaxClassifier.
+"""KernelTimitPipeline — the Nyström BASELINE of the TIMIT experiment
+in arXiv:1602.05310 (which compares random features and Nyström
+features against the exact kernel): MFCC frames → StandardScaler →
+NystromFeatures (seeded landmark sampling + whitening solve; K_nm
+streams at apply time) → BlockLeastSquares (147 classes) →
+MaxClassifier.  It does NOT run the paper's headline method: the exact
+kernel ridge regression by block Gauss–Seidel over the dual is
+``pipelines/kernel_ridge_timit.py`` (``KernelRidgeTimitPipeline``).
 
 Where ``pipelines/timit.py`` approximates the Gaussian kernel with
 random cosine features, this variant uses the data-dependent Nyström
-map — same solver, same labels plumbing, a genuinely kernel feature
-space.  ``--stream`` keeps the MFCC frames out of core end to end:
+map — same primal solver, same labels plumbing, an approximate kernel
+feature space.  ``--stream`` keeps the MFCC frames out of core end to end:
 landmarks are collected in one streaming pass and the solver spills to
 a FeatureBlockStore."""
 

@@ -124,6 +124,17 @@ def traced_param_sig(t: "Transformer") -> tuple:
 #: tests/test_workflow.py; multi-device meshes still disable chunking
 #: (per-chunk resharding collectives — see _apply_chunk_rows).
 _APPLY_CHUNK_DEFAULT = 2048
+#: A long dataset of NARROW rows takes larger chunks: the canonical
+#: chunk doubles while more than ``_APPLY_MAX_CHUNKS`` chunks remain and
+#: the chunk's input stays within ``_APPLY_CHUNK_BYTES`` (so the shapes
+#: compiled grow with log2 n, and items that are already heavy at the
+#: canonical chunk — images, descriptor sets, Fisher vectors — never
+#: grow).  At n = 196,608 rows of 440 floats the 2048-row loop was 192
+#: chunk applies of two nodes, 0.20 s of host time a fit with the chip
+#: idle (0.25–0.29 s beside busy neighbours); at 16,384 rows it is 24
+#: (my chip runs, PR 25).  Up to 32,768 rows nothing changes.
+_APPLY_MAX_CHUNKS = 16
+_APPLY_CHUNK_BYTES = 32 << 20
 
 
 def _apply_chunk_rows() -> int:
@@ -162,6 +173,23 @@ def _apply_chunk_rows() -> int:
     m = active_mesh()
     spans = m.devices.size if m is not None else len(jax.devices())
     return 0 if spans > 1 else _APPLY_CHUNK_DEFAULT
+
+
+def _chunk_rows_for(arr) -> int:
+    """Row-chunk size for a device apply of ``arr``; 0 disables.  The
+    default chunk grows for long datasets of narrow rows (see
+    ``_APPLY_MAX_CHUNKS``); a forced ``KEYSTONE_APPLY_CHUNK`` is taken
+    as it is."""
+    import os
+
+    chunk = _apply_chunk_rows()
+    if not chunk or os.environ.get("KEYSTONE_APPLY_CHUNK", "").strip():
+        return chunk
+    n = arr.shape[0]
+    row_bytes = arr.nbytes // max(1, n)
+    while n > _APPLY_MAX_CHUNKS * chunk and 2 * chunk * row_bytes <= _APPLY_CHUNK_BYTES:
+        chunk *= 2
+    return chunk
 
 
 def iter_row_chunks(arr, mask, chunk: int):
@@ -346,7 +374,7 @@ class Transformer(Chainable):
             base, stages = getattr(ds, "_host_chain", None) or (ds, ())
             res._host_chain = (base, stages + (self,))
             return res
-        chunk = _apply_chunk_rows()
+        chunk = _chunk_rows_for(ds.array)
         if chunk and ds.array.shape[0] > chunk:
             return self._apply_dataset_chunked(ds, chunk)
         result = self._apply_batch_jitted(ds.array, ds.mask)

@@ -74,7 +74,7 @@ INVENTORY = {
 PIPELINES = [
     "mnist_random_fft", "linear_pixels", "random_patch_cifar", "newsgroups",
     "timit", "imagenet_sift_lcs_fv", "voc_sift_fisher", "amazon_reviews",
-    "kernel_timit", "kernel_cifar",
+    "kernel_timit", "kernel_cifar", "kernel_ridge_timit",
 ]
 
 

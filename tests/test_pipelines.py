@@ -10,6 +10,7 @@ from keystone_tpu.pipelines import (
     AmazonReviewsPipeline,
     ImageNetSiftLcsFV,
     KernelCifarPipeline,
+    KernelRidgeTimitPipeline,
     KernelTimitPipeline,
     LinearPixels,
     MnistRandomFFT,
@@ -208,6 +209,8 @@ def test_cli_list(capsys):
         lambda mp: (KernelCifarPipeline, KernelCifarPipeline.Config(
             synthetic_n=96, num_landmarks=48, solver_block_size=48,
             num_epochs=1, model_path=mp)),
+        lambda mp: (KernelRidgeTimitPipeline, KernelRidgeTimitPipeline.Config(
+            synthetic_n=256, num_classes=8, block_size=64, model_path=mp)),
     ],
 )
 def test_model_path_roundtrip_across_apps(app_cfg, tmp_path):

@@ -245,3 +245,26 @@ def test_weighted_bcd_program_keeps_its_name_and_panels(topo, no_persistent_cach
         if "stablehlo.dot_general" in ln and any(ln.endswith(f"loc({loc})") for loc in gram_locs)
     ]
     assert len(gram_dots) == 16 and all("[HIGHEST, HIGHEST]" in ln for ln in gram_dots)
+
+
+@pytest.mark.parametrize("route", ["pallas", "xla"])
+def test_krr_sweep_keeps_its_name_and_its_gram_route(topo, no_persistent_cache, route):
+    """``krr_roofline`` and ``krr_device_ms_per_block`` find the in-core
+    kernel sweep's device time by the program name ``krr_fit``; the column
+    block comes from the route the ``gram_pallas`` gate resolved (one
+    ``tpu_custom_call`` in the loop body, or none), at TIMIT's width."""
+    from keystone_tpu.models import kernel_ridge as kr
+    from keystone_tpu.parallel import use_mesh
+
+    mesh = _v5e_mesh(topo, 1)
+    n, block, d, k = 2048, 512, 440, 147
+    with use_mesh(mesh):
+        compiled = kr._krr_fit.lower(
+            _rows_over(mesh, n, d), _rows_over(mesh, n, k), _f32(NamedSharding(mesh, P())),
+            1.0 / d, 2e-6, block, 1, use_pallas=(route == "pallas"),
+        ).compile()
+    text = compiled.as_text()
+    assert "krr_fit" in text.splitlines()[0]
+    assert text.count('custom_call_target="tpu_custom_call"') == (route == "pallas")
+    # one column block, not three: the masks never touch the (n, block) array
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 4 * n * block

@@ -304,6 +304,41 @@ def test_chunked_apply_ragged_producer_and_sampler(monkeypatch):
     )
 
 
+@pytest.mark.parametrize(
+    "n, row_floats, want",
+    [
+        (64, 6, 4),  # up to _APPLY_MAX_CHUNKS chunks: the canonical chunk
+        (205, 6, 16),  # narrow rows: doubled until 16 chunks or fewer remain
+        (205, 32, 8),  # ... and no further than the chunk's bytes allow
+        (205, 64, 4),  # rows already heavy at the canonical chunk never grow
+    ],
+)
+def test_default_chunk_grows_for_long_narrow_datasets(monkeypatch, n, row_floats, want):
+    """The default chunk doubles for a long dataset of narrow rows (the
+    host loop of 2048-row applies idled the chip for a tenth of a
+    196,608-row fit), bounded by the chunk's bytes; the applies stay
+    bit-identical to the whole batch, and a forced chunk is taken as is."""
+    import importlib
+
+    tr = importlib.import_module("keystone_tpu.workflow.transformer")
+    monkeypatch.delenv("KEYSTONE_APPLY_CHUNK", raising=False)
+    monkeypatch.setattr(tr, "_apply_chunk_rows", lambda: 4)
+    monkeypatch.setattr(tr, "_APPLY_CHUNK_BYTES", 4 * 4 * 64)  # four rows of 64 floats
+    x = np.random.default_rng(n).normal(size=(n, row_floats)).astype(np.float32)
+    ds = Dataset(x)
+    assert tr._chunk_rows_for(ds.array) == want
+    seen = []
+    real = tr.iter_row_chunks
+    monkeypatch.setattr(
+        tr, "iter_row_chunks", lambda a, m, c: seen.append(c) or real(a, m, c)
+    )
+    out = AddConst(1.5).apply_dataset(ds)
+    assert seen == ([want] if n > want else [])
+    np.testing.assert_array_equal(np.asarray(out.array)[:n], x + np.float32(1.5))
+    monkeypatch.setenv("KEYSTONE_APPLY_CHUNK", "4")
+    assert tr._chunk_rows_for(ds.array) == 4
+
+
 def test_host_transformer_path():
     up = transformer(lambda s: s.upper(), name="Upper", host=True)
     ds = Dataset(["ab", "cd"])

@@ -16,8 +16,11 @@ Sections (each only when the run recorded it):
 - **optimizer**: seconds and runs per optimizer rule (``optimizer.rule``
   spans);
 - **solvers**: fits, host seconds and the static shape of each solver's
-  ``solver.fit`` spans (``n``, ``blocks``, and ``gram_panels`` — how many
-  column panels the block Gramian was split into; 1 is the one dot);
+  ``solver.fit`` spans (``n``, ``blocks``; the BCD solvers' ``gram_panels``
+  — how many column panels the block Gramian was split into, 1 is the
+  one dot; the kernel sweeps' ``block_size``, ``epochs``, ``gram`` — the
+  route the ``gram_pallas`` gate resolved, ``pallas`` or ``xla`` — and
+  the cached sweep's ``cache_hits``);
 - **retries**: retry totals across executor, durable I/O, blockstore,
   and streams;
 - **convergence**: per-solver epoch series (objective / grad norm /
