@@ -154,6 +154,8 @@ ATTR_VOCABULARY = {
     "shared_nodes",
     "shared_stages",
     "sick",
+    "sig_by_recipe",
+    "sig_bytes_hashed",
     "site",
     "solver",
     "source",

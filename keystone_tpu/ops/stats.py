@@ -15,6 +15,7 @@ import numpy as np
 
 from keystone_tpu.models.common import constrain
 from keystone_tpu.parallel.mesh import DATA_AXIS
+from keystone_tpu.utils.hashing import cached_fingerprint, pin_recipe
 from keystone_tpu.workflow.dataset import Dataset
 from keystone_tpu.workflow.estimator import Estimator
 from keystone_tpu.workflow.transformer import Transformer
@@ -26,6 +27,16 @@ class CosineRandomFeatures(Transformer):
 
     W rows ~ Gaussian(0, γ) for the RBF kernel or Cauchy(0, γ) for the
     Laplacian kernel; b ~ Uniform[0, 2π].
+
+    Identity (``params()``): a node from :meth:`init` signs with the
+    recipe of its draw — seed, gamma, distribution, shape, PRNG and
+    versions — for as long as ``w`` and ``b`` are the arrays ``init``
+    made, and reads none of their bytes; a node built from given arrays,
+    or one whose ``w`` or ``b`` was reassigned, signs with a digest of
+    their content (``utils/hashing.py``).  Equal signatures mean equal
+    values.  The reverse is not promised: ``init(seed=3)`` and
+    ``CosineRandomFeatures(w, b)`` holding the same bytes do not merge
+    under CSE, which costs a recomputation and is never a wrong answer.
     """
 
     # TIMIT gathers many instances of this class with identical shapes —
@@ -55,11 +66,15 @@ class CosineRandomFeatures(Transformer):
         else:
             raise ValueError(f"unknown distribution {distribution!r}")
         b = jax.random.uniform(kb, (num_output_features,), jnp.float32, 0.0, 2 * np.pi)
-        return cls(w, b)
+        node = cls(w, b)
+        # ``draw`` names the lines above: change it with them
+        pin_recipe(
+            node, "_fp", (w, b), draw="split(PRNGKey(seed)):w=gamma*dist,b=U[0,2pi)",
+            seed=int(seed), gamma=float(gamma), distribution=distribution,
+        )
+        return node
 
     def params(self):
-        from keystone_tpu.utils.hashing import cached_fingerprint
-
         return (self.w.shape, cached_fingerprint(self, "_fp", self.w, self.b))
 
     def apply_batch(self, xs, mask=None):
@@ -76,7 +91,10 @@ class CosineRandomFeatures(Transformer):
 
 class RandomSignNode(Transformer):
     """Elementwise Rademacher sign flip (nodes/stats/RandomSignNode.scala);
-    paired with PaddedFFT for fastfood-style random features."""
+    paired with PaddedFFT for fastfood-style random features.
+
+    Signs as ``CosineRandomFeatures`` does: by the recipe of the draw
+    while ``signs`` is the array :meth:`init` made, by content otherwise."""
 
     traced_attrs = ("signs",)  # MNIST gathers N sign-flip branches
 
@@ -86,11 +104,15 @@ class RandomSignNode(Transformer):
     @classmethod
     def init(cls, num_features: int, seed: int = 0) -> "RandomSignNode":
         bits = jax.random.bernoulli(jax.random.PRNGKey(seed), 0.5, (num_features,))
-        return cls(bits.astype(jnp.float32) * 2.0 - 1.0)
+        node = cls(bits.astype(jnp.float32) * 2.0 - 1.0)
+        # ``draw`` names the lines above: change it with them
+        pin_recipe(
+            node, "_fp", (node.signs,), draw="2*bernoulli(PRNGKey(seed),0.5)-1",
+            seed=int(seed),
+        )
+        return node
 
     def params(self):
-        from keystone_tpu.utils.hashing import cached_fingerprint
-
         return (self.signs.shape[0], cached_fingerprint(self, "_fp", self.signs))
 
     def apply_batch(self, xs, mask=None):

@@ -1194,13 +1194,23 @@ class PipelineDatum:
 def _optimize(graph: G.Graph, *passes) -> G.Graph:
     """``graph`` through ``passes`` (default: the optimizer's rule batches)
     inside one ``pipeline.optimize`` span, whose ``nodes`` reads the
-    graph's size before at its start and after at its end."""
+    graph's size before at its start and after at its end.  At its end it
+    also says what the nodes' signatures cost inside it
+    (``utils/hashing.py``): ``sig_bytes_hashed``, the bytes of weights
+    copied to the host to be digested, and ``sig_by_recipe``, the nodes
+    that signed with the recipe of a seeded draw and copied nothing."""
     from keystone_tpu.obs import ledger
+    from keystone_tpu.utils.hashing import tally_signatures
 
     with ledger.span("pipeline.optimize", nodes=len(graph.operators)) as sp:
-        for run in passes or (PipelineEnv.get_optimizer().execute,):
-            graph = run(graph)
-        sp.set(nodes=len(graph.operators))
+        with tally_signatures() as sigs:
+            for run in passes or (PipelineEnv.get_optimizer().execute,):
+                graph = run(graph)
+        sp.set(
+            nodes=len(graph.operators),
+            sig_bytes_hashed=sigs.bytes_hashed,
+            sig_by_recipe=sigs.by_recipe,
+        )
     return graph
 
 

@@ -437,6 +437,28 @@ def test_array_valued_collision_detected():
     assert "signature-collision" in {f.code for f in report.errors}
 
 
+@pytest.mark.parametrize("case", ["same-recipe-twice", "recipe-vs-reassigned"])
+def test_recipe_signed_nodes_are_stable_and_never_collide(case):
+    """A node that signs with the recipe of its seeded draw
+    (``utils/hashing.py``) under the pass's determinism and collision
+    checks: the same recipe twice is one stable identity over equal
+    state; a twin whose weights were reassigned signs by content, so the
+    two differ and nothing is there to collide."""
+    from keystone_tpu.analysis.signatures import collision_signatures
+    from keystone_tpu.ops import CosineRandomFeatures
+
+    a = CosineRandomFeatures.init(8, 4, gamma=0.5, seed=3)
+    b = CosineRandomFeatures.init(8, 4, gamma=0.5, seed=3)
+    if case == "recipe-vs-reassigned":
+        b.w = b.w + 1.0
+    assert a.signature() == a.signature() and b.signature() == b.signature()
+    assert (a.signature() == b.signature()) is (case == "same-recipe-twice")
+    pipe = Pipeline.gather([a, b])
+    report = analyze(pipe, example=np.zeros((4, 8), np.float32))
+    assert not report.findings, report.render()
+    assert not collision_signatures(pipe.graph)
+
+
 def test_dataset_name_collision_detected():
     from keystone_tpu.models import LinearMapEstimator
 

@@ -14,7 +14,10 @@ Sections (each only when the run recorded it):
 - **stages**: top executor stages by total span seconds, with attempt /
   retry counts and failed-attempt time;
 - **optimizer**: seconds and runs per optimizer rule (``optimizer.rule``
-  spans);
+  spans), and what the nodes' signatures cost inside the
+  ``pipeline.optimize`` spans: bytes of weights copied to the host to be
+  digested (``sig_bytes_hashed``) and nodes that signed with the recipe
+  of a seeded draw instead (``sig_by_recipe``);
 - **solvers**: fits, host seconds and the static shape of each solver's
   ``solver.fit`` spans (``n``, ``blocks``; the BCD solvers' ``gram_panels``
   — how many column panels the block Gramian was split into, 1 is the
@@ -136,12 +139,20 @@ def summarize(path: str, top_k: int = 10) -> dict:
 
     # --------------------------------------------------------- optimizer
     optimizer: Dict[str, dict] = {}
+    signatures: Dict[str, int] = {}  # summed over the optimizes that report them
     for e in events:
-        if e.get("kind") == "span_end" and e.get("name") == "optimizer.rule":
-            rule = str((e.get("attrs") or {}).get("rule", "?"))
+        if e.get("kind") != "span_end":
+            continue
+        attrs = e.get("attrs") or {}
+        if e.get("name") == "optimizer.rule":
+            rule = str(attrs.get("rule", "?"))
             st = optimizer.setdefault(rule, {"seconds": 0.0, "count": 0})
             st["seconds"] += float(e.get("seconds") or 0.0)
             st["count"] += 1
+        elif e.get("name") == "pipeline.optimize" and "sig_bytes_hashed" in attrs:
+            signatures["optimizes"] = signatures.get("optimizes", 0) + 1
+            for key in ("sig_bytes_hashed", "sig_by_recipe"):
+                signatures[key] = signatures.get(key, 0) + int(attrs.get(key) or 0)
 
     # ----------------------------------------------------------- solvers
     solvers: Dict[str, dict] = {}
@@ -369,6 +380,7 @@ def summarize(path: str, top_k: int = 10) -> dict:
         "wall_seconds": wall,
         "stage_top": stage_top,
         "optimizer": optimizer,
+        "signatures": signatures,
         "solvers": solvers,
         "retries": retries,
         "convergence": convergence,
@@ -423,6 +435,13 @@ def render(summary: dict) -> str:
             summary["optimizer"].items(), key=lambda kv: -kv[1]["seconds"]
         ):
             out.append(f"  {st['seconds']:>9.3f}  {st['count']:>4}  {rule}")
+        sg = summary.get("signatures")
+        if sg:
+            out.append(
+                f"  node signatures over {sg['optimizes']} optimizes: "
+                f"sig_bytes_hashed={sg['sig_bytes_hashed']}  "
+                f"sig_by_recipe={sg['sig_by_recipe']}"
+            )
 
     if summary.get("solvers"):
         out.append("\n== solver fits ==")
