@@ -12,6 +12,11 @@ Only ``n`` and the epoch count are cut to fit the time limit; the cuts
 are printed.  Every phase prints one JSON line; any failure is fatal
 (nothing is caught and carried past) and the exit code is non-zero.
 
+It is the bring-up proof, not the benchmark: what is measured is
+``benchmark/`` as ``BENCHMARK.json`` declares it.  It stays because it
+is the only chip check of the HTTP serve path until a serve cell exists
+(``ROADMAP.md`` D19).
+
     python chip_smoke.py              # one chip; the driver's command
     python chip_smoke.py --chips 4    # ONLY the row-sharded weighted BCD
                                       # fit on a 4-device mesh + its
@@ -746,7 +751,7 @@ def main(argv=None) -> int:
         "cache_dir_from_env": bool(os.environ.get("JAX_COMPILATION_CACHE_DIR")),
         # widths are never cut; these are the cuts of scale taken
         "cuts": (
-            {"bcd": sz["bcd"], "published": "bench.py's at-scale solver shape, uncut"}
+            {"bcd": sz["bcd"], "published": "the at-scale solver shape of rounds 1-5, uncut"}
             if args.chips == 4
             else {"n": sz["n"], "epochs": sz["epochs"], "held_out": sz["held_out"],
                   "published": "ImageNet-1k train is 1.28M images and the reference "

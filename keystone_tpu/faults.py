@@ -73,8 +73,8 @@ Plan grammar: ``site:token:token;site:token...`` where tokens are
 - context matches: ``ctx.<key>=<value>`` restricts the spec to calls
   whose site context carries that value (string-compared), e.g.
   ``serve.replica:ctx.replica=0:delay=0.05`` stalls replica 0's
-  flushes only — the straggler leg of ``tools/serve_bench.py`` and
-  single-replica chaos plans ride this.  Non-matching calls do not
+  flushes only — straggler drills and single-replica chaos plans ride
+  this.  Non-matching calls do not
   advance the spec's triggers (``after=N`` counts matching calls).
   Latency actions are valid at every site; the stalls ride
   ``utils.guard.interruptible_sleep``, so a watchdog

@@ -40,7 +40,7 @@ The JSONL export is opt-in:
   first ``span``/``event`` call creates it, ``atexit`` closes it) — the
   zero-code route, mirroring ``KEYSTONE_FAULTS``.
 - ``start_run(dir)`` / ``stop_run()`` scope a ledger explicitly
-  (bench.py and tests use this; an explicit run wins over the env one).
+  (tools and tests use this; an explicit run wins over the env one).
 
 With neither, no file is written and ``active()`` is None: the ring is
 not "active".  While a ledger is active the end of every ROOT span (and

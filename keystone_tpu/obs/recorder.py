@@ -39,9 +39,7 @@ Overhead budget: every hook is one lock acquisition plus O(1) dict/list
 work — no JSON, no I/O, no syscalls on the hot path (JSON-safety is
 applied on READ).  Per-trace event count is capped (``max_events``);
 live traces that never finish are bounded by eviction into ``recent``
-with outcome ``abandoned``.  ``tools/serve_bench.py`` legs with the
-recorder on vs off pin the p99/QPS delta under 5% (the bench artifact
-records it).
+with outcome ``abandoned``.
 
 This module is stdlib-only at import (the ``obs`` package contract).
 """

@@ -3,9 +3,8 @@
 
 PR 15/16 moved replica compute into worker processes and across hosts,
 which left the stdlib ``ThreadingHTTPServer`` front end — one thread
-plus one JSON body per request — as the serving stack's QPS ceiling
-(``tools/serve_bench.py`` measured its per-datum submit loop capping
-near 3k QPS on a small host).  This module replaces thread-per-request
+plus one JSON body per request — as the serving stack's QPS ceiling.
+This module replaces thread-per-request
 with an event loop and per-datum JSON with a batch wire format:
 
 - **Selector loop, not threads.**  Each :class:`AsyncIngress` shard is

@@ -131,7 +131,7 @@ rate.  ``workers=0`` (default) is the threaded path, byte-for-byte.
 
 The HTTP front end is ``keystone_tpu/serve/http.py``; the CLI entry is
 ``python -m keystone_tpu.cli serve``; the load generator is
-``tools/serve_bench.py``.
+``tools/workloads.py``.
 """
 
 from __future__ import annotations

@@ -741,8 +741,8 @@ def serve_multi(
     """Stand up a multi-tenant :class:`MultiTenantService`.
 
     ``models``: ``{tenant name: fitted pipeline (or FrozenApplier)}``.
-    ``share=False`` disables the cross-pipeline stage pool (the A/B
-    arm ``tools/serve_bench.py --tenants`` measures against).  ``pool``:
+    ``share=False`` disables the cross-pipeline stage pool (the arm a
+    shared-vs-unshared comparison measures against).  ``pool``:
     a private :class:`SharedStagePool` (default: the process-wide one).
     ``tenant_queue_bound``/``tenant_deadline_ms``: per-tenant quota and
     default deadline overrides (quota default: an equal share of

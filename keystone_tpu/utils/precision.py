@@ -39,8 +39,8 @@ Modes for the featurize policy:
     per-op story above (bf16 loses on output-bound contractions) is
     about HBM traffic of the op in isolation; inside a fused forward
     program the casts also halve every *inter*-contraction stream, so
-    the whole-pipeline win is a separate measurement — bench.py's
-    precision sweep is the arbiter.  ``bf16_apply`` resolves to the
+    the whole-pipeline win is a separate measurement — the benchmark's
+    scoring cell run under each mode is the arbiter.  ``bf16_apply`` resolves to the
     INERT f32 policy off-TPU (CPU test meshes stay bit-identical; see
     :func:`matmul_mode`) unless ``force_bf16_apply`` /
     ``KEYSTONE_BF16_APPLY_FORCE=1`` overrides the gate for parity

@@ -409,10 +409,9 @@ class FittedPipeline(Pipeline):
         ``block_until_ready`` waits for the device on today's runtime
         (re-tested on the chip by chip_smoke.py's sync probe on every
         run — 0.3047 s blocked vs 0.3049 s synced by a host read, my
-        chip run, PR 21).  bench.py's fit leg ends with this instead of
-        a probe score, which was charging ~5 one-row scoring-program
-        traces (6–7 s/process; rounds 1–5, not re-measured) to fit
-        time.
+        chip run, PR 21).  A timed fit ends with this instead of a
+        probe score, which would charge the one-row scoring programs'
+        traces to fit time.
 
         Non-numeric leaves that expose ``block_until_ready`` but cannot
         join the batched read are only blocked on, not read (none exist

@@ -35,3 +35,39 @@ def mesh():
 @pytest.fixture
 def rng_key():
     return jax.random.PRNGKey(0)
+
+
+@pytest.fixture(scope="module")
+def imagenet_toy_config():
+    """The toy width of the north-star entry that the suite fits."""
+    from keystone_tpu.pipelines import ImageNetSiftLcsFV
+
+    return ImageNetSiftLcsFV.Config(
+        num_classes=4,
+        gmm_k=4,
+        gmm_iters=4,
+        pca_dims=16,
+        descriptor_samples_per_image=32,
+        solver_block_size=512,
+        synthetic_n=48,
+        image_size=48,
+        sift_step=8,
+        lcs_step=8,
+    )
+
+
+@pytest.fixture(scope="module")
+def imagenet_toy_scorer(imagenet_toy_config):
+    """``ImageNetSiftLcsFV.build_scorer`` fitted at the toy width: the
+    program's own forward, for the tests that hold its properties."""
+    from keystone_tpu.loaders.imagenet import ImageNetLoader
+    from keystone_tpu.pipelines import ImageNetSiftLcsFV
+
+    cfg = imagenet_toy_config
+    train = ImageNetLoader.synthetic(
+        cfg.synthetic_n,
+        cfg.num_classes,
+        size=(cfg.image_size, cfg.image_size),
+        seed=1,
+    )
+    return ImageNetSiftLcsFV.build_scorer(cfg, train.data, train.labels).fit()

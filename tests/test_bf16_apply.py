@@ -239,30 +239,21 @@ def test_block_predict_bf16_apply():
     assert (active.argmax(1) == ref.argmax(1)).all()
 
 
-def test_bench_forward_inert_on_cpu():
-    """Tier-1 gate: the FULL headline forward program (SIFT → PCA → FV →
-    normalize → block scoring) is bit-identical on a CPU mesh with the
-    policy set — bf16_apply may not perturb any off-chip result."""
-    import os
-    import sys
+def test_entry_scorer_inert_on_cpu(imagenet_toy_config, imagenet_toy_scorer):
+    """Tier-1 gate: the north-star forward (SIFT and LCS → PCA → FV →
+    normalize → block scoring, the public entry's fitted scorer) is
+    bit-identical on a CPU mesh with the policy set — bf16_apply may not
+    perturb any off-chip result."""
+    from keystone_tpu.workflow import Dataset
 
-    import jax
-
-    sys.path.insert(
-        0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    )
-    import bench
-
-    imgs = jnp.asarray(
-        np.random.default_rng(10).uniform(
-            0, 1, (2, bench.IMAGE_HW, bench.IMAGE_HW, 3)
-        ),
-        jnp.float32,
+    hw = imagenet_toy_config.image_size
+    imgs = np.random.default_rng(10).integers(
+        0, 256, (2, hw, hw, 3), dtype=np.uint8
     )
     with precision.matmul("f32"):
-        ref = np.asarray(jax.jit(bench.build_forward())(imgs))
+        ref = imagenet_toy_scorer(Dataset(imgs)).get().numpy()
     with precision.matmul("bf16_apply"):
-        got = np.asarray(jax.jit(bench.build_forward())(imgs))
+        got = imagenet_toy_scorer(Dataset(imgs)).get().numpy()
     np.testing.assert_array_equal(got, ref)
 
 

@@ -1048,14 +1048,21 @@ def _threaded_ref(x: np.ndarray) -> np.ndarray:
 
 
 def test_net_fleet_serves_and_matches_threaded(net_service):
-    """Predictions over TCP are BIT-identical to the threaded
-    single-replica service — the transport is a transport, never a
-    numerics change."""
+    """The transport is a transport: predictions over TCP are
+    BIT-identical to a threaded single-replica service laid out as a
+    worker lays itself out (its own default mesh, every device on the
+    data axis; a mesh the router ``set_mesh`` is not shipped to it), and
+    within one unit in the last place of one under the suite's 4x2 mesh,
+    which splits each row's norm into two partial sums."""
+    from keystone_tpu.parallel import default_mesh, use_mesh
+
     x = _rows(12, seed=3)
     got = np.stack(
         [f.result(timeout=60) for f in [net_service.submit(r) for r in x]]
     )
-    assert got.tobytes() == _threaded_ref(x).tobytes()
+    with use_mesh(default_mesh()):
+        assert got.tobytes() == _threaded_ref(x).tobytes()
+    np.testing.assert_array_max_ulp(got, _threaded_ref(x), maxulp=1)
 
 
 def test_net_fleet_status_exposes_leased_links(net_service):
@@ -1079,11 +1086,16 @@ def test_partition_mid_flight_loses_nothing_and_heals(net_service):
     """THE acceptance pin: sever one worker's link both directions
     while requests stream — zero lost futures (the forfeited flush
     re-serves on the survivor), results bit-identical to the
-    unpartitioned reference, and after the partition lifts the fleet
+    unpartitioned reference laid out as a worker is (see
+    test_net_fleet_serves_and_matches_threaded), and after the
+    partition lifts the fleet
     heals back to two live leased workers (the fenced worker rejoins
     through the front door)."""
+    from keystone_tpu.parallel import default_mesh, use_mesh
+
     x = _rows(48, seed=7)
-    want = _threaded_ref(x)
+    with use_mesh(default_mesh()):
+        want = _threaded_ref(x)
     links = [r["link"] for r in net_service.replica_statuses() if "link" in r]
     assert links, "no leased links to partition"
     victim = links[0]

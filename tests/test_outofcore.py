@@ -540,10 +540,24 @@ def test_iter_blocks_oserror_carries_block_index(tmp_path, monkeypatch):
     assert e.filename == "blk_00001.bin"
 
 
+def _aliased_params(step, *args) -> set:
+    """The parameters that the COMPILED program aliases onto outputs
+    (the header's ``input_output_alias`` table)."""
+    import re
+
+    header = step.lower(*args).compile().as_text().split("\n", 1)[0]
+    table = re.search(r"input_output_alias=\{(.*?)\}, entry_", header)
+    assert table, header[:300]
+    return {int(i) for i in re.findall(r"\((\d+), \{\}, \w+-alias\)", table[1])}
+
+
 def test_oc_block_step_donates_carry(tmp_path):
-    """The donation pin: the carried (p, w_b) buffers are CONSUMED by
-    the step (is_deleted under live references — refcount alone could
-    never do that), so the epoch loop cannot grow live device state."""
+    """The donation pin, as the sweep uses it: the carry a step RETURNS
+    is CONSUMED by the next step (is_deleted under live references —
+    refcount alone could never do that), so the epoch loop cannot grow
+    live device state.  A first ``p`` that arrives laid out otherwise
+    than the step lays it out (single-device here, rows over the mesh's
+    data axis out of the step) cannot be aliased and is copied once."""
     import jax
 
     from keystone_tpu.models.block_ls import _oc_block_step
@@ -557,17 +571,18 @@ def test_oc_block_step_donates_carry(tmp_path):
     row_ok = jnp.ones((n,), jnp.float32)
     p = jnp.zeros((n, k), jnp.float32)
     wb = jnp.zeros((bs, k), jnp.float32)
-    wb2, p2, tick = _oc_block_step(
-        a, xm_b, yc, sa, row_ok, p, wb, jnp.float32(0.1)
-    )
-    jax.block_until_ready(p2)
-    assert p.is_deleted() and wb.is_deleted()
+    lam_n = jnp.float32(0.1)
+    # exactly the carry aliases outputs: not the block, not the targets
+    assert _aliased_params(
+        _oc_block_step, a, xm_b, yc, sa, row_ok, p, wb, lam_n
+    ) == {5, 6}
+    wb2, p2, tick = _oc_block_step(a, xm_b, yc, sa, row_ok, p, wb, lam_n)
+    wb3, p3, _ = _oc_block_step(a, xm_b, yc, sa, row_ok, p2, wb2, lam_n)
+    jax.block_until_ready(p3)
+    assert p2.is_deleted() and wb2.is_deleted()
     assert not yc.is_deleted() and not a.is_deleted()
     # the tick (the sweep's flow-control handle) is NOT donated: it must
     # stay waitable after later steps consume the real outputs
-    wb3, p3, _ = _oc_block_step(
-        a, xm_b, yc, sa, row_ok, p2, wb2, jnp.float32(0.1)
-    )
     assert not tick.is_deleted()
     jax.block_until_ready(tick)
 
@@ -577,9 +592,7 @@ def test_oc_block_step_donates_carry(tmp_path):
     gc.collect()
     baseline = len(jax.live_arrays())
     for _ in range(4):
-        wb3, p3, tick = _oc_block_step(
-            a, xm_b, yc, sa, row_ok, p3, wb3, jnp.float32(0.1)
-        )
+        wb3, p3, tick = _oc_block_step(a, xm_b, yc, sa, row_ok, p3, wb3, lam_n)
     jax.block_until_ready(p3)
     del tick
     gc.collect()
@@ -587,20 +600,34 @@ def test_oc_block_step_donates_carry(tmp_path):
 
 
 def test_bcd_epoch_donates_carry():
+    """The same pin for the checkpointed host loop's epoch: the (w, p)
+    an epoch returns is consumed by the next one."""
+    import gc
+
     import jax
 
     from keystone_tpu.models.block_ls import _bcd_epoch, blockify
 
     rng = np.random.default_rng(26)
     x = rng.normal(size=(16, 12)).astype(np.float32)
-    y = rng.normal(size=(16, 3)).astype(np.float32)
+    y = jnp.asarray(rng.normal(size=(16, 3)).astype(np.float32))
     xb = blockify(jnp.asarray(x), 8)
     w = jnp.zeros((xb.shape[0], 8, 3), jnp.float32)
     p = jnp.zeros((16, 3), jnp.float32)
-    w2, p2 = _bcd_epoch(xb, jnp.asarray(y), jnp.float32(16.0), 1e-3, w, p)
-    jax.block_until_ready(w2)
-    assert w.is_deleted() and p.is_deleted()
-    assert not xb.is_deleted()
+    n, lam = jnp.float32(16.0), 1e-3
+    assert _aliased_params(_bcd_epoch, xb, y, n, lam, w, p) == {4, 5}
+    w2, p2 = _bcd_epoch(xb, y, n, lam, w, p)
+    w3, p3 = _bcd_epoch(xb, y, n, lam, w2, p2)
+    jax.block_until_ready(w3)
+    assert w2.is_deleted() and p2.is_deleted()
+    assert not xb.is_deleted() and not y.is_deleted()
+    gc.collect()
+    baseline = len(jax.live_arrays())
+    for _ in range(4):
+        w3, p3 = _bcd_epoch(xb, y, n, lam, w3, p3)
+    jax.block_until_ready(w3)
+    gc.collect()
+    assert len(jax.live_arrays()) <= baseline
 
 
 def test_lbfgs_chunk_donates_carry(tmp_path):

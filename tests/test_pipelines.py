@@ -19,6 +19,7 @@ from keystone_tpu.pipelines import (
     TimitPipeline,
     VOCSIFTFisher,
 )
+from keystone_tpu.workflow import Dataset
 
 
 def test_mnist_random_fft_e2e():
@@ -114,22 +115,79 @@ def test_kernel_cifar_e2e():
     assert result["accuracy"] > 0.5, result
 
 
-def test_imagenet_sift_lcs_fv_e2e():
-    cfg = ImageNetSiftLcsFV.Config(
-        num_classes=4,
-        gmm_k=4,
-        gmm_iters=4,
-        pca_dims=16,
-        descriptor_samples_per_image=32,
-        solver_block_size=512,
-        synthetic_n=48,
-        image_size=48,
-        sift_step=8,
-        lcs_step=8,
-    )
-    result = ImageNetSiftLcsFV.run(cfg)
+def test_imagenet_sift_lcs_fv_e2e(imagenet_toy_config):
+    result = ImageNetSiftLcsFV.run(imagenet_toy_config)
     assert result["top5_error"] <= result["top1_error"] + 1e-9, result
     assert result["accuracy"] > 0.5, result
+
+
+def _toy_images(cfg, n, seed):
+    hw = cfg.image_size
+    return np.random.default_rng(seed).integers(
+        0, 256, (n, hw, hw, 3), dtype=np.uint8
+    )
+
+
+def _scores_finite_and_shaped(cfg, scorer):
+    out = scorer(Dataset(_toy_images(cfg, 4, 0))).get().numpy()
+    assert out.shape == (4, cfg.num_classes)
+    assert np.isfinite(out).all()
+
+
+def _scores_batch_invariant(cfg, scorer):
+    # per-image results must not depend on batch packing (pure map
+    # semantics, the reference's Transformer.apply(RDD) contract)
+    imgs = _toy_images(cfg, 6, 1)
+    full = scorer(Dataset(imgs)).get().numpy()
+    half = scorer(Dataset(imgs[:3])).get().numpy()
+    np.testing.assert_allclose(full[:3], half, rtol=2e-4, atol=2e-4)
+
+
+def _multiscale_sift_through_fitted_pca_and_fv(cfg, scorer):
+    # vl_phow's bins and smoothing in front of the fitted SIFT branch's
+    # PCA and Fisher vector, all under one jit
+    import jax
+
+    from keystone_tpu.ops import GrayScaler, SIFTExtractor
+
+    graph = scorer.graph
+    child = {deps[0]: n for n, deps in graph.dependencies.items() if deps}
+    at = next(
+        n
+        for n, op in graph.operators.items()
+        if isinstance(getattr(op, "transformer", None), SIFTExtractor)
+    )
+    pca = graph.operators[child[at]].transformer
+    fv = graph.operators[child[child[at]]].transformer
+    gray = GrayScaler()
+    sift = SIFTExtractor(
+        step=cfg.sift_step, bin_sizes=(4, 6, 8, 10), smoothing_magnif=6.0
+    )
+
+    def forward(images):
+        desc, mask = sift.apply_batch(gray.apply_batch(images))
+        desc, mask = pca.apply_batch(desc, mask=mask)
+        return fv.apply_batch(desc, mask=mask)
+
+    imgs = _toy_images(cfg, 2, 2).astype(np.float32) / 255.0
+    out = np.asarray(jax.jit(forward)(imgs))
+    assert out.shape == (2, 2 * cfg.gmm_k * cfg.pca_dims)
+    assert np.isfinite(out).all()
+
+
+@pytest.mark.parametrize(
+    "holds",
+    [
+        _scores_finite_and_shaped,
+        _scores_batch_invariant,
+        _multiscale_sift_through_fitted_pca_and_fv,
+    ],
+    ids=lambda f: f.__name__.lstrip("_"),
+)
+def test_imagenet_sift_lcs_fv_scorer(holds, imagenet_toy_config, imagenet_toy_scorer):
+    """Properties of the north-star forward, held on the entry's own
+    fitted scorer (they used to be held on a hand-built copy)."""
+    holds(imagenet_toy_config, imagenet_toy_scorer)
 
 
 def test_imagenet_augmented_view_eval():

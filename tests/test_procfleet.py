@@ -151,17 +151,10 @@ def _rows(k: int, seed: int = 0) -> np.ndarray:
     return np.random.default_rng(seed).normal(size=(k, DIM)).astype(np.float32)
 
 
-def test_process_fleet_serves_and_matches_threaded(proc_service):
-    """Predictions from the process fleet are BIT-identical to the
-    threaded single-replica service over the same pipeline — the
-    promotion is a transport change, never a numerics change."""
+def _threaded_ref(x: np.ndarray) -> np.ndarray:
     from keystone_tpu.serve import serve
 
-    x = _rows(12, seed=3)
-    got = np.stack(
-        [f.result(timeout=60) for f in [proc_service.submit(r) for r in x]]
-    )
-    ref_svc = serve(
+    ref = serve(
         _pipeline(),
         max_batch=8,
         max_wait_ms=2.0,
@@ -170,12 +163,30 @@ def test_process_fleet_serves_and_matches_threaded(proc_service):
         supervise=False,
     )
     try:
-        want = np.stack(
-            [f.result(timeout=60) for f in [ref_svc.submit(r) for r in x]]
+        return np.stack(
+            [f.result(timeout=60) for f in [ref.submit(r) for r in x]]
         )
     finally:
-        ref_svc.close()
-    assert got.tobytes() == want.tobytes()
+        ref.close()
+
+
+def test_process_fleet_serves_and_matches_threaded(proc_service):
+    """The promotion is a transport change: predictions from the process
+    fleet are BIT-identical to a threaded single-replica service laid
+    out as a worker lays itself out (a spawned worker builds its own
+    default mesh, every device on the data axis; a mesh the router
+    ``set_mesh`` is not shipped to it).  Against the suite's 4x2 mesh,
+    which splits each row's norm into two partial sums, they agree to
+    one unit in the last place."""
+    from keystone_tpu.parallel import default_mesh, use_mesh
+
+    x = _rows(12, seed=3)
+    got = np.stack(
+        [f.result(timeout=60) for f in [proc_service.submit(r) for r in x]]
+    )
+    with use_mesh(default_mesh()):
+        assert got.tobytes() == _threaded_ref(x).tobytes()
+    np.testing.assert_array_max_ulp(got, _threaded_ref(x), maxulp=1)
 
 
 def test_process_fleet_status_exposes_workers(proc_service):

@@ -505,15 +505,9 @@ def test_overload_keeps_accepting_with_bounded_queue():
     (a serve.batch delay plan emulates a heavier model).  The service
     keeps completing work at occupancy > 1, sheds/rejects the excess
     (counted), and every completed request beats its deadline."""
-    sys.path.insert(
-        0,
-        __import__("os").path.dirname(
-            __import__("os").path.dirname(__import__("os").path.abspath(__file__))
-        ),
-    )
-    from tools import serve_bench
+    from tools import workloads
 
-    svc, item_shape = serve_bench.build_service(
+    svc, item_shape = workloads.build_service(
         dim=16,
         max_batch=8,
         max_wait_ms=2.0,
@@ -521,7 +515,7 @@ def test_overload_keeps_accepting_with_bounded_queue():
         deadline_ms=500.0,
     )
     try:
-        rep = serve_bench.run_bench(
+        rep = workloads.offer(
             svc,
             item_shape,
             qps=600.0,
@@ -540,19 +534,19 @@ def test_overload_keeps_accepting_with_bounded_queue():
     assert rep["p99_ms"] is not None and rep["p99_ms"] < 500.0
 
 
-def test_serve_bench_counts_an_unavailable_fleet_as_rejected(monkeypatch):
+def test_offer_counts_an_unavailable_fleet_as_rejected(monkeypatch):
     """An open breaker answers ``FleetUnavailable`` at admission — a
-    typed refusal like ``Overloaded``.  The offer loop must count it and
-    go on: on the v5e the overload leg died with it one run in two
-    (PR 21), and a leg that dies reports nothing."""
-    from tools import serve_bench
+    typed refusal like ``Overloaded``.  The offer must count it and go
+    on: on the v5e an overloaded service ended the offer loop with it
+    one run in two (PR 21), and a run that dies reports nothing."""
+    from tools import workloads
 
-    svc, item_shape = serve_bench.build_service(
+    svc, item_shape = workloads.build_service(
         dim=16, max_batch=8, max_wait_ms=2.0, queue_bound=32, deadline_ms=500.0
     )
     try:
         monkeypatch.setattr(svc._pool, "available", lambda: False)
-        rep = serve_bench.run_bench(
+        rep = workloads.offer(
             svc, item_shape, qps=400.0, duration=0.25, deadline_ms=500.0
         )
     finally:

@@ -216,7 +216,7 @@ def _serve_artifacts(tmp, restarts):
     import numpy as np
 
     from keystone_tpu.serve import ModelRegistry, serve
-    from tools.serve_bench import build_pipeline
+    from tools.workloads import build_pipeline
 
     dim = 16
     reg = ModelRegistry(os.path.join(tmp, "registry"))
@@ -282,7 +282,7 @@ def _tenants(tmp, restarts):
     import numpy as np
 
     from keystone_tpu.serve import serve_multi
-    from tools.serve_bench import build_tenant_models
+    from tools.workloads import build_tenant_models
 
     dim = 16
     models = build_tenant_models(tenants=2, dim=dim, branches=3)
@@ -377,7 +377,7 @@ def _procfleet(tmp, restarts):
 
     import numpy as np
 
-    from tools.serve_bench import build_service
+    from tools.workloads import build_service
 
     dim = 8
     svc, item_shape = build_service(
@@ -474,7 +474,7 @@ def _nethost(tmp, restarts):
     import numpy as np
 
     from keystone_tpu import faults as _faults
-    from tools.serve_bench import build_service
+    from tools.workloads import build_service
 
     dim = 8
     svc, item_shape = build_service(
@@ -820,7 +820,7 @@ def run_soak(
     from keystone_tpu import faults
     from keystone_tpu.utils import guard as _guard
 
-    from tools import serve_bench
+    from tools import workloads
 
     rng = _random.Random(seed)
     fleet_kw = (
@@ -831,7 +831,7 @@ def run_soak(
         if workers
         else dict(replicas=replicas)
     )
-    svc, item_shape = serve_bench.build_service(
+    svc, item_shape = workloads.build_service(
         dim=8,
         max_batch=8,
         max_wait_ms=2.0,

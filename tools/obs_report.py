@@ -1,8 +1,8 @@
 """Render a run-ledger JSONL file into a human (or JSON) summary.
 
 The read side of ``keystone_tpu.obs``: ``Pipeline.fit`` (with
-``KEYSTONE_OBS_DIR`` set), ``tools/chaos.py --ledger``, and bench.py all
-write JSONL ledgers; this tool folds one back into the questions an
+``KEYSTONE_OBS_DIR`` set) and ``tools/chaos.py --ledger`` write JSONL
+ledgers; this tool folds one back into the questions an
 operator actually asks::
 
     JAX_PLATFORMS=cpu python tools/obs_report.py /tmp/obs/run_abc.jsonl
@@ -39,8 +39,7 @@ Sections (each only when the run recorded it):
   retransmit/late-discard counts;
 - **faults**: per-site injected counts (chaos runs).
 
-``summarize()`` / ``render()`` are importable — bench.py embeds the
-summary dict in its round artifacts.
+``summarize()`` / ``render()`` are importable.
 """
 
 from __future__ import annotations

@@ -6,11 +6,16 @@ payload row seeds all derive from one BLAKE2b-seeded PRNG, so a
 workload that kills a canary (or slips past one) replays exactly —
 ``trace_digest()`` pins the whole schedule to a hash the tests assert
 on.  The zoo doubles as the guarded-rollout drill corpus
-(``tools/chaos.py --workload rollout``) and a serve_bench leg
-(``--scenario NAME``).
+(``tools/chaos.py --workload rollout``), and it is the tree's one load
+generator: :func:`play` is the only arrival loop, :func:`offer` drives
+it open-loop against a service and counts what came back, and the
+``build_*`` functions make the small synthetic models and services the
+failure drills (``tools/chaos.py``) and the service tests load.
 
 Scenarios::
 
+    constant      one single-row request every 1/qps seconds (the
+                  open-loop offer :func:`offer` reports on)
     bursty        quiet baseline with seeded 10x arrival bursts
     diurnal       sinusoidal offered rate over the window
     heavy_tailed  Pareto-ish batch sizes: most tiny, a few huge
@@ -54,6 +59,7 @@ from keystone_tpu.workflow.transformer import Transformer  # noqa: E402
 MARK = np.float32(123.0)
 
 SCENARIOS = (
+    "constant",
     "bursty",
     "diurnal",
     "heavy_tailed",
@@ -98,6 +104,103 @@ def build_zoo_pipeline(dim: int = 8, scale: float = 2.0, gate: bool = False):
     if gate:
         return Pipeline.of(MarkerGate()) | NormalizeRows() | LinearMapper(w)
     return Pipeline.of(NormalizeRows()) | LinearMapper(w)
+
+
+#: output width of the synthetic models' linear heads
+_CLASSES = 16
+
+
+def build_pipeline(dim: int = 64, seed: int = 0):
+    """The synthetic two-stage workload (NormalizeRows → LinearMapper)."""
+    import jax.numpy as jnp
+
+    from keystone_tpu.models.linear import LinearMapper
+    from keystone_tpu.ops.stats import NormalizeRows
+    from keystone_tpu.workflow import Pipeline
+
+    rng = np.random.default_rng(seed)
+    w = jnp.asarray(rng.normal(size=(dim, _CLASSES)).astype(np.float32))
+    return Pipeline.of(NormalizeRows()) | LinearMapper(w)
+
+
+def _fft_gather_feat(dim: int, branches: int):
+    """A ``branches``-way gather of RandomSignNode → PaddedFFT →
+    LinearRectifier chains — the MnistRandomFFT shape; returns
+    ``(featurizer pipeline, feature dim)``.  Each branch's rectifier
+    carries a DISTINCT constant: identical-structure branches lower to
+    identical HLO that the persistent compile cache dedupes across
+    programs, which real heterogeneous pipelines don't enjoy."""
+    from keystone_tpu.ops.stats import (
+        LinearRectifier,
+        PaddedFFT,
+        RandomSignNode,
+    )
+    from keystone_tpu.workflow import Pipeline
+
+    feat = Pipeline.gather(
+        [
+            RandomSignNode.init(dim, i)
+            | PaddedFFT()
+            | LinearRectifier(0.0, alpha=0.001 * (i + 1))
+            for i in range(int(branches))
+        ]
+    )
+    padded = 1 << (dim - 1).bit_length()
+    return feat, branches * (padded // 2 + 1) * 2
+
+
+def build_tenant_models(tenants: int = 3, dim: int = 64, branches: int = 6):
+    """N tenant pipelines SHARING a featurization prefix: every tenant
+    gathers the SAME RandomSignNode → PaddedFFT → LinearRectifier
+    branches (identical seeds/constants, so the prefix signatures are
+    equal and the cross-pipeline planner shares them) feeding a
+    per-tenant linear head (distinct weights — never shared, and with
+    ``params() = None`` never collision-prone either)."""
+    import jax.numpy as jnp
+
+    from keystone_tpu.models.linear import LinearMapper
+    from keystone_tpu.ops.stats import NormalizeRows
+
+    models = {}
+    for t in range(int(tenants)):
+        # the SAME seed for every tenant's featurizer: equal prefix
+        # signatures are what the cross-pipeline planner shares
+        feat, feat_dim = _fft_gather_feat(dim, branches)
+        rng = np.random.default_rng(100 + t)
+        w = jnp.asarray(
+            rng.normal(size=(feat_dim, _CLASSES)).astype(np.float32)
+        )
+        models[f"t{t}"] = feat | NormalizeRows() | LinearMapper(w)
+    return models
+
+
+def build_service(
+    dim: int = 64,
+    max_batch: int = 32,
+    max_wait_ms: float = 2.0,
+    queue_bound: int = 128,
+    deadline_ms: float | None = 250.0,
+    **serve_kw,
+):
+    """A primed service over :func:`build_pipeline`; returns
+    ``(service, item_shape)``.  Extra keywords (``replicas``,
+    ``workers``, ``hosts``, ``hedge_ms``, ``heartbeat_s``, ...) pass
+    through to :func:`keystone_tpu.serve.serve` — the chaos drills and
+    the soak pick their fleet with them."""
+    from keystone_tpu.serve import serve
+
+    item_shape = (int(dim),)
+    svc = serve(
+        build_pipeline(dim=dim),
+        max_batch=max_batch,
+        max_wait_ms=max_wait_ms,
+        queue_bound=queue_bound,
+        deadline_ms=deadline_ms,
+        example=np.zeros(item_shape, np.float32),
+        name="workload",
+        **serve_kw,
+    )
+    return svc, item_shape
 
 
 class Scenario:
@@ -197,7 +300,13 @@ def make_scenario(
             }
         )
 
-    if name == "bursty":
+    if name == "constant":
+        # arrivals on a fixed grid whether or not earlier ones completed
+        # (open loop: a closed-loop generator throttles itself and hides
+        # queueing collapse)
+        for i in range(n_events):
+            _event(i / qps)
+    elif name == "bursty":
         # quiet baseline + seeded bursts: ~1/8 of events arrive in
         # 10-event clumps at the same instant (queue-depth spikes the
         # admission/shedding layer must absorb)
@@ -316,6 +425,124 @@ def play(scenario: Scenario, submit, time_scale: float = 1.0) -> list:
         except Exception as e:
             out.append(e)
     return out
+
+
+def offer(
+    svc,
+    item_shape,
+    qps: float,
+    duration: float,
+    deadline_ms: float | None = None,
+    batch_delay_ms: float = 0.0,
+) -> dict:
+    """Offer ``qps`` single-row requests a second for ``duration``
+    seconds (the ``constant`` scenario through :func:`play`), wait for
+    the tail to drain, and report what became of every one: completed,
+    shed at its deadline, rejected at admission, or failed; p50/p99 of
+    the completed; mean batch occupancy.  ``batch_delay_ms`` > 0 stalls
+    every flush via a ``serve.batch:delay=…`` fault plan (a heavier
+    model, so a laptop can exercise overload deterministically)."""
+    import contextlib
+    import threading
+    from concurrent.futures import wait as futures_wait
+
+    from keystone_tpu import faults
+    from keystone_tpu.obs import metrics
+    from keystone_tpu.serve import FleetUnavailable, Overloaded
+    from keystone_tpu.utils import guard
+
+    deadline_s = None if not deadline_ms else float(deadline_ms) / 1000.0
+    scenario = make_scenario(
+        "constant", seed=1, duration_s=duration, qps=qps, dim=item_shape[0]
+    )
+    snap0 = metrics.snapshot()
+
+    lock = threading.Lock()
+    latencies: list = []
+    outcomes = {"completed": 0, "shed": 0, "rejected": 0, "errors": 0}
+
+    def record(fut, t_submit):
+        t_done = time.monotonic()
+        exc = fut.exception()
+        with lock:
+            if exc is None:
+                outcomes["completed"] += 1
+                latencies.append(t_done - t_submit)
+            elif isinstance(exc, guard.DeadlineExceeded):
+                outcomes["shed"] += 1
+            else:
+                outcomes["errors"] += 1
+
+    def submit(event, rows):
+        t_submit = time.monotonic()
+        futs = svc.submit_many(rows, deadline=deadline_s)
+        for fut in futs:
+            fut.add_done_callback(lambda f: record(f, t_submit))
+        return futs
+
+    plan = (
+        faults.inject(f"serve.batch:delay={batch_delay_ms / 1000.0}")
+        if batch_delay_ms > 0
+        else contextlib.nullcontext()
+    )
+    with plan:
+        t_start = time.monotonic()
+        results = play(scenario, submit)
+        # throughput denominator = the OFFER window: the tail drain
+        # below would make achieved_qps track the queue's depth
+        offer_elapsed = time.monotonic() - t_start
+        futs = []
+        for event, res in zip(scenario.events, results):
+            if isinstance(res, (Overloaded, FleetUnavailable)):
+                # both are typed refusals at admission (a 503 on the
+                # wire).  An open breaker is where an overloaded service
+                # can land: on the v5e one run in two ended here with
+                # FleetUnavailable out of the offer loop (PR 21)
+                outcomes["rejected"] += event["rows"]
+            elif isinstance(res, Exception):
+                outcomes["errors"] += event["rows"]
+            else:
+                futs.extend(res)
+        # everything admitted resolves (completed or shed): the report
+        # accounts for every offered request
+        futures_wait(futs, timeout=duration + 30.0)
+
+    snap1 = metrics.snapshot()
+
+    def batch_rows(snap):
+        hist = (snap.get("histograms") or {}).get("serve.batch_rows")
+        return hist or {"count": 0, "sum": 0.0}
+
+    def deadline_misses(snap):
+        return (snap.get("counters") or {}).get("serve.deadline_miss", 0.0)
+
+    batches = batch_rows(snap1)["count"] - batch_rows(snap0)["count"]
+    rows_batched = batch_rows(snap1)["sum"] - batch_rows(snap0)["sum"]
+    lat_ms = [x * 1000.0 for x in latencies]
+
+    def pct(p):
+        return round(float(np.percentile(lat_ms, p)), 2) if lat_ms else None
+
+    n_requests = scenario.total_rows()
+    return {
+        "offered_qps": qps,
+        "duration_s": duration,
+        "deadline_ms": deadline_ms,
+        "batch_delay_ms": batch_delay_ms,
+        "n_requests": n_requests,
+        **outcomes,
+        "achieved_qps": round(outcomes["completed"] / offer_elapsed, 1),
+        "p50_ms": pct(50),
+        "p99_ms": pct(99),
+        "batches": int(batches),
+        "mean_batch_occupancy": (
+            round(rows_batched / batches, 2) if batches else None
+        ),
+        "shed_rate": round(
+            (outcomes["shed"] + outcomes["rejected"]) / n_requests, 4
+        ),
+        "deadline_miss": int(deadline_misses(snap1) - deadline_misses(snap0)),
+    }
 
 
 def main(argv=None) -> int:
