@@ -15,6 +15,15 @@ weighted mean-centering providing the intercept.
 TPU form mirrors block_ls.py: one jitted scan-over-epochs /
 fori-over-blocks program; weighted Gramians contract over the row-sharded
 axis (all-reduce over ICI); the class axis shards over 'model'.
+
+A block's regularised Gramian XᵀDX + λn·I depends on the block, the
+weights and λ, and on no sweep.  A fit of more than one sweep therefore
+builds and factors each of them once, in a prologue loop of the same
+program, and every sweep's block step solves against the kept Cholesky
+factor — the same products, precision and order of summation as
+factoring anew in every sweep, which is what a one-sweep fit still does
+inline (:func:`factor_cache_blocks` decides, on shapes alone).  The
+reference keeps each block's statistics from its first pass as well.
 """
 
 from __future__ import annotations
@@ -27,14 +36,19 @@ import jax.numpy as jnp
 from jax import lax
 
 from keystone_tpu.models.block_ls import BlockLinearMapper, blockify
-from keystone_tpu.models.common import constrain, solve_spd
+from keystone_tpu.models.common import (
+    constrain,
+    factor_spd,
+    solve_factored,
+    solve_spd,
+)
 from keystone_tpu.parallel.collectives import (
     gram_panels,
     sharded_gram,
     sharded_matmul,
 )
 from jax.sharding import PartitionSpec as P
-from keystone_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+from keystone_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, current_mesh
 from keystone_tpu.workflow.dataset import Dataset
 from keystone_tpu.workflow.estimator import LabelEstimator
 
@@ -176,11 +190,16 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         x = jnp.asarray(x, jnp.float32)
         y = jnp.asarray(y, jnp.float32)
         nf = jnp.float32(n)
+        kept = factor_cache_blocks(
+            x.shape[0], x.shape[1], self.block_size, self.num_iter
+        )
         # the host's part of the solve (the dispatch); nothing here waits
         with ledger.span(
             "solver.fit", solver="bcd.weighted", n=int(n),
             blocks=-(-x.shape[1] // self.block_size),
             gram_panels=gram_panels(self.block_size),
+            factor_cache=kept,
+            factor_cache_bytes=kept * self.block_size**2 * 4,
         ):
             alpha = class_weights(y, nf, self.mixture_weight)
             weights, xm, ym = _weighted_bcd_fit(
@@ -192,6 +211,21 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         return finish_block_model(
             weights, xm, ym, x.shape[1], self.block_size, self.fit_intercept
         )
+
+
+def factor_cache_blocks(n_rows: int, d: int, block_size: int, num_iter: int) -> int:
+    """How many blocks' Cholesky factors :func:`_weighted_bcd_fit` keeps
+    across its sweeps: all ``ceil(d / block_size)`` or none.  Two shapes
+    decide, both static.  One sweep has no second use for a factor.  And
+    the ``(blocks, block_size, block_size)`` float32 factors, replicated,
+    may cost a device no more than one more copy of its rows of the
+    features (the solver holds two, ``x`` and ``xb``): the rows a device
+    holds, array rows over the mesh's data axis, are at least
+    ``block_size``.  At width 4096 sixteen factors are 1.07 GB."""
+    rows_a_device = n_rows // current_mesh().shape[DATA_AXIS]
+    if num_iter > 1 and rows_a_device >= block_size:
+        return -(-d // block_size)
+    return 0
 
 
 @partial(
@@ -223,20 +257,41 @@ def _weighted_bcd_fit(
     w0 = jnp.zeros((nb, bs, k), jnp.float32)
     p0 = jnp.zeros_like(yc)
 
+    def scaled(b):
+        return xb[b] * sa[:, None]  # √α-scaled block: AᵀA = XᵀDX
+
+    def gram(a):
+        with jax.named_scope("bcd.gram"):
+            return sharded_gram(a)
+
+    factors = None
+    if factor_cache_blocks(n_rows, x.shape[1], block_size, num_iter):
+
+        def factor_block(b):
+            ata = gram(scaled(b))
+            with jax.named_scope("bcd.solve"):
+                return factor_spd(ata, reg=lam * n)
+
+        # once per fit: no sweep changes a block's Gramian or its factor
+        factors = constrain(lax.map(factor_block, jnp.arange(nb)))
+
     def block_step(b, carry):
         w, p = carry
-        a = xb[b] * sa[:, None]  # √α-scaled block: AᵀA = XᵀDX
+        a = scaled(b)
         wb = w[b]
         # scopes are metadata only (what an operator reads in a device
         # trace): the HLO and the compile cache's key do not change
         with jax.named_scope("bcd.residual"):
             target = (yc - p) * sa[:, None] + a @ wb
-        with jax.named_scope("bcd.gram"):
-            ata = sharded_gram(a)
+        if factors is None:
+            ata = gram(a)
         with jax.named_scope("bcd.cross"):
             atr = sharded_matmul(a, target, out_spec=P(None, MODEL_AXIS))
         with jax.named_scope("bcd.solve"):
-            wb_new = solve_spd(ata, atr, reg=lam * n)
+            if factors is None:
+                wb_new = solve_spd(ata, atr, reg=lam * n)
+            else:
+                wb_new = solve_factored(factors[b], atr)
         with jax.named_scope("bcd.residual"):
             p_new = constrain(p + xb[b] @ (wb_new - wb), DATA_AXIS, MODEL_AXIS)
         return w.at[b].set(wb_new), p_new

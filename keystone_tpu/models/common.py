@@ -10,14 +10,27 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from keystone_tpu.parallel import mesh as _mesh
 
 
+def factor_spd(A: jnp.ndarray, reg: float = 0.0) -> jnp.ndarray:
+    """The upper Cholesky factor of (A + reg·I) for symmetric
+    positive-definite A: the half of :func:`solve_spd` that does not see
+    the right-hand side, for a caller that solves against one matrix more
+    than once (:func:`solve_factored`)."""
+    d = A.shape[0]
+    A = A + reg * jnp.eye(d, dtype=A.dtype)
+    c, _ = jax.scipy.linalg.cho_factor(A)
+    return c
+
+
+def solve_factored(c: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
+    """Solve against a factor :func:`factor_spd` returned."""
+    return jax.scipy.linalg.cho_solve((c, False), B)
+
+
 def solve_spd(A: jnp.ndarray, B: jnp.ndarray, reg: float = 0.0) -> jnp.ndarray:
     """Solve (A + reg·I) X = B for symmetric positive-definite A via
     Cholesky — the on-device replacement for every reference driver-side
     ``cholesky(... + λI) \\ ...`` (e.g. nodes/learning/BlockLeastSquares.scala)."""
-    d = A.shape[0]
-    A = A + reg * jnp.eye(d, dtype=A.dtype)
-    c, lower = jax.scipy.linalg.cho_factor(A)
-    return jax.scipy.linalg.cho_solve((c, lower), B)
+    return solve_factored(factor_spd(A, reg), B)
 
 
 def constrain(x, *spec):

@@ -108,6 +108,8 @@ ATTR_VOCABULARY = {
     "epoch",
     "epoch_seconds",
     "error",
+    "factor_cache",
+    "factor_cache_bytes",
     "failed_attempt_seconds",
     "from_state",
     "from_replica",

@@ -548,3 +548,176 @@ def test_kernel_spill_dir_refuses_foreign_files(tmp_path):
     assert meta1["fingerprint"] != json.load(
         open(d2 / "kcache_meta.json")
     )["fingerprint"]
+
+
+# ------------------------------------------- the weighted solver's kept factors
+# A fit of more than one sweep factors each block's regularised Gramian
+# once, before the first sweep (models/block_weighted_ls.py §
+# factor_cache_blocks); these hold it to a Gauss–Seidel that factors anew
+# in every sweep, and to the program's shape.  The suite's mesh has four
+# devices on the data axis, so a device holds a quarter of the rows.
+_FC_BLOCK = 16
+_FC_SHAPES = {  # rows, features: blocks of _FC_BLOCK
+    "rows_ge_block": (64, 64),
+    "ragged_last_block": (64, 56),
+    "rows_lt_block": (32, 64),
+}
+
+
+def _fc_data(rows, d, k=3, seed=11):
+    from keystone_tpu.models import block_weighted_ls as bw
+
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(rows, d)).astype(np.float32))
+    cls = rng.integers(0, k, size=rows)
+    cls[: rows // 2] = 0  # skewed classes, so the weights differ
+    y = jnp.asarray(2.0 * np.eye(k, dtype=np.float32)[cls] - 1.0)
+    nf = jnp.float32(rows)
+    return x, y, bw.class_weights(y, nf, 0.5), nf
+
+
+def _gauss_seidel_factoring_every_sweep(x, y, alpha, n, lam, sweeps, bs, intercept):
+    """The same sweep in plain ``jax.numpy``: every block's Gramian and its
+    Cholesky factor rebuilt in every sweep."""
+    hi = jax.lax.Precision.HIGHEST
+    if intercept:
+        wsum = jnp.sum(alpha)
+        ok = (alpha > 0).astype(jnp.float32)[:, None]
+        x = (x - (alpha @ x) / wsum) * ok
+        y = (y - (alpha @ y) / wsum) * ok
+    nb = -(-x.shape[1] // bs)
+    x = jnp.pad(x, ((0, 0), (0, nb * bs - x.shape[1])))
+    sa = jnp.sqrt(alpha)[:, None]
+    w = [jnp.zeros((bs, y.shape[1]), jnp.float32) for _ in range(nb)]
+    p = jnp.zeros_like(y)
+    for _ in range(sweeps):
+        for b in range(nb):
+            xb = x[:, b * bs:(b + 1) * bs]
+            a = xb * sa
+            target = (y - p) * sa + a @ w[b]
+            ata = jnp.matmul(a.T, a, precision=hi) + lam * n * jnp.eye(bs)
+            factor = jax.scipy.linalg.cho_factor(ata)
+            new = jax.scipy.linalg.cho_solve(factor, jnp.matmul(a.T, target, precision=hi))
+            p = p + xb @ (new - w[b])
+            w[b] = new
+    return np.asarray(jnp.stack(w))
+
+
+@pytest.mark.parametrize("num_iter", [1, 2, 3])
+@pytest.mark.parametrize("intercept", [True, False], ids=["intercept", "no_intercept"])
+@pytest.mark.parametrize("shape", list(_FC_SHAPES))
+def test_weighted_bcd_keeping_factors_fits_what_refactoring_fits(
+    shape, intercept, num_iter, monkeypatch
+):
+    from keystone_tpu.models import block_weighted_ls as bw
+
+    rows, d = _FC_SHAPES[shape]
+    blocks = -(-d // _FC_BLOCK)
+    kept = bw.factor_cache_blocks(rows, d, _FC_BLOCK, num_iter)
+    assert kept == (blocks if num_iter > 1 and shape != "rows_lt_block" else 0)
+    x, y, alpha, nf = _fc_data(rows, d)
+    fit = lambda: np.asarray(  # noqa: E731
+        bw._weighted_bcd_fit(x, y, alpha, nf, 0.1, num_iter, _FC_BLOCK, intercept)[0]
+    )
+    got = fit()
+    want = _gauss_seidel_factoring_every_sweep(
+        x, y, alpha, nf, 0.1, num_iter, _FC_BLOCK, intercept
+    )
+    assert got.shape == want.shape == (blocks, _FC_BLOCK, 3)
+    assert np.linalg.norm(got - want) / np.linalg.norm(want) < 1e-5
+    if kept:
+        # the solver's own program with the rule answering "none": the same
+        # operations on the same device layout, so the same bits
+        monkeypatch.setattr(bw, "factor_cache_blocks", lambda *a: 0)
+        bw._weighted_bcd_fit.clear_cache()
+        try:
+            refactored = fit()
+        finally:
+            bw._weighted_bcd_fit.clear_cache()
+        assert np.array_equal(got, refactored)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _fc_jaxpr(shape, num_iter):
+    from keystone_tpu.models import block_weighted_ls as bw
+
+    x, y, alpha, nf = _fc_data(*_FC_SHAPES[shape])
+    (call,) = jax.make_jaxpr(
+        lambda *a: bw._weighted_bcd_fit(*a, 0.1, num_iter, _FC_BLOCK, True)
+    )(x, y, alpha, nf).eqns
+    return call.params["jaxpr"].jaxpr  # the jitted program's own body
+
+
+def _loops(jaxpr):
+    """The program's outermost loops (a ``fori_loop`` of static bounds
+    traces to a ``scan`` as well), by length."""
+    return {e.params["length"]: e.params["jaxpr"].jaxpr for e in jaxpr.eqns
+            if e.primitive.name == "scan"}
+
+
+def _shapes_out(eqns):
+    return {tuple(v.aval.shape) for e in eqns for v in e.outvars}
+
+
+def test_no_sweep_of_a_three_sweep_fit_builds_or_factors_a_gramian():
+    program = _fc_jaxpr("rows_ge_block", 3)
+    eqns = list(_eqns(program))
+    blocks = 64 // _FC_BLOCK
+    loops = _loops(program)
+    assert set(loops) == {3, blocks}  # the sweeps; the prologue over the blocks
+    sweep = list(_eqns(loops[3]))
+    assert "cholesky" not in {e.primitive.name for e in sweep}
+    assert "triangular_solve" in {e.primitive.name for e in sweep}
+    dots = [e for e in sweep if e.primitive.name == "dot_general"]
+    assert dots and (_FC_BLOCK, _FC_BLOCK) not in _shapes_out(dots)
+    # all of that is in the prologue, once a block
+    prologue = list(_eqns(loops[blocks]))
+    assert [e.primitive.name for e in eqns].count("cholesky") == 1
+    assert [e.primitive.name for e in prologue].count("cholesky") == 1
+    assert (_FC_BLOCK, _FC_BLOCK) in _shapes_out(
+        e for e in prologue if e.primitive.name == "dot_general"
+    )
+    assert (blocks, _FC_BLOCK, _FC_BLOCK) in _shapes_out(eqns)
+
+
+@pytest.mark.parametrize(
+    "shape,num_iter", [("rows_ge_block", 1), ("rows_lt_block", 3)],
+    ids=["one_sweep", "rows_lt_block"],
+)
+def test_a_fit_that_keeps_no_factor_holds_no_array_of_them(shape, num_iter):
+    program = _fc_jaxpr(shape, num_iter)
+    assert (64 // _FC_BLOCK, _FC_BLOCK, _FC_BLOCK) not in _shapes_out(_eqns(program))
+    # Gramian and factor are where they were: in the block step of the sweeps
+    loops = _loops(program)
+    assert set(loops) == {num_iter}
+    names = [e.primitive.name for e in _eqns(loops[num_iter])]
+    assert names.count("cholesky") == 1
+
+
+def test_solve_spd_is_its_two_halves_and_traces_as_before():
+    from keystone_tpu.models.common import factor_spd, solve_factored, solve_spd
+
+    def before(A, B, reg):
+        A = A + reg * jnp.eye(A.shape[0], dtype=A.dtype)
+        c, lower = jax.scipy.linalg.cho_factor(A)
+        return jax.scipy.linalg.cho_solve((c, lower), B)
+
+    rng = np.random.default_rng(5)
+    m = rng.normal(size=(24, 8)).astype(np.float32)
+    A, B = jnp.asarray(m.T @ m), jnp.asarray(rng.normal(size=(8, 3)).astype(np.float32))
+    halves = lambda A, B, reg: solve_factored(factor_spd(A, reg), B)  # noqa: E731
+    texts = {
+        str(jax.make_jaxpr(f)(A, B, 0.5)) for f in (before, halves, lambda *a: solve_spd(*a))
+    }
+    assert len(texts) == 1
+    assert np.array_equal(solve_spd(A, B, 0.5), before(A, B, 0.5))

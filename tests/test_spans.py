@@ -169,8 +169,11 @@ def test_fit_without_ledger_leaves_one_tree_and_no_file(tmp_path, monkeypatch):
             assert set(r.attrs) == {"rule", "batch"}
         if r.name == "solver.fit":
             assert by_id[r.parent_id].name == "executor.stage"
+            # two sweeps, 24 rows a device on the suite's mesh, blocks of 8:
+            # the three 8 × 8 float32 factors are kept across the sweeps
             assert r.attrs == {
                 "solver": "bcd.weighted", "n": 96, "blocks": 3, "gram_panels": 1,
+                "factor_cache": 3, "factor_cache_bytes": 3 * 8 * 8 * 4,
             }
         if r.name == "executor.stage":
             assert by_id[r.parent_id].name == "pipeline.fit"

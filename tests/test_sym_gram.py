@@ -6,7 +6,8 @@ diagonal (sixteen column panels at a width that is a multiple of 2048,
 eight at another multiple of 1024) and copies the rest.  These tests hold the panelled form to the one dot
 it replaced: same entries to f32 rounding, exactly symmetric, replicated
 under the mesh, 53 % of the flops, the same fitted weights — and the
-``solver.fit`` span says how many panels a fit ran with.
+``solver.fit`` span says how many panels a fit ran with, and how many
+blocks' Cholesky factors the weighted solver kept across its sweeps.
 """
 
 import jax
@@ -132,6 +133,34 @@ def test_solver_fit_span_carries_gram_panels(estimator, solver, block_size, pane
     assert fits[0].attrs["solver"] == solver
     assert fits[0].attrs["blocks"] == 2
     assert fits[0].attrs["gram_panels"] == panels == gram_panels(block_size)
+    # one sweep keeps no factor; only the weighted solver has the cache
+    kept = {k: v for k, v in fits[0].attrs.items() if k.startswith("factor_cache")}
+    assert kept == (
+        {"factor_cache": 0, "factor_cache_bytes": 0} if solver == "bcd.weighted" else {}
+    )
+
+
+@pytest.mark.parametrize(
+    "rows,num_iter,kept",
+    [(384, 2, 2), (384, 1, 0), (32, 2, 0)],
+    ids=["two_sweeps", "one_sweep", "rows_lt_block"],
+)
+def test_solver_fit_span_says_how_many_factors_the_fit_kept(rows, num_iter, kept):
+    # four devices on the suite's data axis: 96 rows a device, or 8
+    block_size = 96
+    rng = np.random.default_rng(2)
+    x = rng.normal(size=(rows, 2 * block_size)).astype(np.float32)
+    y = 2.0 * np.eye(4, dtype=np.float32)[rng.integers(0, 4, size=rows)] - 1.0
+    mark = max((r.span_id for r in ledger.recent_spans()), default=0)
+    bw.BlockWeightedLeastSquaresEstimator(
+        block_size=block_size, num_iter=num_iter, lam=1e-2
+    ).fit_arrays(x, y)
+    (fit,) = [
+        r for r in ledger.recent_spans()
+        if r.span_id > mark and r.name == "solver.fit"
+    ]
+    assert fit.attrs["factor_cache"] == kept
+    assert fit.attrs["factor_cache_bytes"] == kept * block_size * block_size * 4
 
 
 def test_obs_report_shows_the_solvers_gram_panels(tmp_path):
@@ -151,4 +180,7 @@ def test_obs_report_shows_the_solvers_gram_panels(tmp_path):
     summary = summarize(path)
     assert summary["solvers"]["bcd.weighted"]["count"] == 1
     assert summary["solvers"]["bcd.weighted"]["gram_panels"] == 8
-    assert "gram_panels=8" in render(summary)
+    assert summary["solvers"]["bcd.weighted"]["factor_cache"] == 0
+    text = render(summary)
+    assert "gram_panels=8" in text
+    assert "factor_cache=0  factor_cache_bytes=0" in text

@@ -21,9 +21,11 @@ Sections (each only when the run recorded it):
 - **solvers**: fits, host seconds and the static shape of each solver's
   ``solver.fit`` spans (``n``, ``blocks``; the BCD solvers' ``gram_panels``
   — how many column panels the block Gramian was split into, 1 is the
-  one dot; the kernel sweeps' ``block_size``, ``epochs``, ``gram`` — the
-  route the ``gram_pallas`` gate resolved, ``pallas`` or ``xla`` — and
-  the cached sweep's ``cache_hits``);
+  one dot; the weighted solver's ``factor_cache`` — the blocks whose
+  Cholesky factor it kept across its sweeps, 0 where it factors in every
+  sweep — and ``factor_cache_bytes``; the kernel sweeps' ``block_size``,
+  ``epochs``, ``gram`` — the route the ``gram_pallas`` gate resolved,
+  ``pallas`` or ``xla`` — and the cached sweep's ``cache_hits``);
 - **retries**: retry totals across executor, durable I/O, blockstore,
   and streams;
 - **convergence**: per-solver epoch series (objective / grad norm /
