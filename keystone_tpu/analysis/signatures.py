@@ -31,13 +31,9 @@ from typing import List
 
 from keystone_tpu.analysis.findings import PASS_SIGNATURES, Finding
 from keystone_tpu.workflow import graph as G
+from keystone_tpu.workflow.transformer import PLAIN_TYPES, PLUMBING_ATTRS
 
 logger = logging.getLogger(__name__)
-
-#: instance attributes that are caches/plumbing, never identity
-_SKIP_ATTRS = {"_fp", "_jitted", "_breaker_token", "fallback", "optional"}
-
-_SIMPLE = (int, float, str, bool, bytes, type(None))
 
 #: value-compare arrays up to this many elements (device→host read is
 #: bounded); larger arrays compare by shape/dtype only
@@ -49,15 +45,15 @@ def _state_conflict(a, b) -> str:
     equal-signature instances, or '' when none is detectable."""
     import numpy as np
 
-    va = {k: v for k, v in vars(a).items() if k not in _SKIP_ATTRS}
-    vb = {k: v for k, v in vars(b).items() if k not in _SKIP_ATTRS}
+    va = {k: v for k, v in vars(a).items() if k not in PLUMBING_ATTRS}
+    vb = {k: v for k, v in vars(b).items() if k not in PLUMBING_ATTRS}
     for k in sorted(set(va) | set(vb)):
         if k.startswith("__"):
             continue
         x, y = va.get(k, _MISSING), vb.get(k, _MISSING)
         if x is _MISSING or y is _MISSING:
             return k
-        if isinstance(x, _SIMPLE) or isinstance(y, _SIMPLE):
+        if isinstance(x, PLAIN_TYPES) or isinstance(y, PLAIN_TYPES):
             if type(x) is not type(y) or x != y:
                 return k
             continue

@@ -215,7 +215,11 @@ class Pooler(Transformer):
         self.pool_mode = pool_mode
 
     def params(self):
-        return (self.stride, self.pool_size, self.pool_mode, self.pixel_fn is None)
+        # a pixel function has no identity to compare: such a pooler is
+        # never merged and keeps its own program (TermFrequency's rule)
+        if self.pixel_fn is not None:
+            return None
+        return (self.stride, self.pool_size, self.pool_mode)
 
     def apply_batch(self, xs, mask=None):
         x = xs.astype(jnp.float32)

@@ -26,7 +26,13 @@ import jax
 
 from keystone_tpu.workflow import graph as G
 from keystone_tpu.workflow.estimator import Estimator
-from keystone_tpu.workflow.transformer import Cacher, Transformer, jit_named, mint_span
+from keystone_tpu.workflow.transformer import (
+    Cacher,
+    Transformer,
+    jit_named,
+    mint_span,
+    share_key,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -294,24 +300,8 @@ def _truncate_datasets(graph: G.Graph, k: int) -> G.Graph:
 # ------------------------------------------------------------- stage fusion
 
 #: class-shared jitted fused chains, keyed by (per-stage share keys,
-#: matmul mode) — see FusedTransformer._share_key
+#: matmul mode) — see transformer.share_key
 _FUSED_SHARED_CACHE: dict = {}
-
-
-def _stage_share_key(s: Transformer):
-    """Identity of one stage for cross-instance program sharing.
-
-    Stages declaring traced_attrs share by (class, jit_static) with
-    their arrays passed as traced arguments; stages without share by
-    (class, params()) — the CSE contract already promises params()
-    fully identifies such a transformer.  None = not shareable (params()
-    is None), which disables sharing for the whole chain."""
-    ta = type(s).traced_attrs
-    if ta:
-        st = s.jit_static()
-        return None if st is None else ("T", type(s), st)
-    p = s.params()
-    return None if p is None else ("C", type(s), p)
 
 
 class FusedTransformer(Transformer):
@@ -361,7 +351,7 @@ class FusedTransformer(Transformer):
         from keystone_tpu.utils import precision
 
         mode = precision.matmul_mode()
-        skeys = tuple(_stage_share_key(s) for s in self.stages)
+        skeys = tuple(share_key(s) for s in self.stages)
         if all(k is not None for k in skeys):
             # the input signature scopes the untraceable memo (one odd
             # dtype/rank must not pin every later call of the chain to

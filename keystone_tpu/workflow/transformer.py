@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import contextlib
 import re
+import threading
 import weakref
 from typing import Callable, Optional
 
@@ -26,14 +27,24 @@ import numpy as np
 
 from keystone_tpu.workflow.dataset import Dataset, as_dataset
 
-#: per-transformer jitted apply_batch wrappers (see _apply_batch_jitted)
+#: per-OBJECT jitted apply_batch wrappers, for the nodes that promise no
+#: identity (share_key() is None) or hold an array they do not declare
+#: in traced_attrs: weak, so wrapper and arrays die with the node (see
+#: _apply_batch_jitted)
 _JIT_APPLY_CACHE = weakref.WeakKeyDictionary()
 
-#: CLASS-shared jitted applies for transformers declaring traced_attrs:
-#: (cls, jit_static(), input signature, param signature) -> jitted fn
-#: (or None = memoized untraceable for that exact signature).  Values
+#: process-wide jitted applies, one per kind of node:
+#: (share_key(node), input signature, traced_param_sig(node)) -> jitted
+#: fn (or None = memoized untraceable for that exact signature).  Every
+#: equal node built later -- in the same graph, the next fit, the next
+#: build -- calls the same wrapper and hits its trace cache.  Values
 #: hold parameter-stripped template copies, never fitted arrays.
+#: Bounded FIFO like optimizer._FUSED_SHARED_CACHE: a process sweeping a
+#: constructor argument mints a key per value, and an evicted live node
+#: just mints again.
 _SHARED_APPLY_CACHE: dict = {}
+_SHARED_APPLY_MAX = 128
+_SHARED_APPLY_LOCK = threading.Lock()
 
 
 def _class_names(stages) -> str:
@@ -80,6 +91,10 @@ def stripped_template(t: "Transformer") -> "Transformer":
     tpl = copy.copy(t)
     for name in type(t).traced_attrs:
         setattr(tpl, name, None)
+    if tpl.fallback is not None:
+        # the executor reads the substitute from the node, never from
+        # the template; a fitted substitute must not be pinned either
+        tpl.fallback = None
     for derived in ("_fp", "_jitted"):
         if derived in getattr(tpl, "__dict__", {}):
             try:
@@ -109,6 +124,69 @@ def traced_param_sig(t: "Transformer") -> tuple:
                 )
             )
     return tuple(sig)
+
+
+#: instance attributes that are caches or the executor's plumbing, never
+#: part of what a node computes (analysis/signatures.py reads this too)
+PLUMBING_ATTRS = frozenset({"_fp", "_jitted", "_breaker_token", "fallback", "optional"})
+
+#: what an attribute has to be for its value to be compared as it stands
+PLAIN_TYPES = (int, float, str, bool, bytes, type(None))
+
+
+def _is_plain(v) -> bool:
+    return isinstance(v, PLAIN_TYPES) or (
+        isinstance(v, tuple) and all(_is_plain(e) for e in v)
+    )
+
+
+def share_key(t: "Transformer"):
+    """Identity of one transformer for cross-instance program sharing:
+    the one rule for "the same node", used by the single node's apply
+    (``Transformer._apply_batch_jitted``) and by every stage of a fused
+    chain (``optimizer.FusedTransformer``).
+
+    Classes declaring traced_attrs share by (class, jit_static()) with
+    their arrays passed as traced arguments.  The rest share by (class,
+    params()): the CSE contract already promises params() fully
+    identifies such a transformer.  A shared program makes a hole in
+    that promise outlive the object, so the key also holds what the code
+    can see of the instance beside the promise — its plain attributes
+    (numbers, strings, tuples of them) by name: two nodes whose params()
+    are equal and whose ``scale`` differs get a program each, where one
+    would silently run its twin's.  None = not shareable (params() is
+    None: the node never promised an identity), which keeps a single
+    node per object and disables sharing for a whole chain.
+
+    The degradation contract (``optional``, ``fallback``; what
+    ``signature()`` adds for CSE) is NOT in the key: the executor acts
+    on it around the node, apply_batch never reads it, so a degrading
+    node runs the program of its plain twin (``stripped_template`` drops
+    the substitute from the shared template)."""
+    if type(t).traced_attrs:
+        st = t.jit_static()
+        return None if st is None else ("T", type(t), st)
+    p = t.params()
+    if p is None:
+        return None
+    seen = tuple(
+        (name, v)
+        for name, v in sorted(getattr(t, "__dict__", {}).items())
+        if name not in PLUMBING_ATTRS and _is_plain(v)
+    )
+    return ("C", type(t), p, seen)
+
+
+def _holds_array(t: "Transformer") -> bool:
+    """Whether any instance attribute's pytree leaves include a device
+    or host array.  Held without a traced_attrs declaration, it would be
+    pinned by a process-lifetime template, and since PR 26 such a node's
+    params() may be a content digest that mints a new key every refit."""
+    return any(
+        isinstance(leaf, (jax.Array, np.ndarray))
+        for leaf in jax.tree_util.tree_leaves(getattr(t, "__dict__", {}))
+    )
+
 
 #: canonical apply chunk (rows); 0 = whole-batch applies.
 #: Chunking pins the compiled programs' shapes so they stop scaling
@@ -238,7 +316,10 @@ class Transformer(Chainable):
     #: program, and embedding VALUES keys the persistent compile cache
     #: by the fit's bits, so every refit recompiled from scratch.
     #: Declaring classes must route every OTHER attribute that shapes
-    #: the trace through jit_static().  Empty = per-instance programs.
+    #: the trace through jit_static().  Empty = the node holds no fitted
+    #: array: it shares one program per (class, params()) if params() is
+    #: not None, and keeps a program per object otherwise or where it
+    #: holds an array it did not declare here (see share_key).
     traced_attrs: tuple = ()
     #: True for transformers whose apply_batch manages its OWN jit and
     #: program cache (FusedTransformer).  The generic per-instance jit
@@ -422,14 +503,23 @@ class Transformer(Chainable):
         PR 21.)  Untraceable apply_batch implementations (host-side numpy,
         data-dependent Python) fall back to the eager path.
 
-        The per-instance cache is keyed by (matmul mode, traced signature):
-        the mode key — the RESOLVED policy, one of f32/bf16/bf16_apply,
-        so e.g. enabling the bf16 apply path (utils/precision.py §
-        bf16_apply) retraces every chunked/whole-batch apply instead of
-        reusing a stale executable — and the signature key confines a
-        trace failure to the one input signature that caused it: one odd
-        mask/dtype combination must not pin every later call of this
-        instance to the eager path."""
+        A wrapper is minted once per (share_key, input signature) a
+        PROCESS, not once per node object: a node whose share_key() is
+        not None takes it from _SHARED_APPLY_CACHE, so a second
+        ``SIFTExtractor(step=4, bin_sizes=(4,))`` — in the same graph,
+        the next fit, the next build — traces, lowers and loads nothing.
+        Per object (the weak _JIT_APPLY_CACHE) stay the nodes the code
+        can see are not safe to pin: share_key() None (LambdaTransformer,
+        Cacher, any node that never promised an identity), and a node
+        that holds an array without declaring it in traced_attrs.
+
+        The signature key holds the matmul mode — the RESOLVED policy,
+        one of f32/bf16/bf16_apply, so e.g. enabling the bf16 apply path
+        (utils/precision.py § bf16_apply) retraces every chunked/
+        whole-batch apply instead of reusing a stale executable — and
+        confines a trace failure to the one input signature that caused
+        it: one odd mask/dtype combination must not pin every later call
+        to the eager path."""
         from keystone_tpu.utils import precision
 
         if type(self).self_jitted:
@@ -444,8 +534,12 @@ class Transformer(Chainable):
             getattr(xs, "ndim", None),
             None if mask is None else str(getattr(mask, "dtype", "")),
         )
-        if type(self).traced_attrs:
-            return self._apply_batch_shared(xs, mask, sig)
+        # the array check first: such a node's params() may have to
+        # digest the arrays it holds
+        undeclared = not type(self).traced_attrs and _holds_array(self)
+        skey = None if undeclared else share_key(self)
+        if skey is not None:
+            return self._apply_batch_shared(xs, mask, skey, sig)
         entry = _JIT_APPLY_CACHE.get(self)
         if entry is None:
             entry = {}
@@ -469,24 +563,30 @@ class Transformer(Chainable):
                 return fn(xs, mask)
         except (TypeError, jax.errors.JAXTypeError):
             entry[sig] = None  # don't re-pay a failed trace for this sig
-            import logging
+            return self._apply_batch_untraceable(xs, mask, sig)
 
-            logging.getLogger(__name__).warning(
-                "%s.apply_batch is untraceable for signature %s; using the "
-                "eager path (one dispatch per primitive)",
-                self.label,
-                sig,
-            )
-            return self.apply_batch(xs, mask=mask)
+    def _apply_batch_untraceable(self, xs, mask, sig):
+        import logging
 
-    def _apply_batch_shared(self, xs, mask, sig):
-        """Class-shared jitted apply for traced_attrs declarers.
+        logging.getLogger(__name__).warning(
+            "%s.apply_batch is untraceable for signature %s; using the "
+            "eager path (one dispatch per primitive)",
+            self.label,
+            sig,
+        )
+        return self.apply_batch(xs, mask=mask)
+
+    def _apply_batch_shared(self, xs, mask, skey, sig):
+        """Process-wide shared jitted apply (see _SHARED_APPLY_CACHE).
 
         The jitted callable closes over a parameter-STRIPPED template
-        copy of the first instance seen per (class, jit_static) key and
-        rebinds the traced attributes to tracer values at trace time —
-        so the compiled program is a pure function of parameter shapes,
-        shared by every instance and every refit."""
+        copy of the first instance seen per key — never over an
+        instance, which would die or be pinned — and rebinds the traced
+        attributes to tracer values at trace time, so the compiled
+        program is a pure function of parameter shapes, shared by every
+        equal instance and every refit.  A node without traced_attrs
+        passes an empty dict, which adds nothing to the lowered module:
+        its text is that of a plain ``(xs, mask)`` program."""
         import copy
 
         cls = type(self)
@@ -507,7 +607,7 @@ class Transformer(Chainable):
                 )
                 setattr(self, name, v)
             params[name] = v
-        key = (cls, self.jit_static(), sig, traced_param_sig(self))
+        key = (skey, sig, traced_param_sig(self))
         sentinel = object()
         fn = _SHARED_APPLY_CACHE.get(key, sentinel)
         if fn is None:  # memoized "untraceable" for this exact signature
@@ -522,21 +622,17 @@ class Transformer(Chainable):
                     setattr(obj, name, v)
                 return obj.apply_batch(a, mask=m)
 
-            fn = _SHARED_APPLY_CACHE[key] = jit_named(run, [self])
+            fn = jit_named(run, [self])
+            with _SHARED_APPLY_LOCK:
+                while len(_SHARED_APPLY_CACHE) >= _SHARED_APPLY_MAX:
+                    _SHARED_APPLY_CACHE.pop(next(iter(_SHARED_APPLY_CACHE)))
+                _SHARED_APPLY_CACHE[key] = fn
         try:
             with mint_span(minted, [self], shared=True):
                 return fn(params, xs, mask)
         except (TypeError, jax.errors.JAXTypeError):
             _SHARED_APPLY_CACHE[key] = None
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "%s.apply_batch is untraceable for signature %s; using the "
-                "eager path (one dispatch per primitive)",
-                self.label,
-                sig,
-            )
-            return self.apply_batch(xs, mask=mask)
+            return self._apply_batch_untraceable(xs, mask, sig)
 
     def __call__(self, x):
         from keystone_tpu.workflow.pipeline import Pipeline, PipelineDataset

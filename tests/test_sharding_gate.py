@@ -260,7 +260,7 @@ def test_shared_traced_param_apply_stays_sharded(mesh):
     keys = [
         kk
         for kk in T._SHARED_APPLY_CACHE
-        if kk[0] is PCATransformer and callable(T._SHARED_APPLY_CACHE[kk])
+        if kk[0][1] is PCATransformer and callable(T._SHARED_APPLY_CACHE[kk])
     ]
     assert keys, "shared apply did not compile"
     # lower the same wrapper at the same signature and gate the HLO

@@ -304,7 +304,7 @@ def test_shared_program_is_named_for_its_class_not_its_parameters():
     assert not np.allclose(ya, yb)
     mints = [r for r in _since(mark) if r.name == "transformer.jit_mint"]
     assert [r.attrs for r in mints] == [{"node": "CosineRandomFeatures", "shared": True}]
-    (fn,) = [f for k, f in T._SHARED_APPLY_CACHE.items() if k[0] is CosineRandomFeatures]
+    (fn,) = [f for k, f in T._SHARED_APPLY_CACHE.items() if k[0][1] is CosineRandomFeatures]
     params = {"w": a.w, "b": a.b}
     assert _module_name(fn, params, data.array, None) == "jit_apply_CosineRandomFeatures"
 
@@ -329,6 +329,44 @@ def test_fused_chain_is_named_for_its_classes():
     assert long.__name__ == ("fused_" + "_".join(type(s).__name__ for s in chain.stages * 12))[:96]
     assert "jit_" + long.__name__.rstrip("_") == _module_name(long, x)
     assert O._FUSED_SHARED_CACHE or chain._jitted  # the chain's wrapper is cached
+
+
+def test_second_fit_of_the_imagenet_graph_mints_no_node_program(imagenet_toy_config):
+    """Build and fit the north-star graph twice in one process: the
+    second fit's nodes are new objects with the first's (class,
+    params()), so none of them mints a wrapper — not the five of the
+    featurizer inside the fit, not the build's eager label node before
+    it.  What the second fit still asks of the compiler (what
+    ``fit_programs`` reads) is what the sampling rule compiles to price
+    a node, which opens the same span under ``optimizer.rule``
+    (``profiling.py § _static_node_seconds``: another mechanism)."""
+    from keystone_tpu.loaders.imagenet import ImageNetLoader
+    from keystone_tpu.pipelines import ImageNetSiftLcsFV
+
+    cfg = imagenet_toy_config
+    train = ImageNetLoader.synthetic(
+        cfg.synthetic_n, cfg.num_classes, size=(cfg.image_size, cfg.image_size), seed=1
+    )
+    log = compile_log.CompileLog().install()
+
+    def build_and_fit():
+        mark, before = _mark(), log.snapshot()
+        ImageNetSiftLcsFV.build_scorer(cfg, train.data, train.labels).fit().block_until_ready()
+        return _since(mark), compile_log.delta(log.snapshot(), before)
+
+    build_and_fit()  # a process's first fit mints for real (unless an earlier test's did)
+    records, asked = build_and_fit()
+    by_id = {r.span_id: r for r in records}
+    mints = [r for r in records if r.name == "transformer.jit_mint"]
+    applied = [
+        r for r in mints
+        if getattr(by_id.get(r.parent_id), "name", None) != "optimizer.rule"
+    ]
+    assert [r.attrs for r in applied] == []
+    assert all(r.attrs["shared"] is False for r in mints)  # the priced ones
+    (root,) = [r for r in records if r.name == "pipeline.fit"]
+    assert all(r.root_id == root.span_id for r in mints)
+    assert (asked["requests"] or asked["backend_compiles"]) == len(mints)
 
 
 # ---------------------------------------------------------------- the readers

@@ -5,6 +5,7 @@ GraphSuite.scala pattern: toy graphs, side-effect counters in fake nodes to
 assert CSE merges and memoized execution counts (SURVEY.md §4).
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -418,7 +419,7 @@ def test_traced_params_share_one_program_across_instances():
 
     # hermetic: earlier tests may have populated PCA entries for other
     # input signatures (bf16 mode, masked applies)
-    for k in [k for k in T._SHARED_APPLY_CACHE if k[0] is PCATransformer]:
+    for k in [k for k in T._SHARED_APPLY_CACHE if k[0][1] is PCATransformer]:
         del T._SHARED_APPLY_CACHE[k]
 
     rng = np.random.default_rng(0)
@@ -437,9 +438,9 @@ def test_traced_params_share_one_program_across_instances():
     # one shared wrapper per parameter STRUCTURE (mean present vs absent
     # key separately so a bad instance poisons only its own signature);
     # instances with equal structure share one wrapper and one program
-    keys = [k for k in T._SHARED_APPLY_CACHE if k[0] is PCATransformer]
+    keys = [k for k in T._SHARED_APPLY_CACHE if k[0][1] is PCATransformer]
     assert len(keys) == 2
-    key2 = [k for k in keys if k[3] == T.traced_param_sig(p2)]
+    key2 = [k for k in keys if k[2] == T.traced_param_sig(p2)]
     assert len(key2) == 1
     fn = T._SHARED_APPLY_CACHE[key2[0]]
     # a third instance with the SAME structure as p2 must hit the cache,
@@ -471,6 +472,227 @@ def test_traced_params_refit_uses_new_values():
         Dataset(xs, shard=False)).array)
     np.testing.assert_allclose(out1, xs @ w1)
     np.testing.assert_allclose(out2, xs @ w2)
+
+
+# ------------------------------------- parameter-free nodes: one program a kind
+def _transformer_module():
+    import importlib
+
+    # the workflow package re-exports the `transformer` DECORATOR under
+    # the module's name, so attribute-style imports get the function
+    return importlib.import_module("keystone_tpu.workflow.transformer")
+
+
+def _shared_entries(cls):
+    T = _transformer_module()
+    return {k: f for k, f in T._SHARED_APPLY_CACHE.items() if k[0][1] is cls}
+
+
+def _images(dtype, shape=(2, 32, 32, 3)):
+    x = np.random.default_rng(7).integers(0, 256, size=shape)
+    return jnp.asarray(x.astype(dtype))
+
+
+def _parameter_free_cases():
+    from keystone_tpu.ops import (
+        ClassLabelIndicators,
+        GrayScaler,
+        LCSExtractor,
+        PixelScaler,
+        SIFTExtractor,
+    )
+
+    return [
+        pytest.param(
+            lambda: PixelScaler(only_if_integer=True), lambda: PixelScaler(),
+            lambda: _images(np.uint8), id="PixelScaler",
+        ),
+        pytest.param(GrayScaler, None, lambda: _images(np.float32), id="GrayScaler"),
+        pytest.param(
+            lambda: SIFTExtractor(step=4, bin_sizes=(4,)),
+            lambda: SIFTExtractor(step=8, bin_sizes=(4,)),
+            lambda: _images(np.float32, (2, 32, 32)), id="SIFTExtractor",
+        ),
+        pytest.param(
+            lambda: LCSExtractor(step=4, subpatch_size=4),
+            lambda: LCSExtractor(step=4, subpatch_size=3),
+            lambda: _images(np.float32), id="LCSExtractor",
+        ),
+        pytest.param(
+            lambda: ClassLabelIndicators(5), lambda: ClassLabelIndicators(6),
+            lambda: jnp.arange(7, dtype=jnp.int32) % 5, id="ClassLabelIndicators",
+        ),
+    ]
+
+
+@pytest.mark.parametrize("make, make_other, make_input", _parameter_free_cases())
+def test_equal_parameter_free_nodes_share_one_program(make, make_other, make_input):
+    """A node that holds no array takes its jitted apply from the
+    process-wide cache by (class, params()): a second object with equal
+    params() — the next fit's, the next build's — calls the first's
+    wrapper and traces nothing; another params() gets its own; and the
+    shared program computes what the node's own program did."""
+    T = _transformer_module()
+    first, second, xs = make(), make(), make_input()
+    cls = type(first)
+    for k in _shared_entries(cls):  # hermetic: other tests' entries of the class
+        del T._SHARED_APPLY_CACHE[k]
+    out = first._apply_batch_jitted(xs, None)
+    ((key, fn),) = _shared_entries(cls).items()
+    assert key[0] == T.share_key(first) == T.share_key(second)
+    assert first not in T._JIT_APPLY_CACHE
+    traced = fn._cache_size()
+    again = second._apply_batch_jitted(xs, None)
+    assert list(_shared_entries(cls)) == [key] and fn._cache_size() == traced
+    assert second not in T._JIT_APPLY_CACHE
+    # bit for bit what a per-object wrapper computed; the eager apply_batch
+    # within a rounding (XLA compiles PixelScaler's division to a multiply)
+    own = jax.jit(lambda a: first.apply_batch(a, mask=None))(xs)
+    eager = first.apply_batch(xs, mask=None)
+    for got in (out, again):
+        for g, o, e in zip(*map(jax.tree_util.tree_leaves, (got, own, eager))):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(o))
+            np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=1e-6, atol=0)
+    if make_other is not None:
+        other = make_other()
+        assert T.share_key(other) != key[0]
+        other._apply_batch_jitted(xs, None)
+        assert len(_shared_entries(cls)) == 2 and fn._cache_size() == traced
+
+
+class _Shift(Transformer):
+    """Holds an array it does not declare in traced_attrs."""
+
+    def __init__(self, mu, tag="shift"):
+        self.mu = mu
+        self.tag = tag
+
+    def params(self):
+        return (self.tag,)
+
+    def apply_batch(self, xs, mask=None):
+        return xs - self.mu
+
+
+@pytest.mark.parametrize(
+    "make",
+    [
+        pytest.param(lambda: transformer(lambda v: v * 2.0), id="params-None"),
+        pytest.param(lambda: _Shift(jnp.arange(6, dtype=jnp.float32)), id="undeclared-array"),
+        pytest.param(lambda: _Shift(np.arange(6, dtype=np.float32)), id="undeclared-host-array"),
+    ],
+)
+def test_nodes_without_a_safe_identity_keep_a_program_per_object(make):
+    """params() None promises no identity, and an undeclared array would
+    be pinned by a process-lifetime template: both stay in the weak
+    per-object cache, and the array dies with its node."""
+    import gc
+    import weakref
+
+    T = _transformer_module()
+    xs = jnp.ones((4, 6), jnp.float32)
+    shared_before = dict(T._SHARED_APPLY_CACHE)
+    a, b = make(), make()
+    ya, yb = a._apply_batch_jitted(xs, None), b._apply_batch_jitted(xs, None)
+    np.testing.assert_array_equal(np.asarray(ya), np.asarray(yb))
+    assert T._SHARED_APPLY_CACHE == shared_before
+    assert T._JIT_APPLY_CACHE[a] is not T._JIT_APPLY_CACHE[b]
+    held = getattr(a, "mu", None)
+    dies = weakref.ref(a if held is None or isinstance(held, np.ndarray) else held)
+    del a, held, ya
+    gc.collect()
+    assert dies() is None
+
+
+def test_matmul_mode_flip_retraces_a_shared_node():
+    from keystone_tpu.ops import LCSExtractor
+    from keystone_tpu.utils import precision
+
+    T = _transformer_module()
+    for k in _shared_entries(LCSExtractor):
+        del T._SHARED_APPLY_CACHE[k]
+    xs = _images(np.float32)
+    with precision.matmul("f32"):
+        LCSExtractor(step=4, subpatch_size=4)._apply_batch_jitted(xs, None)
+    with precision.matmul("bf16"):
+        LCSExtractor(step=4, subpatch_size=4)._apply_batch_jitted(xs, None)
+    modes = sorted(k[1][0] for k in _shared_entries(LCSExtractor))
+    assert modes == ["bf16", "f32"]
+
+
+def test_under_specified_params_do_not_share_a_program():
+    """params() that leave out an attribute apply_batch reads: the key
+    also holds the instance's plain attributes, so each value computes
+    its own answer (tests/test_multitenant.py holds the same end to end)."""
+
+    class Leaky(Transformer):
+        def __init__(self, scale):
+            self.scale = float(scale)
+
+        def params(self):
+            return ("leaky",)
+
+        def apply_batch(self, xs, mask=None):
+            return xs * self.scale
+
+    xs = jnp.ones((3, 2), jnp.float32)
+    assert float(Leaky(2.0)._apply_batch_jitted(xs, None)[0, 0]) == 2.0
+    assert float(Leaky(3.0)._apply_batch_jitted(xs, None)[0, 0]) == 3.0
+    assert len(_shared_entries(Leaky)) == 2
+
+
+def test_shared_apply_cache_is_bounded(monkeypatch):
+    from keystone_tpu.ops import ClassLabelIndicators
+
+    T = _transformer_module()
+    monkeypatch.setattr(T, "_SHARED_APPLY_CACHE", {})
+    monkeypatch.setattr(T, "_SHARED_APPLY_MAX", 3)
+    xs = jnp.zeros((4,), jnp.int32)
+    for k in range(2, 8):
+        ClassLabelIndicators(k)._apply_batch_jitted(xs, None)
+    assert [key[0][2] for key in T._SHARED_APPLY_CACHE] == [(5,), (6,), (7,)]
+    # an evicted live node just mints again
+    assert ClassLabelIndicators(2)._apply_batch_jitted(xs, None).shape == (4, 2)
+    assert len(T._SHARED_APPLY_CACHE) == 3
+
+
+def test_degrading_node_shares_its_plain_twins_program():
+    from keystone_tpu.ops import LinearRectifier
+
+    T = _transformer_module()
+    plain = LinearRectifier(0.25)
+    degrading = LinearRectifier(0.25).with_fallback(_Shift(jnp.ones(6)))
+    degrading.optional = True
+    assert T.share_key(degrading) == T.share_key(plain)
+    assert T.stripped_template(degrading).fallback is None
+    assert degrading.fallback is not None
+
+
+def test_shared_sift_program_is_the_per_object_wrappers_text():
+    """The lowered module of the shared apply is, text for text, what
+    the per-object ``(xs, mask)`` wrapper lowered to, so the persistent
+    compile cache a process warmed before this change still answers.
+    The digest is the parent commit's (d6c4605, jax 0.9.0), taken there
+    from ``_JIT_APPLY_CACHE``'s wrapper at this shape."""
+    import hashlib
+
+    from keystone_tpu.ops import SIFTExtractor
+
+    T = _transformer_module()
+    node = SIFTExtractor(step=4, bin_sizes=(4,))
+    xs = jnp.zeros((2, 32, 32), jnp.float32)
+    node._apply_batch_jitted(xs, None)
+    fn = next(
+        f for k, f in _shared_entries(SIFTExtractor).items() if k[0] == T.share_key(node)
+    )
+    text = fn.lower({}, xs, None).as_text()
+    plain = T.jit_named(lambda a, m: node.apply_batch(a, mask=m), [node])
+    assert text == plain.lower(xs, None).as_text()
+    assert text.split("module @", 1)[1].split(" ", 1)[0] == "jit_apply_SIFTExtractor"
+    if jax.__version__ == "0.9.0":
+        assert hashlib.sha256(text.encode()).hexdigest() == (
+            "b1789e97c86da4020c4b731f42ac19791d5e996a1393eaa47c49616802f5f203"
+        )
 
 
 def test_fused_chain_shares_program_across_instances():
