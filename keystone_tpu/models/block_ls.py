@@ -267,33 +267,22 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         return self._fit(x, jnp.asarray(y), x.shape[0])
 
     def _fit(self, x, y, n) -> BlockLinearMapper:
-        x = x.astype(jnp.float32)
-        y = y.astype(jnp.float32)
-        nf = jnp.float32(n)
-        xm = jnp.sum(x, axis=0) / nf if self.fit_intercept else None
-        ym = jnp.sum(y, axis=0) / nf if self.fit_intercept else None
-        # Center on padded arrays: pad rows become (−x̄), which would
-        # corrupt Gramians — so mask them back to zero explicitly.
-        if self.fit_intercept:
-            row_ok = (jnp.arange(x.shape[0]) < n)[:, None].astype(jnp.float32)
-            xc = (x - xm) * row_ok
-            yc = (y - ym) * row_ok
-        else:
-            xc, yc = x, y
         from keystone_tpu.obs import ledger
 
+        x = x.astype(jnp.float32)
+        y = y.astype(jnp.float32)
+        # the solver program holds its input and ONE centred block of it
+        # (no centred copy, no blocked copy: see _bcd_fit)
         with ledger.span(
             "solver.fit", solver="bcd", n=int(n),
             blocks=-(-x.shape[1] // self.block_size),
             gram_panels=gram_panels(self.block_size),
+            d=int(x.shape[1]), block_size=self.block_size,
+            held_bytes=4 * x.shape[0] * (x.shape[1] + self.block_size),
         ):
-            weights = _bcd_fit(
-                blockify(xc, self.block_size),
-                yc,
-                nf,
-                self.lam,
-                self.num_iter,
-                obs=ledger.solver_obs(),
+            weights, xm, ym = _bcd_fit(
+                x, y, jnp.float32(n), self.lam, self.num_iter, self.block_size,
+                self.fit_intercept, obs=ledger.solver_obs(),
             )
         return finish_block_model(
             weights, xm, ym, x.shape[1], self.block_size, self.fit_intercept
@@ -928,24 +917,28 @@ def _spill_dir(hint=None):
     return tempfile.mkdtemp(prefix="kst_spill_", dir=base)
 
 
+def _bcd_block_step(a, wb, y, p, reg):
+    """One Gauss–Seidel block update from the block's (centred) columns
+    ``a`` (n_rows, bs): the new block weights and the new running
+    prediction."""
+    # residual with this block's contribution restored
+    target = y - p + a @ wb
+    # per-partition gemm + treeReduce == sharded contraction + psum
+    ata = sharded_gram(a)
+    atr = sharded_matmul(a, target, out_spec=P(None, MODEL_AXIS))
+    wb_new = solve_spd(ata, atr, reg=reg)
+    return wb_new, constrain(p + a @ (wb_new - wb), DATA_AXIS, MODEL_AXIS)
+
+
 def _bcd_epoch_body(xb, y, n, lam, carry):
-    """One Gauss–Seidel sweep over all blocks."""
-    nb = xb.shape[0]
+    """One Gauss–Seidel sweep over all blocks of a blocked matrix."""
 
     def block_step(b, carry):
         w, p = carry
-        a = xb[b]  # (n_rows, bs)
-        wb = w[b]
-        # residual with this block's contribution restored
-        target = y - p + a @ wb
-        # per-partition gemm + treeReduce == sharded contraction + psum
-        ata = sharded_gram(a)
-        atr = sharded_matmul(a, target, out_spec=P(None, MODEL_AXIS))
-        wb_new = solve_spd(ata, atr, reg=lam * n)
-        p_new = constrain(p + a @ (wb_new - wb), DATA_AXIS, MODEL_AXIS)
+        wb_new, p_new = _bcd_block_step(xb[b], w[b], y, p, lam * n)
         return w.at[b].set(wb_new), p_new
 
-    return lax.fori_loop(0, nb, block_step, carry)
+    return lax.fori_loop(0, xb.shape[0], block_step, carry)
 
 
 @partial(jax.jit, donate_argnums=(4, 5))
@@ -961,11 +954,21 @@ def _bcd_epoch(xb, y, n, lam, w, p):
     return _bcd_epoch_body(xb, y, n, lam, (w, p))
 
 
-@partial(jax.jit, static_argnames=("num_iter", "obs"))
-def _bcd_fit(xb, y, n, lam, num_iter, obs=False):
-    """The hot loop (SURVEY.md §3.2) as one XLA program.
+@partial(jax.jit, static_argnames=("num_iter", "block_size", "fit_intercept", "obs"))
+def _bcd_fit(x, y, n, lam, num_iter, block_size, fit_intercept, obs=False):
+    """The hot loop (SURVEY.md §3.2) as one XLA program, from the feature
+    matrix AS IT ARRIVES: x (n_rows, d) row-sharded, y (n_rows, k), n the
+    true row count.  Returns ``(weights (nb, bs, k), xm (d,), ym (k,))``.
 
-    xb: (nb, n_rows, bs) row-sharded; y: (n_rows, k).
+    The program holds x and one block of it.  A block step slices its
+    ``block_size`` columns out of x, centres them on their column means
+    and zeroes the padding rows — there is no centred copy and no
+    ``blockify`` copy of the matrix (at 16,384 × 80,000 each is 5.2 GB).
+    Where ``block_size`` does not divide d the last block is the LAST
+    ``block_size`` columns of x with the columns an earlier block owns
+    zeroed (a zero column has a zero weight: its row of the cross term
+    is zero), and its weights are rolled to the front once, after the
+    sweeps, to the place ``BlockLinearMapper`` reads them from.
 
     ``obs`` (static): emit a per-epoch ``solver.epoch`` convergence
     point (residual objective) to the active run ledger via
@@ -973,15 +976,40 @@ def _bcd_fit(xb, y, n, lam, num_iter, obs=False):
     the host callback, and is resolved at trace time so the inert
     program carries no callbacks at all.
     """
-    nb, n_rows, bs = xb.shape
+    bs = block_size
+    n_rows, d = x.shape
     k = y.shape[1]
-    xb = constrain(xb, None, DATA_AXIS, None)
+    nb = -(-d // bs)
+    if d < bs:  # one narrow block: its zero columns sit behind it as it is
+        x = jnp.pad(x, ((0, 0), (0, bs - d)))
+    width = x.shape[1]
+    x = constrain(x, DATA_AXIS, None)
     y = constrain(y, DATA_AXIS, MODEL_AXIS)
-    w0 = jnp.zeros((nb, bs, k), jnp.float32)
-    p0 = jnp.zeros_like(y)
+    row_ok = (jnp.arange(n_rows) < n)[:, None].astype(jnp.float32)
+    if fit_intercept:
+        xm = jnp.sum(x, axis=0) / n
+        ym = jnp.sum(y, axis=0) / n
+        # pad rows would centre to −mean and corrupt the Gramians
+        y = (y - ym) * row_ok
+    else:
+        xm = jnp.zeros((width,), jnp.float32)
+        ym = jnp.zeros((k,), jnp.float32)
+
+    def block(b):
+        lo = jnp.minimum(b * bs, width - bs)
+        a = lax.dynamic_slice_in_dim(x, lo, bs, axis=1)
+        if fit_intercept:
+            a = (a - lax.dynamic_slice_in_dim(xm, lo, bs)) * row_ok
+        own = lo + jnp.arange(bs) >= b * bs
+        return constrain(jnp.where(own, a, 0.0), DATA_AXIS, None)
+
+    def block_step(b, carry):
+        w, p = carry
+        wb_new, p_new = _bcd_block_step(block(b), w[b], y, p, lam * n)
+        return w.at[b].set(wb_new), p_new
 
     def epoch(carry, e):
-        carry = _bcd_epoch_body(xb, y, n, lam, carry)
+        carry = lax.fori_loop(0, nb, block_step, carry)
         if obs:
             from keystone_tpu.obs import ledger
 
@@ -994,10 +1022,15 @@ def _bcd_fit(xb, y, n, lam, num_iter, obs=False):
             )
         return carry, None
 
+    w0 = jnp.zeros((nb, bs, k), jnp.float32)
+    p0 = jnp.zeros_like(y)
     # xs only when observing — the inert program stays byte-identical
     # to the pre-obs one (see models/kmeans.py)
     if obs:
         (w, _), _ = lax.scan(epoch, (w0, p0), jnp.arange(num_iter))
     else:
         (w, _), _ = lax.scan(epoch, (w0, p0), None, length=num_iter)
-    return w
+    behind = nb * bs - width  # columns of the last block that an earlier one owns
+    if behind:
+        w = w.at[nb - 1].set(jnp.roll(w[nb - 1], -behind, axis=0))
+    return w, xm[:d], ym

@@ -94,6 +94,7 @@ ATTR_VOCABULARY = {
     "attempt",
     "attempts",
     "batch",
+    "block_size",
     "blocks",
     "bucket",
     "budget_bytes",
@@ -103,6 +104,7 @@ ATTR_VOCABULARY = {
     "canary_fraction",
     "checkpoint_save_seconds",
     "chunk_seconds",
+    "d",
     "degraded",
     "depth",
     "epoch",
@@ -111,11 +113,13 @@ ATTR_VOCABULARY = {
     "factor_cache",
     "factor_cache_bytes",
     "failed_attempt_seconds",
+    "filters",
     "from_state",
     "from_replica",
     "from_version",
     "grad_norm",
     "gram_panels",
+    "held_bytes",
     "host",
     "instances",
     "it",
@@ -131,6 +135,7 @@ ATTR_VOCABULARY = {
     "objective",
     "occupancy",
     "outcome",
+    "patches",
     "path",
     "pause_seconds",
     "pid",

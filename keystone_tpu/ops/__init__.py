@@ -29,6 +29,7 @@ from keystone_tpu.ops.util import (  # noqa: F401
 from keystone_tpu.ops.images import (  # noqa: F401
     CenterCornerPatcher,
     Convolver,
+    PooledConvolver,
     GrayScaler,
     ImageVectorizer,
     PixelScaler,

@@ -37,18 +37,32 @@ class Convolver(Transformer):
       (fh·fw·c, K) gemm, the reference's own execution strategy and a
       better MXU mapping when the patch dim and filter count are both
       MXU-friendly (≥~128) while the conv is small;
-    - ``"auto"`` (default) — resolved per shape from the measured
-      crossover (rounds 1–5, not re-measured), pinned to a
-      concrete form by the optimizer's NodeChoiceRule when it samples.
+    - ``"auto"`` (default) — resolved per shape by
+      ``_IM2COL_MAX_PATCH_ELEMENTS`` (see there for what was measured),
+      pinned to a concrete form by the optimizer's NodeChoiceRule when it
+      samples.
+
+    ``normalize_patches`` is upstream's ``normalizePatches``: every patch
+    has its mean taken off and is divided by √(variance + ``var_constant``)
+    (``Stats.normalizeRows``: the variance over d − 1) BEFORE it meets the
+    filters.  That is not linear in the image, so no filter bank folds it
+    in: a normalising Convolver extracts its patches (im2col, whatever
+    ``strategy`` says) and normalises them in float32 first.  The
+    whitener's fold (:meth:`from_whitened_patches`) is linear in the
+    NORMALISED patch and stays.  Followed by a SymmetricRectifier and a
+    sum Pooler it is fused into :class:`PooledConvolver` by the optimizer
+    and its activation is never written.
     """
 
     strategy = "auto"  # class default for pre-strategy pickles
+    normalize_patches = False  # … and for pre-normalisation ones
+    var_constant = 10.0
     # fitted filters/offset ride as traced jit arguments (refits and
     # sibling instances share programs; no lowering read-back)
     traced_attrs = ("filters", "offset")
 
     def jit_static(self):
-        return (self.stride, self.strategy)
+        return (self.stride, self.strategy, self.normalize_patches, self.var_constant)
 
     def __init__(
         self,
@@ -56,6 +70,8 @@ class Convolver(Transformer):
         stride: int = 1,
         offset=None,
         strategy: str = "auto",
+        normalize_patches: bool = False,
+        var_constant: float = 10.0,
     ):
         if strategy not in ("auto", "direct", "im2col"):
             raise ValueError(f"unknown Convolver strategy {strategy!r}")
@@ -63,20 +79,27 @@ class Convolver(Transformer):
         self.stride = int(stride)
         self.offset = offset  # (num_filters,) additive term
         self.strategy = strategy
+        self.normalize_patches = bool(normalize_patches)
+        self.var_constant = float(var_constant)
 
     @classmethod
     def from_whitened_patches(
-        cls, patches: jnp.ndarray, whitener, patch_shape, stride: int = 1
+        cls, patches: jnp.ndarray, whitener, patch_shape, stride: int = 1,
+        normalize_patches: bool = False, var_constant: float = 10.0,
     ) -> "Convolver":
         """Build from flat random patches + a fitted ZCAWhitener
         (RandomPatchCifar pattern): filters = (W_zca · Pᵀ) reshaped,
-        offset = −mean·W_zca·Pᵀ."""
+        offset = −mean·W_zca·Pᵀ; with ``normalize_patches`` the whitener
+        is taken to have been fitted on normalised patches, and the
+        convolver normalises each image patch before it applies them.
+        The two products enter every feature: solver-grade."""
         fh, fw, c = patch_shape
         p = jnp.asarray(patches, jnp.float32)  # (K, fh*fw*c), whitened space
-        w_eff = whitener.whitener @ p.T  # (d, K)
-        offset = -(whitener.mean @ w_eff)  # (K,)
+        w_eff = precision.sdot(whitener.whitener, p.T)  # (d, K)
+        offset = -precision.sdot(whitener.mean, w_eff)  # (K,)
         filters = w_eff.T.reshape(-1, fh, fw, c)
-        return cls(filters, stride=stride, offset=offset)
+        return cls(filters, stride=stride, offset=offset,
+                   normalize_patches=normalize_patches, var_constant=var_constant)
 
     def params(self):
         from keystone_tpu.utils.hashing import cached_fingerprint
@@ -91,6 +114,8 @@ class Convolver(Transformer):
             self.stride,
             self.offset is None,
             self.strategy,
+            self.normalize_patches,
+            self.var_constant,
         )
 
     def choose_physical(self, sample):
@@ -107,7 +132,8 @@ class Convolver(Transformer):
             shape[1], shape[2], self.filters.shape, self.stride
         )
         return Convolver(
-            self.filters, stride=self.stride, offset=self.offset, strategy=picked
+            self.filters, stride=self.stride, offset=self.offset, strategy=picked,
+            normalize_patches=self.normalize_patches, var_constant=self.var_constant,
         )
 
     def apply_batch(self, xs, mask=None):
@@ -124,7 +150,7 @@ class Convolver(Transformer):
             xs = xs[..., None]
         xs = xs.astype(jnp.float32)
         mxu = precision.apply_mode()
-        strategy = self.strategy
+        strategy = "im2col" if self.normalize_patches else self.strategy
         if strategy == "auto":
             strategy = _pick_conv_strategy(
                 xs.shape[1], xs.shape[2], self.filters.shape, self.stride
@@ -169,6 +195,8 @@ class Convolver(Transformer):
             dimension_numbers=("NHWC", "HWIO", "NHWC"),
         )  # (n, oh, ow, c*fh*fw) — channel-major patch layout
         oh, ow = patches.shape[1], patches.shape[2]
+        if self.normalize_patches:
+            patches = normalize_rows(patches, self.var_constant)
         # filters (k, fh, fw, c) -> (c, fh, fw, k) flattened to match the
         # patches' (c, fh, fw) minor order
         rhs = jnp.transpose(self.filters, (3, 1, 2, 0)).reshape(c * fh * fw, k)
@@ -181,11 +209,29 @@ class Convolver(Transformer):
         return self.apply_batch(x[None])[0]
 
 
-#: measured crossover, TPU v5 lite (rounds 1–5, not re-measured): the
-#: im2col patches tensor per image — (oh·ow) positions
-#: × (fh·fw·c) patch dim — below this many elements, patch-extract+gemm
-#: beats XLA's conv emitter (its fixed per-conv costs dominate small
-#: convs); above it, materializing patches loses to the fused conv.
+def normalize_rows(patches, var_constant: float):
+    """Upstream's ``Stats.normalizeRows`` over the last axis: the mean
+    taken off, then divided by √(variance over d − 1, plus the constant)."""
+    centred = patches - jnp.mean(patches, axis=-1, keepdims=True)
+    var = jnp.sum(centred * centred, axis=-1, keepdims=True) / (patches.shape[-1] - 1.0)
+    return centred * lax.rsqrt(var + var_constant)
+
+
+#: The im2col patches tensor per image — (oh·ow) positions × (fh·fw·c)
+#: patch dim — up to which ``"auto"`` takes patch-extract + gemm and above
+#: which XLA's conv emitter.  It decides only for a Convolver that stands
+#: alone and does not normalise its patches: a normalising one extracts
+#: patches whatever this says, and one followed by a rectifier and a sum
+#: pooler is fused into ``PooledConvolver``, which has no such choice
+#: (RandomPatchCifar at 32 × 32 × 3 with 6 × 6 patches is 78,732 elements
+#: and never asks).  Read once on the v5e (my chip run, PR 32; 6 × 6 × 3
+#: filters, µs an image, direct / im2col): at 256 filters 0.24 / 0.25 at
+#: 13,068 elements, 0.40 / 0.55 at 24,300, 0.64 / 0.89 at 38,988, 0.99 /
+#: 1.29 at 57,132, 2.75 / 3.15 at 62,208, 1.23 / 1.73 at 78,732; at 1024
+#: filters 2.46 / 2.44 at 38,988 and 4.84 / 5.25 at 78,732.  So the older
+#: rounds' crossover is not borne out at these widths: below the constant
+#: im2col is level at best and up to 1.4× slower.  The value stands until
+#: a `perf_opt` issue reads it over more filter shapes (PERF.md §7).
 _IM2COL_MAX_PATCH_ELEMENTS = 58_000
 
 
@@ -252,6 +298,89 @@ class SymmetricRectifier(Transformer):
         pos = jnp.maximum(xs - self.alpha, self.max_val)
         neg = jnp.maximum(-xs - self.alpha, self.max_val)
         return jnp.concatenate([pos, neg], axis=-1)
+
+    def apply_one(self, x):
+        return self.apply_batch(x[None])[0]
+
+
+class PooledConvolver(Transformer):
+    """``Convolver`` → ``SymmetricRectifier`` → sum ``Pooler`` (→
+    ``ImageVectorizer``) as ONE node whose program never writes the
+    convolution's activation (ops/conv_pool_pallas.py): what the optimizer
+    makes of that chain (``workflow/optimizer.py § ConvPoolFusionRule``).
+    At RandomPatchCifar's widths the activation is 29 MB an image and the
+    pooled output 0.32 MB.
+
+    ``owns_tiling``: the program loops over tiles of images itself and
+    writes each tile's pooled rows into its one output, so the node is
+    applied whole (no chunk of rows is offered to it, and no chunk
+    outputs are held beside their concatenation).
+    """
+
+    traced_attrs = ("filters", "offset")
+    owns_tiling = True
+
+    def __init__(self, conv: Convolver, rectifier: "SymmetricRectifier",
+                 pooler: Pooler, vectorize: bool):
+        self.filters = conv.filters
+        self.offset = conv.offset
+        self.stride = conv.stride
+        self.normalize_patches = conv.normalize_patches
+        self.var_constant = conv.var_constant
+        self.alpha = rectifier.alpha
+        self.max_val = rectifier.max_val
+        self.pool_stride = pooler.stride
+        self.pool_size = pooler.pool_size
+        self.vectorize = bool(vectorize)
+        # the convolver's identity (a pinned recipe, or a digest it has
+        # already paid for) is this node's too
+        cached = getattr(conv, "_fp", None)
+        if cached is not None:
+            self._fp = cached
+
+    @staticmethod
+    def fuses(conv, rectifier, pooler) -> bool:
+        """Whether the chain is the fused program's: a sum pooler of plain
+        pixels over a rectifier whose two thresholds are not negative."""
+        return (
+            pooler.pool_mode == "sum" and pooler.pixel_fn is None
+            and rectifier.alpha + rectifier.max_val >= 0
+        )
+
+    def jit_static(self):
+        return (
+            self.stride, self.normalize_patches, self.var_constant, self.alpha,
+            self.max_val, self.pool_stride, self.pool_size, self.vectorize,
+        )
+
+    def params(self):
+        from keystone_tpu.utils.hashing import cached_fingerprint
+
+        arrays = (self.filters,) if self.offset is None else (self.filters, self.offset)
+        return (self.filters.shape, cached_fingerprint(self, "_fp", *arrays),
+                self.offset is None) + self.jit_static()
+
+    def apply_batch(self, xs, mask=None):
+        from keystone_tpu.ops.conv_pool_pallas import conv_rectify_pool, pool_geometry
+        from keystone_tpu.ops.fisher_pallas import pallas_supported
+
+        if xs.ndim == 3:
+            xs = xs[..., None]
+        flat = conv_rectify_pool(
+            xs, self.filters, self.offset, stride=self.stride,
+            normalize=self.normalize_patches, var_constant=self.var_constant,
+            alpha=self.alpha, max_val=self.max_val, pool_stride=self.pool_stride,
+            pool_size=self.pool_size, dtype=precision.fdtype(),
+            use_pallas=pallas_supported(),
+        )
+        if self.vectorize:
+            return flat
+        _, fh, fw, _ = self.filters.shape
+        ph, pw = pool_geometry(
+            (xs.shape[1] - fh) // self.stride + 1, (xs.shape[2] - fw) // self.stride + 1,
+            self.pool_stride, self.pool_size,
+        ).pooled_hw
+        return flat.reshape(xs.shape[0], ph, pw, -1)
 
     def apply_one(self, x):
         return self.apply_batch(x[None])[0]

@@ -389,8 +389,6 @@ class FusedTransformer(Transformer):
         traced arguments (Transformer.traced_attrs), so e.g. the two
         branch tails Fused[SignedHellinger > NormalizeRows] compile ONCE
         and refits never invalidate the persistent compile cache."""
-        import copy
-
         sentinel = object()
         entry = _FUSED_SHARED_CACHE.get(ckey, sentinel)
         if entry is None:  # memoized untraceable for this chain+signature
@@ -403,16 +401,13 @@ class FusedTransformer(Transformer):
             # an evicted-but-live chain just rebuilds its entry.
             while len(_FUSED_SHARED_CACHE) >= 128:
                 _FUSED_SHARED_CACHE.pop(next(iter(_FUSED_SHARED_CACHE)))
-            from keystone_tpu.workflow.transformer import stripped_template
+            from keystone_tpu.workflow.transformer import rebound, stripped_template
 
             templates = [stripped_template(s) for s in self.stages]
 
             def run(plist, arr):
                 for t, p in zip(templates, plist):
-                    obj = copy.copy(t)
-                    for name, v in p.items():
-                        setattr(obj, name, v)
-                    arr = obj.apply_batch(arr)
+                    arr = rebound(t, p).apply_batch(arr)
                 return arr
 
             entry = _FUSED_SHARED_CACHE[ckey] = jit_named(run, self.stages)
@@ -480,6 +475,68 @@ def _fusable(op) -> bool:
 def _stages(op) -> list:
     t = op.transformer
     return list(t.stages) if isinstance(t, FusedTransformer) else [t]
+
+
+class ConvPoolFusionRule(Rule):
+    """Collapse ``Convolver → SymmetricRectifier → sum Pooler`` (and the
+    ``ImageVectorizer`` behind it, if there is one) into ONE
+    ``PooledConvolver`` node, whose program keeps the convolution's
+    activation in fast memory a tile at a time (ops/conv_pool_pallas.py).
+
+    At RandomPatchCifar's widths that activation is 29 MB an image: no
+    stage-by-stage execution of the chain can exist there, the sampled
+    ones of the node-choice and materialisation rules included, so this
+    runs right after CSE and before either.  Every link must be a plain
+    single-consumer node (the contract of ``_fusable``)."""
+
+    name = "ConvPoolFusion"
+
+    def apply(self, graph: G.Graph) -> G.Graph:
+        from keystone_tpu.ops.images import (
+            Convolver,
+            ImageVectorizer,
+            Pooler,
+            PooledConvolver,
+            SymmetricRectifier,
+        )
+
+        def only_dependent(n, kind):
+            """The one node that consumes n, if it is a fusable ``kind``
+            fed by n alone."""
+            deps_on_n = graph.dependents(n)
+            if len(deps_on_n) != 1 or isinstance(deps_on_n[0], G.SinkId):
+                return None
+            m = deps_on_n[0]
+            mop = graph.operators.get(m)
+            if (
+                _fusable(mop)
+                and type(mop.transformer) is kind
+                and graph.dependencies[m] == (n,)
+            ):
+                return m
+            return None
+
+        for n in list(graph.topological_nodes()):
+            op = graph.operators.get(n)
+            if not _fusable(op) or type(op.transformer) is not Convolver:
+                continue
+            r = only_dependent(n, SymmetricRectifier)
+            p = r and only_dependent(r, Pooler)
+            if not p:
+                continue
+            conv, rect, pool = (graph.operators[x].transformer for x in (n, r, p))
+            if not PooledConvolver.fuses(conv, rect, pool):
+                continue
+            v = only_dependent(p, ImageVectorizer)
+            last = v or p
+            fused_op = G.TransformerOperator(PooledConvolver(conv, rect, pool, bool(v)))
+            if getattr(graph.operators[last], "no_memoize", False):
+                fused_op.no_memoize = True
+            graph = graph.set_operator(last, fused_op)
+            graph = graph.set_dependencies(last, graph.dependencies[n])
+            for gone in [x for x in (n, r, p) if x != last]:
+                graph = graph.remove_node(gone)
+        return graph
 
 
 class PallasFvFusionRule(Rule):
@@ -653,6 +710,9 @@ def default_optimizer(
     return Optimizer(
         [
             RuleBatch("cse", FixedPoint(5), [EquivalentNodeMergeRule()]),
+            # before any rule that samples: the chain it replaces cannot
+            # be executed stage by stage at the widths it exists for
+            RuleBatch("conv-pool", Once(), [ConvPoolFusionRule()]),
             RuleBatch("node-choice", Once(), [NodeChoiceRule(sample_size)]),
             RuleBatch(
                 "materialize",

@@ -190,20 +190,68 @@ def _static_node_seconds(graph: G.Graph, ex, n: G.NodeId, op, full_n: int):
         return None
     ds = d.dataset
     arr_aval = jax.ShapeDtypeStruct((full_n,) + tuple(ds.array.shape[1:]), ds.array.dtype)
+    mask_aval = None
+    if ds.mask is not None:
+        mask_aval = jax.ShapeDtypeStruct((full_n,) + tuple(ds.mask.shape[1:]), ds.mask.dtype)
     t = op.transformer
     from keystone_tpu.obs import ledger
 
+    if type(t).traced_attrs:
+        return _priced_by_shape(t, arr_aval, mask_aval)
     # a program traced, lowered and compiled (or loaded) anew on every
     # optimizer pass — every fit, every scoring call — to be priced, never run
     with ledger.span("transformer.jit_mint", node=type(t).__name__, shared=False):
-        if ds.mask is not None:
-            mask_aval = jax.ShapeDtypeStruct(
-                (full_n,) + tuple(ds.mask.shape[1:]), ds.mask.dtype
-            )
+        if mask_aval is not None:
             cost = hlo_stage_cost(lambda a, m: t.apply_batch(a, mask=m), arr_aval, mask_aval)
         else:
             cost = hlo_stage_cost(lambda a: t.apply_batch(a), arr_aval)
     return cost["seconds_est"] if cost else None
+
+
+#: roofline seconds of nodes that declare ``traced_attrs``, by what the
+#: estimate can depend on: (share key, input and parameter shapes, matmul
+#: mode).  Bounded FIFO, as the shared-apply cache is.
+_PRICED: dict = {}
+_PRICED_MAX = 128
+
+
+def _priced_by_shape(t, arr_aval, mask_aval):
+    """The static price of a node whose fitted arrays ride as traced
+    ARGUMENTS (``Transformer.traced_attrs``): the program is lowered from
+    their shapes, as the node's own shared apply is, and the estimate is
+    kept for every later node of the same kind and shapes.  Closed over
+    instead, a freshly fitted array is read back to the host by the
+    lowering and embedded in the program — 4.3 MB of convolution filters
+    made a new 2 s compile of every RandomPatchCifar fit (my chip run,
+    PR 32) — for a number that cannot depend on its values."""
+    import jax
+
+    from keystone_tpu.obs import ledger
+    from keystone_tpu.utils import precision
+    from keystone_tpu.workflow.transformer import rebound, share_key, stripped_template
+
+    shape_of = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
+    params = {
+        name: jax.tree_util.tree_map(shape_of, getattr(t, name))
+        for name in type(t).traced_attrs
+    }
+    kind = share_key(t)  # None: the node promises no identity, and is priced every time
+    key = (kind, precision.matmul_mode(), str(arr_aval), str(mask_aval),
+           str(jax.tree_util.tree_structure(params)), str(jax.tree_util.tree_leaves(params)))
+    if kind is not None and key in _PRICED:
+        return _PRICED[key]
+    template = stripped_template(t)
+    with ledger.span("transformer.jit_mint", node=type(t).__name__, shared=False):
+        cost = hlo_stage_cost(
+            lambda p, a, m: rebound(template, p).apply_batch(a, mask=m),
+            params, arr_aval, mask_aval,
+        )
+    seconds = cost["seconds_est"] if cost else None
+    if kind is not None:
+        while len(_PRICED) >= _PRICED_MAX:
+            _PRICED.pop(next(iter(_PRICED)))
+        _PRICED[key] = seconds
+    return seconds
 
 
 def device_hbm_budget(fraction: float = 0.5) -> int:

@@ -104,6 +104,17 @@ def stripped_template(t: "Transformer") -> "Transformer":
     return tpl
 
 
+def rebound(template: "Transformer", params: dict) -> "Transformer":
+    """A copy of a ``stripped_template`` with its traced attributes bound to
+    ``params`` (tracers, inside a shared program's trace)."""
+    import copy
+
+    obj = copy.copy(template)
+    for name, v in params.items():
+        setattr(obj, name, v)
+    return obj
+
+
 def traced_param_sig(t: "Transformer") -> tuple:
     """Hashable structure signature of an instance's traced parameters
     (pytree treedef + leaf dtypes per attr).  Part of the shared-cache
@@ -213,6 +224,15 @@ _APPLY_CHUNK_DEFAULT = 2048
 #: (my chip runs, PR 25).  Up to 32,768 rows nothing changes.
 _APPLY_MAX_CHUNKS = 16
 _APPLY_CHUNK_BYTES = 32 << 20
+#: A chunked apply holds its chunks' outputs beside their concatenation:
+#: twice the output.  No chunk is offered where that cannot exist — to a
+#: dataset that is itself over a quarter of a 16 GB device (80,000
+#: features of 16,384 rows are 5.2 GB; their scaled copy in eight chunks
+#: and again in one piece would be 10.5 GB beside them), nor to a node
+#: that tiles its own input (``Transformer.owns_tiling``).  Such an apply
+#: is one program over the whole array.  The largest input a benchmark
+#: cell chunks is 1.6 GB (4096 images' SIFT descriptors).
+_APPLY_WHOLE_BYTES = 4 << 30
 
 
 def _apply_chunk_rows() -> int:
@@ -263,6 +283,8 @@ def _chunk_rows_for(arr) -> int:
     chunk = _apply_chunk_rows()
     if not chunk or os.environ.get("KEYSTONE_APPLY_CHUNK", "").strip():
         return chunk
+    if arr.nbytes > _APPLY_WHOLE_BYTES:
+        return 0
     n = arr.shape[0]
     row_bytes = arr.nbytes // max(1, n)
     while n > _APPLY_MAX_CHUNKS * chunk and 2 * chunk * row_bytes <= _APPLY_CHUNK_BYTES:
@@ -327,6 +349,11 @@ class Transformer(Chainable):
     #: inline the inner program and embed its traced stage parameters
     #: as outer-program constants, nullifying cross-instance sharing.
     self_jitted: bool = False
+    #: True for a transformer whose program loops over tiles of its rows
+    #: itself and sizes them from the shapes it sees (PooledConvolver:
+    #: its input row is 3 KB and the activation it must never write
+    #: 29 MB).  ``apply_dataset`` offers it no chunk: it is applied whole.
+    owns_tiling: bool = False
     #: Graceful degradation (workflow/executor.py): an ``optional``
     #: stage whose retry/deadline budget is exhausted — or whose circuit
     #: breaker is open — is replaced by :class:`Identity` (its input
@@ -455,7 +482,7 @@ class Transformer(Chainable):
             base, stages = getattr(ds, "_host_chain", None) or (ds, ())
             res._host_chain = (base, stages + (self,))
             return res
-        chunk = _chunk_rows_for(ds.array)
+        chunk = 0 if self.owns_tiling else _chunk_rows_for(ds.array)
         if chunk and ds.array.shape[0] > chunk:
             return self._apply_dataset_chunked(ds, chunk)
         result = self._apply_batch_jitted(ds.array, ds.mask)
@@ -587,8 +614,6 @@ class Transformer(Chainable):
         equal instance and every refit.  A node without traced_attrs
         passes an empty dict, which adds nothing to the lowered module:
         its text is that of a plain ``(xs, mask)`` program."""
-        import copy
-
         cls = type(self)
         params = {}
         for name in cls.traced_attrs:
@@ -617,10 +642,7 @@ class Transformer(Chainable):
             template = stripped_template(self)
 
             def run(p, a, m):
-                obj = copy.copy(template)
-                for name, v in p.items():
-                    setattr(obj, name, v)
-                return obj.apply_batch(a, mask=m)
+                return rebound(template, p).apply_batch(a, mask=m)
 
             fn = jit_named(run, [self])
             with _SHARED_APPLY_LOCK:

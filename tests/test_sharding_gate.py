@@ -143,10 +143,12 @@ def test_bcd_fit_stays_sharded(mesh):
 
     rng = np.random.default_rng(0)
     nb, bs, k = 2, 16, 4
-    xb = jnp.asarray(rng.normal(size=(nb, _N, bs)).astype(np.float32))
+    # the matrix as it arrives (PR 32: the program blocks and centres it
+    # itself), the second block four columns short
+    x = jnp.asarray(rng.normal(size=(_N, nb * bs - 4)).astype(np.float32))
     y = jnp.asarray(rng.normal(size=(_N, k)).astype(np.float32))
-    compiled = _bcd_fit.lower(xb, y, _N, 1e-3, 2).compile()
-    _assert_gate(compiled, (xb, y, _N, 1e-3), _N, "_bcd_fit")
+    compiled = _bcd_fit.lower(x, y, _N, 1e-3, 2, bs, True).compile()
+    _assert_gate(compiled, (x, y, _N, 1e-3), _N, "_bcd_fit")
 
 
 def test_oc_block_step_stays_sharded(mesh):
@@ -223,18 +225,18 @@ def test_gate_detects_dropped_constraints(mesh, monkeypatch):
     # __wrapped__ directly would silently reuse the UNMUTATED trace
     # when the clean test compiled the same shapes first
     mutated = jax.jit(
-        lambda xb, y, n, lam, num_iter: bls._bcd_fit.__wrapped__(
-            xb, y, n, lam, num_iter
+        lambda x, y, n, lam, num_iter, block_size, fit_intercept: bls._bcd_fit.__wrapped__(
+            x, y, n, lam, num_iter, block_size, fit_intercept
         ),
-        static_argnames=("num_iter",),
+        static_argnames=("num_iter", "block_size", "fit_intercept"),
     )
     rng = np.random.default_rng(0)
     nb, bs, k = 2, 16, 4
-    xb = jnp.asarray(rng.normal(size=(nb, _N, bs)).astype(np.float32))
+    x = jnp.asarray(rng.normal(size=(_N, nb * bs)).astype(np.float32))
     y = jnp.asarray(rng.normal(size=(_N, k)).astype(np.float32))
-    compiled = mutated.lower(xb, y, _N, 1e-3, 2).compile()
+    compiled = mutated.lower(x, y, _N, 1e-3, 2, bs, True).compile()
     with pytest.raises(AssertionError, match="all-reduce|replication"):
-        _assert_gate(compiled, (xb, y, _N, 1e-3), _N, "_bcd_fit[mutated]")
+        _assert_gate(compiled, (x, y, _N, 1e-3), _N, "_bcd_fit[mutated]")
 
 
 def test_shared_traced_param_apply_stays_sharded(mesh):

@@ -268,3 +268,46 @@ def test_krr_sweep_keeps_its_name_and_its_gram_route(topo, no_persistent_cache, 
     assert text.count('custom_call_target="tpu_custom_call"') == (route == "pallas")
     # one column block, not three: the masks never touch the (n, block) array
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 4 * n * block
+
+
+@pytest.mark.parametrize("images", [1024, 4096])
+def test_cifar_featurizer_never_holds_its_activation(one_chip, no_persistent_cache, images):
+    """RandomPatchCifar's convolution → rectifier → pooling at its published
+    10,000 filters, as ``PooledConvolver`` traces it: the kernel is in the
+    program, the output is the 80,000 pooled numbers an image, and the
+    temporaries are one tile's (under 2 GB whatever n is), where the
+    activation alone would be 29 MB an image."""
+    from keystone_tpu.ops import conv_pool_pallas as cp
+
+    s = one_chip
+    k = 10000
+    args = (jax.ShapeDtypeStruct((images, 32, 32, 3), jnp.uint8, sharding=s),
+            _f32(s, k, 6, 6, 3), _f32(s, k))
+    compiled = jax.jit(lambda x, f, o: cp.conv_rectify_pool(
+        x, f, o, stride=1, normalize=True, var_constant=10.0, alpha=0.25, max_val=0.0,
+        pool_stride=13, pool_size=14, dtype=jnp.bfloat16, use_pallas=True,
+    )).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == images * 80000 * 4
+    assert mem.temp_size_in_bytes < 2 << 30 < images * 27 * 27 * k * 4
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_bcd_program_keeps_its_name_and_holds_one_block(topo, no_persistent_cache):
+    """The unweighted solver at the CIFAR cell's block width (fewer rows and blocks): the
+    program is ``jit__bcd_fit`` (``ls_roofline`` finds it by that name) and
+    its temporaries are a block's, not a copy of its input."""
+    from keystone_tpu.models import block_ls
+    from keystone_tpu.parallel import use_mesh
+
+    mesh = _v5e_mesh(topo, 1)
+    n, d, bs = 4096, 20000, 4096  # five blocks, the last one 3616 wide
+    with use_mesh(mesh):
+        lowered = block_ls._bcd_fit.lower(
+            _rows_over(mesh, n, d), _rows_over(mesh, n, 10), _f32(NamedSharding(mesh, P())),
+            0.06, 1, bs, True,
+        )
+        assert "module @jit__bcd_fit" in lowered.as_text()
+        mem = lowered.compile().memory_analysis()
+    assert mem.argument_size_in_bytes >= 4 * n * d
+    assert mem.temp_size_in_bytes < 4 * n * d // 2

@@ -23,7 +23,9 @@ Sections (each only when the run recorded it):
   — how many column panels the block Gramian was split into, 1 is the
   one dot; the weighted solver's ``factor_cache`` — the blocks whose
   Cholesky factor it kept across its sweeps, 0 where it factors in every
-  sweep — and ``factor_cache_bytes``; the kernel sweeps' ``block_size``,
+  sweep — and ``factor_cache_bytes``; the unweighted solver's ``d``,
+  ``block_size`` and ``held_bytes`` — its input and the one block it
+  centres at a time; the kernel sweeps' ``block_size``,
   ``epochs``, ``gram`` — the route the ``gram_pallas`` gate resolved,
   ``pallas`` or ``xla`` — and the cached sweep's ``cache_hits``);
 - **retries**: retry totals across executor, durable I/O, blockstore,
