@@ -142,6 +142,8 @@ ATTR_VOCABULARY = {
     "pinned_bytes",
     "poisons",
     "predicted_seconds",
+    "price_hits",
+    "priced",
     "prime_seconds",
     "queue_depth",
     "queue_wait_seconds",
@@ -155,6 +157,7 @@ ATTR_VOCABULARY = {
     "refused",
     "rows",
     "rule",
+    "sampled",
     "seconds",
     "shared",
     "shared_bytes",
@@ -172,6 +175,7 @@ ATTR_VOCABULARY = {
     "tag",
     "tenant",
     "tenants",
+    "to_place",
     "to_state",
     "to_replica",
     "to_version",
@@ -595,6 +599,17 @@ def event(name: str, **attrs) -> None:
     led = active()
     if led is not None:
         led.event(name, **attrs)
+
+
+def annotate(name: str, **attrs) -> None:
+    """Merge ``attrs`` into this thread's innermost OPEN span called
+    ``name`` (``sp.set`` for code that runs inside a span another module
+    opened: an optimizer rule saying how its pass went on the
+    ``optimizer.rule`` span around it).  No such span open: nothing."""
+    for sp in reversed(_stack()):
+        if sp.name == name:
+            sp.set(**attrs)
+            return
 
 
 def capture_context():

@@ -117,6 +117,40 @@ class EquivalentNodeMergeRule(Rule):
 
 
 # ----------------------------------------------------------- materialization
+def _is_cacher(op) -> bool:
+    return isinstance(op, G.TransformerOperator) and isinstance(op.transformer, Cacher)
+
+
+def needs_barrier(graph: G.Graph, n: G.NodeId) -> bool:
+    """Whether ``n``'s output has more than one consumer and no
+    materialization barrier yet.  A placed ``Cacher`` IS the barrier: it
+    is never a candidate itself, however many nodes read it, and a node
+    that one already reads has been decided.  The one test of both
+    materialization rules — the structural one below and the profiled one
+    (``workflow/profiling.py``) — so an optimized graph that is optimized
+    again (every call of a fitted pipeline) has nothing left to place."""
+    if _is_cacher(graph.operators.get(n)):
+        return False
+    consumers = [d for d in graph.dependents(n) if not isinstance(d, G.SinkId)]
+    return len(consumers) > 1 and not any(
+        _is_cacher(graph.operators.get(d)) for d in consumers
+    )
+
+
+def place_barrier(graph: G.Graph, n: G.NodeId) -> G.Graph:
+    """A ``Cacher`` behind ``n``, read by every node that read ``n``;
+    the graph as it is where ``n`` needs none."""
+    if not needs_barrier(graph, n):
+        return graph
+    consumers = [d for d in graph.dependents(n) if isinstance(d, G.NodeId)]
+    graph, cache_node = graph.add_node(G.TransformerOperator(Cacher()), (n,))
+    for d in consumers:
+        graph = graph.set_dependencies(
+            d, tuple(cache_node if x == n else x for x in graph.dependencies[d])
+        )
+    return graph
+
+
 class AutoMaterializeRule(Rule):
     """Insert Cacher nodes after outputs consumed by >1 dependent.
 
@@ -125,39 +159,17 @@ class AutoMaterializeRule(Rule):
     (workflow/AutoCacheRule.scala).  Here the executor already memoizes
     per-node results, so "cache or recompute" is decided structurally:
     shared outputs get an explicit materialization barrier, which also
-    pins them as stage boundaries for the fusion rule below.  A cost-model
-    driven HBM-vs-recompute variant is the round-2 refinement.
+    pins them as stage boundaries for the fusion rule below.  The
+    cost-model driven HBM-vs-recompute variant is the default
+    (``ProfiledMaterializeRule``); this one is its fallback.
     """
 
     name = "AutoMaterialize"
 
     def apply(self, graph: G.Graph) -> G.Graph:
         for n in list(graph.topological_nodes()):
-            op = graph.operators.get(n)
-            if not isinstance(op, (G.TransformerOperator,)):
-                continue
-            if isinstance(op.transformer, Cacher):
-                continue
-            deps_on_n = [d for d in graph.dependents(n) if not isinstance(d, G.SinkId)]
-            already = any(
-                isinstance(graph.operators.get(d), G.TransformerOperator)
-                and isinstance(graph.operators[d].transformer, Cacher)
-                for d in deps_on_n
-                if isinstance(d, G.NodeId)
-            )
-            if len(deps_on_n) > 1 and not already:
-                graph, cache_node = graph.add_node(
-                    G.TransformerOperator(Cacher()), (n,)
-                )
-                for d in deps_on_n:
-                    if isinstance(d, G.NodeId):
-                        graph = graph.set_dependencies(
-                            d,
-                            tuple(
-                                cache_node if x == n else x
-                                for x in graph.dependencies[d]
-                            ),
-                        )
+            if isinstance(graph.operators.get(n), G.TransformerOperator):
+                graph = place_barrier(graph, n)
         return graph
 
 

@@ -23,8 +23,12 @@ import numpy as np
 
 from keystone_tpu.workflow import graph as G
 from keystone_tpu.workflow.dataset import Dataset
-from keystone_tpu.workflow.optimizer import Rule, _truncate_datasets
-from keystone_tpu.workflow.transformer import Cacher
+from keystone_tpu.workflow.optimizer import (
+    Rule,
+    _truncate_datasets,
+    needs_barrier,
+    place_barrier,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -37,6 +41,10 @@ class NodeProfile:
     output_bytes: int
     scale: float  # full_n / sample_n extrapolation factor
     hlo_seconds: Optional[float] = None  # full-scale roofline estimate
+    #: where the static price came from: a program compiled for it (False),
+    #: the memo of an earlier node of its kind and shapes (True), or no
+    #: static price was asked for or possible (None)
+    price_hit: Optional[bool] = None
 
     @property
     def full_bytes(self) -> int:
@@ -128,9 +136,10 @@ def profile_graph(
     passes the SHARED nodes here: they are the only ones whose profiles
     the placement decision reads, and pricing only them avoids compiling
     every stage at full batch size and avoids sampled execution of
-    subgraphs (e.g. the solver's) that no shared output depends on —
-    measured 4 shared of 23 profilable on the north-star fit, where the
-    unrestricted pass was ~60% of total fit wall-clock."""
+    subgraphs (e.g. the solver's) that no shared output depends on — 4
+    shared of 23 profilable on the north-star fit when rounds 1–5 measured
+    it, where the unrestricted pass was ~60% of total fit wall-clock; in
+    the benchmark's ImageNet fit one node, `PixelScaler` (PR 33)."""
     from keystone_tpu.workflow.executor import DatasetExpr, GraphExecutor
 
     full_n = max(
@@ -161,89 +170,81 @@ def profile_graph(
             arr = expr.dataset.array
             nbytes = int(np.prod(arr.shape)) * arr.dtype.itemsize
             sample_n = max(expr.dataset.n, 1)
-        hlo_seconds = None
+        hlo_seconds = price_hit = None
         if static_cost:
-            hlo_seconds = _static_node_seconds(truncated, ex, n, op, full_n)
+            hlo_seconds, price_hit = _static_node_seconds(truncated, ex, n, op, full_n)
         profiles[n] = NodeProfile(
             seconds=ex.timings.get(n, 0.0),
             output_bytes=nbytes,
             scale=max(full_n / sample_n, 1.0),
             hlo_seconds=hlo_seconds,
+            price_hit=price_hit,
         )
     return profiles
 
 
 def _static_node_seconds(graph: G.Graph, ex, n: G.NodeId, op, full_n: int):
-    """Full-scale roofline estimate for one transformer node, from the
-    sampled input's shape with the batch axis widened to full_n."""
+    """(full-scale roofline estimate, whether the memo had it) for one
+    transformer node, from the sampled input's shape with the batch axis
+    widened to full_n; (None, None) for a node that cannot be priced."""
     import jax
 
     if not isinstance(op, G.TransformerOperator):
-        return None
+        return None, None
     from keystone_tpu.workflow.executor import DatasetExpr
 
     deps = graph.dependencies.get(n, ())
     if len(deps) != 1:
-        return None
+        return None, None
     d = ex.results.get(deps[0])
     if not isinstance(d, DatasetExpr) or d.dataset.is_host:
-        return None
+        return None, None
     ds = d.dataset
     arr_aval = jax.ShapeDtypeStruct((full_n,) + tuple(ds.array.shape[1:]), ds.array.dtype)
     mask_aval = None
     if ds.mask is not None:
         mask_aval = jax.ShapeDtypeStruct((full_n,) + tuple(ds.mask.shape[1:]), ds.mask.dtype)
-    t = op.transformer
-    from keystone_tpu.obs import ledger
-
-    if type(t).traced_attrs:
-        return _priced_by_shape(t, arr_aval, mask_aval)
-    # a program traced, lowered and compiled (or loaded) anew on every
-    # optimizer pass — every fit, every scoring call — to be priced, never run
-    with ledger.span("transformer.jit_mint", node=type(t).__name__, shared=False):
-        if mask_aval is not None:
-            cost = hlo_stage_cost(lambda a, m: t.apply_batch(a, mask=m), arr_aval, mask_aval)
-        else:
-            cost = hlo_stage_cost(lambda a: t.apply_batch(a), arr_aval)
-    return cost["seconds_est"] if cost else None
+    return _priced_by_shape(op.transformer, arr_aval, mask_aval)
 
 
-#: roofline seconds of nodes that declare ``traced_attrs``, by what the
-#: estimate can depend on: (share key, input and parameter shapes, matmul
-#: mode).  Bounded FIFO, as the shared-apply cache is.
+#: roofline seconds of a node, by what the estimate can depend on: (the
+#: node's program identity, input and parameter shapes, matmul mode).
+#: Bounded FIFO, as the shared-apply cache is; holds numbers only.
 _PRICED: dict = {}
 _PRICED_MAX = 128
 
 
 def _priced_by_shape(t, arr_aval, mask_aval):
-    """The static price of a node whose fitted arrays ride as traced
-    ARGUMENTS (``Transformer.traced_attrs``): the program is lowered from
-    their shapes, as the node's own shared apply is, and the estimate is
-    kept for every later node of the same kind and shapes.  Closed over
-    instead, a freshly fitted array is read back to the host by the
-    lowering and embedded in the program — 4.3 MB of convolution filters
-    made a new 2 s compile of every RandomPatchCifar fit (my chip run,
-    PR 32) — for a number that cannot depend on its values."""
+    """The static price of a node, and whether the memo had it.  The
+    program is lowered from SHAPES, as the node's own shared apply is —
+    its fitted arrays (``Transformer.traced_attrs``; none for a
+    parameter-free node) ride as traced arguments — and the estimate is
+    kept for every later node of the same kind and shapes: a warm fit or
+    call prices from the memo and compiles nothing.  Closed over instead,
+    a freshly fitted array is read back to the host by the lowering and
+    embedded in the program — 4.3 MB of convolution filters made a new
+    2 s compile of every RandomPatchCifar fit (my chip run, PR 32) — for
+    a number that cannot depend on its values.  A node that promises no
+    identity (``program_share_key`` None) is priced every time."""
     import jax
 
     from keystone_tpu.obs import ledger
     from keystone_tpu.utils import precision
-    from keystone_tpu.workflow.transformer import rebound, share_key, stripped_template
+    from keystone_tpu.workflow.transformer import program_share_key, rebound
 
     shape_of = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
     params = {
         name: jax.tree_util.tree_map(shape_of, getattr(t, name))
         for name in type(t).traced_attrs
     }
-    kind = share_key(t)  # None: the node promises no identity, and is priced every time
+    kind = program_share_key(t)
     key = (kind, precision.matmul_mode(), str(arr_aval), str(mask_aval),
            str(jax.tree_util.tree_structure(params)), str(jax.tree_util.tree_leaves(params)))
     if kind is not None and key in _PRICED:
-        return _PRICED[key]
-    template = stripped_template(t)
+        return _PRICED[key], True
     with ledger.span("transformer.jit_mint", node=type(t).__name__, shared=False):
         cost = hlo_stage_cost(
-            lambda p, a, m: rebound(template, p).apply_batch(a, mask=m),
+            lambda p, a, m: rebound(t, p).apply_batch(a, mask=m),
             params, arr_aval, mask_aval,
         )
     seconds = cost["seconds_est"] if cost else None
@@ -251,7 +252,7 @@ def _priced_by_shape(t, arr_aval, mask_aval):
         while len(_PRICED) >= _PRICED_MAX:
             _PRICED.pop(next(iter(_PRICED)))
         _PRICED[key] = seconds
-    return seconds
+    return seconds, False
 
 
 def device_hbm_budget(fraction: float = 0.5) -> int:
@@ -348,6 +349,8 @@ class ProfilingAutoCacheRule(Rule):
         self.static_cost = bool(static_cost)
 
     def apply(self, graph: G.Graph) -> G.Graph:
+        from keystone_tpu.obs import ledger, metrics
+
         # a PREVIOUS fit's estimate must never leak into this fit's
         # auto-out-of-core decision (fallback/early-return paths would
         # otherwise leave it standing — review r5)
@@ -356,20 +359,33 @@ class ProfilingAutoCacheRule(Rule):
             n
             for n in graph.topological_nodes()
             if isinstance(graph.operators.get(n), (G.TransformerOperator, G.GatherOperator))
-            and len([d for d in graph.dependents(n) if not isinstance(d, G.SinkId)]) > 1
+            and needs_barrier(graph, n)
         ]
-        if not shared:  # nothing to place — skip the sampling pass entirely
+        if not shared:
+            # nothing to place — a linear graph, or one whose fan-out
+            # already stands behind a Cacher (a fitted pipeline's, in every
+            # call): no slice of the input, no sampled run, no price
+            ledger.annotate("optimizer.rule", to_place=0, sampled=0, priced=0, price_hits=0)
             return graph
         import os
 
         # debug/A-B knob: profile every node like the pre-r4 rule did
-        # (measured ~60% of north-star fit wall-clock; rounds 1–5, not re-measured)
+        # (~60% of the north-star fit's wall-clock when rounds 1–5 measured
+        # it; the shared-only pass is in `fit_optimize_s`, 0.032 s of a
+        # 0.447 s ImageNet fit: my chip run, PR 33)
         profile_all = os.environ.get("KEYSTONE_CACHE_PROFILE_ALL", "") == "1"
         profiles = profile_graph(
             graph,
             self.sample_size,
             static_cost=self.static_cost,
             targets=None if profile_all else frozenset(shared),
+        )
+        ledger.annotate(
+            "optimizer.rule",
+            to_place=len(shared),
+            sampled=1,
+            priced=sum(p.price_hit is False for p in profiles.values()),
+            price_hits=sum(p.price_hit is True for p in profiles.values()),
         )
         seconds = _comparable_seconds(profiles)
         # most compute saved per byte pinned, first
@@ -391,7 +407,7 @@ class ProfilingAutoCacheRule(Rule):
             if cost <= remaining:
                 remaining -= cost
                 pinned_bytes += cost
-                graph = _insert_cacher(graph, n)
+                graph = place_barrier(graph, n)
             else:
                 op = graph.operators[n]
                 if isinstance(op, G.TransformerOperator):
@@ -415,8 +431,6 @@ class ProfilingAutoCacheRule(Rule):
                 "budget_bytes": int(self.budget_bytes),
             }
         )
-        from keystone_tpu.obs import ledger, metrics
-
         metrics.set_gauge("optimizer.pinned_bytes", float(pinned_bytes))
         if demotions:
             metrics.inc("optimizer.no_memoize_demotions", demotions)
@@ -454,21 +468,3 @@ def _comparable_seconds(profiles: Dict[G.NodeId, NodeProfile]) -> Dict[G.NodeId,
         )
         for n, p in profiles.items()
     }
-
-
-def _insert_cacher(graph: G.Graph, n: G.NodeId) -> G.Graph:
-    deps_on_n = [d for d in graph.dependents(n) if isinstance(d, G.NodeId)]
-    already = any(
-        isinstance(graph.operators.get(d), G.TransformerOperator)
-        and isinstance(graph.operators[d].transformer, Cacher)
-        for d in deps_on_n
-    )
-    if already:
-        return graph
-    graph, cache_node = graph.add_node(G.TransformerOperator(Cacher()), (n,))
-    for d in deps_on_n:
-        if d != cache_node:
-            graph = graph.set_dependencies(
-                d, tuple(cache_node if x == n else x for x in graph.dependencies[d])
-            )
-    return graph

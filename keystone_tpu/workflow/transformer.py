@@ -105,8 +105,9 @@ def stripped_template(t: "Transformer") -> "Transformer":
 
 
 def rebound(template: "Transformer", params: dict) -> "Transformer":
-    """A copy of a ``stripped_template`` with its traced attributes bound to
-    ``params`` (tracers, inside a shared program's trace)."""
+    """A copy of ``template`` — a ``stripped_template``, or the node itself
+    for a trace nothing keeps (``profiling.py``'s pricing) — with its traced
+    attributes bound to ``params`` (tracers, inside the program's trace)."""
     import copy
 
     obj = copy.copy(template)
@@ -197,6 +198,17 @@ def _holds_array(t: "Transformer") -> bool:
         isinstance(leaf, (jax.Array, np.ndarray))
         for leaf in jax.tree_util.tree_leaves(getattr(t, "__dict__", {}))
     )
+
+
+def program_share_key(t: "Transformer"):
+    """``share_key(t)`` for what is kept per PROGRAM of a node — its jitted
+    apply, its static price — or None where the code can see the promise
+    does not cover the program: a node that holds an array it did not
+    declare in traced_attrs.  The array check comes first: such a node's
+    params() may have to digest the arrays it holds."""
+    if not type(t).traced_attrs and _holds_array(t):
+        return None
+    return share_key(t)
 
 
 #: canonical apply chunk (rows); 0 = whole-batch applies.
@@ -561,10 +573,7 @@ class Transformer(Chainable):
             getattr(xs, "ndim", None),
             None if mask is None else str(getattr(mask, "dtype", "")),
         )
-        # the array check first: such a node's params() may have to
-        # digest the arrays it holds
-        undeclared = not type(self).traced_attrs and _holds_array(self)
-        skey = None if undeclared else share_key(self)
+        skey = program_share_key(self)
         if skey is not None:
             return self._apply_batch_shared(xs, mask, skey, sig)
         entry = _JIT_APPLY_CACHE.get(self)

@@ -502,6 +502,144 @@ def test_default_optimizer_uses_profiled_materialization():
     )
 
 
+def _two_branch_fit():
+    """Fitted: Expensive -> {AddC(1), AddC(2)} -> gather -> combine -> block
+    least squares.  CSE merges the two Expensive nodes and the fit's
+    optimizer places a Cacher behind the merged one; the fitted graph
+    keeps it."""
+    from keystone_tpu.models import BlockLeastSquaresEstimator
+    from keystone_tpu.ops.util import VectorCombiner
+
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(96, 8)).astype(np.float32)
+    y = np.where(rng.random((96, 3)) < 0.3, 1.0, -1.0).astype(np.float32)
+    feat = Pipeline.gather(
+        [Expensive("two") | AddC(1.0), Expensive("two") | AddC(2.0)]
+    ) | VectorCombiner()
+    fitted = feat.and_then(
+        BlockLeastSquaresEstimator(block_size=8, num_iter=1, lam=1e-3), Dataset(x), Dataset(y)
+    ).fit()
+    return fitted, rng.normal(size=(32, 8)).astype(np.float32)
+
+
+def _labels(g, flag=None):
+    """Labels of g's transformer nodes (those whose operator has ``flag``)."""
+    from keystone_tpu.workflow import TransformerOperator
+
+    return sorted(
+        op.label() for op in g.operators.values()
+        if isinstance(op, TransformerOperator) and (flag is None or getattr(op, flag, False))
+    )
+
+
+def _feeds(g, label):
+    """Labels of the nodes that read a ``label`` node's output."""
+    return sorted(
+        g.operators[d].label()
+        for n, op in g.operators.items() if op.label() == label
+        for d in g.dependents(n) if d in g.operators
+    )
+
+
+@pytest.mark.parametrize(
+    "case", ["one_cacher", "second_call_is_quiet", "scores_of_the_structural_rule",
+             "new_fan_out", "over_budget"]
+)
+def test_a_placed_cacher_is_a_decision_already_made(case, monkeypatch):
+    """A fitted pipeline's graph keeps the Cacher its fit placed, and every
+    scoring call optimizes that graph again.  The node with two consumers is
+    then the Cacher itself: a barrier, not a candidate (``optimizer.
+    needs_barrier``, the one test of both materialization rules).  So the
+    call's pass finds nothing to place and leaves before it slices the
+    input, runs a sample, waits for it and compiles a price — while fan-out
+    that is NEW in the graph it sees is still profiled and placed, and the
+    over-budget ranking and demotion are what they were."""
+    import keystone_tpu.workflow.profiling as prof_mod
+    from benchmark import compile_log
+    from keystone_tpu.obs import ledger
+    from keystone_tpu.workflow import PipelineEnv
+    from keystone_tpu.workflow.optimizer import AutoMaterializeRule, default_optimizer
+
+    sampled = []
+    orig = prof_mod.profile_graph
+    monkeypatch.setattr(
+        prof_mod, "profile_graph", lambda *a, **k: sampled.append(1) or orig(*a, **k)
+    )
+    fitted, held = _two_branch_fit()
+    assert sampled == [1] and _feeds(fitted.graph, "Expensive") == ["Cacher"]
+
+    def scoring_graph():
+        return PipelineEnv.get_optimizer().execute(fitted(Dataset(held)).graph)
+
+    if case == "one_cacher":
+        g = scoring_graph()
+        assert _labels(g).count("Cacher") == 1 and len(g.operators) == len(fitted.graph.operators) + 1
+        assert _feeds(g, "Cacher") == ["AddC", "AddC"] and sampled == [1]
+    elif case == "second_call_is_quiet":
+        fitted(Dataset(held)).get().numpy()
+        log = compile_log.CompileLog().install()
+        mark = max(r.span_id for r in ledger.recent_spans())
+        before = log.snapshot()
+        fitted(Dataset(held)).get().numpy()
+        asked = compile_log.delta(log.snapshot(), before)
+        recs = [r for r in ledger.recent_spans() if r.span_id > mark]
+        assert (asked["requests"], asked["backend_compiles"]) == (0, 0)
+        assert not [r for r in recs if r.name == "transformer.jit_mint"]
+        (optimize,) = [r for r in recs if r.name == "pipeline.optimize"]
+        rules = [r for r in recs if r.parent_id == optimize.span_id]
+        assert {r.name for r in rules} == {"optimizer.rule"}
+        # no rule ran a stage, sliced or moved data: nothing was launched in it
+        assert not [r for r in recs if r.parent_id in {x.span_id for x in rules}]
+        assert sampled == [1]  # the fit's; neither call sliced and ran a sample
+    elif case == "scores_of_the_structural_rule":
+        profiled = fitted(Dataset(held)).get().numpy()
+        structural = default_optimizer()
+        for batch in structural.batches:
+            if batch.name == "materialize":
+                batch.rules = [AutoMaterializeRule()]
+        try:
+            PipelineEnv.set_optimizer(structural)
+            assert _labels(scoring_graph()).count("Cacher") == 1
+            np.testing.assert_array_equal(fitted(Dataset(held)).get().numpy(), profiled)
+        finally:
+            PipelineEnv.set_optimizer(None)
+    elif case == "new_fan_out":
+        # a lazy input whose own node feeds the fitted pipeline AND a
+        # second consumer: fan-out the fit never saw
+        lazy = Pipeline.of(AddC(5.0))(Dataset(held))
+        both = Pipeline.gather([fitted, Pipeline.of(AddC(7.0))])(lazy)
+        g = PipelineEnv.get_optimizer().execute(both.graph)
+        assert sampled == [1, 1]  # the fit's, and this graph's new shared node
+        # the fit's Cacher as it was, and one behind the new shared node
+        assert _feeds(g, "Cacher") == ["AddC", "AddC", "AddC", "Expensive"]
+        np.testing.assert_array_equal(
+            both.get().numpy()[:, :3], fitted(Dataset(held + 5.0)).get().numpy()
+        )
+    else:
+        # two shared nodes, room for one: the matmul chain saves more
+        # compute per byte pinned than the add, so it gets the Cacher and
+        # the add is demoted to recompute per consumer
+        class Heavy(Transformer):
+            def params(self):
+                return ("heavy",)
+
+            def apply_batch(self, xs, mask=None):
+                return (xs @ jnp.ones((8, 512))) @ jnp.ones((512, 8))
+
+        n = 4096
+        lazy = Pipeline.gather([
+            Heavy() | AddC(1.0), Heavy() | AddC(2.0),
+            AddC(9.0) | AddC(3.0), AddC(9.0) | AddC(4.0),
+        ])(Dataset(np.ones((n, 8), np.float32)))
+        monkeypatch.setenv("KEYSTONE_HBM_BUDGET_BYTES", str(2 * (n * 8 * 4) + 2))
+        g = default_optimizer().execute(lazy.graph)
+        assert _feeds(g, "Heavy") == ["Cacher"] and _labels(g).count("Cacher") == 1
+        assert _labels(g, flag="no_memoize") == ["AddC"]
+        assert prof_mod.last_footprint == {
+            "shared_bytes": 2 * n * 8 * 4, "budget_bytes": n * 8 * 4 + 1,
+        }
+
+
 def test_saved_state_orbax_mesh_mismatch_restores_replicated(tmp_path):
     """A prefix saved (mesh-padded) on one mesh must still restore under a
     mesh whose 'data' axis doesn't divide the saved leading dim — via the

@@ -166,7 +166,11 @@ def test_fit_without_ledger_leaves_one_tree_and_no_file(tmp_path, monkeypatch):
     for r in tree:
         if r.name == "optimizer.rule":
             assert by_id[r.parent_id].name == "pipeline.optimize"
-            assert set(r.attrs) == {"rule", "batch"}
+            how_it_went = set(r.attrs) - {"rule", "batch"}
+            assert how_it_went == (
+                {"to_place", "sampled", "priced", "price_hits"}
+                if r.attrs["rule"] == "ProfiledMaterialize" else set()
+            )
         if r.name == "solver.fit":
             assert by_id[r.parent_id].name == "executor.stage"
             # two sweeps, 24 rows a device on the suite's mesh, blocks of 8:
@@ -331,19 +335,14 @@ def test_fused_chain_is_named_for_its_classes():
     assert O._FUSED_SHARED_CACHE or chain._jitted  # the chain's wrapper is cached
 
 
-def test_second_fit_of_the_imagenet_graph_mints_no_node_program(imagenet_toy_config):
-    """Build and fit the north-star graph twice in one process: the
-    second fit's nodes are new objects with the first's (class,
-    params()), so none of them mints a wrapper — not the five of the
-    featurizer inside the fit, not the build's eager label node before
-    it.  What the second fit still asks of the compiler (what
-    ``fit_programs`` reads) is what the sampling rule compiles to price
-    a node, which opens the same span under ``optimizer.rule``
-    (``profiling.py § _static_node_seconds``: another mechanism)."""
+def _imagenet_toy_fitter(cfg):
+    """A function that builds and fits the north-star graph at toy size and
+    returns (fitted pipeline, the spans it left, what it asked of the
+    compiler); a process's first call mints for real, unless an earlier
+    test's did."""
     from keystone_tpu.loaders.imagenet import ImageNetLoader
     from keystone_tpu.pipelines import ImageNetSiftLcsFV
 
-    cfg = imagenet_toy_config
     train = ImageNetLoader.synthetic(
         cfg.synthetic_n, cfg.num_classes, size=(cfg.image_size, cfg.image_size), seed=1
     )
@@ -351,22 +350,81 @@ def test_second_fit_of_the_imagenet_graph_mints_no_node_program(imagenet_toy_con
 
     def build_and_fit():
         mark, before = _mark(), log.snapshot()
-        ImageNetSiftLcsFV.build_scorer(cfg, train.data, train.labels).fit().block_until_ready()
-        return _since(mark), compile_log.delta(log.snapshot(), before)
+        fitted = ImageNetSiftLcsFV.build_scorer(cfg, train.data, train.labels).fit()
+        fitted.block_until_ready()
+        return fitted, _since(mark), compile_log.delta(log.snapshot(), before)
 
-    build_and_fit()  # a process's first fit mints for real (unless an earlier test's did)
-    records, asked = build_and_fit()
-    by_id = {r.span_id: r for r in records}
+    return build_and_fit, train, log
+
+
+def test_second_fit_of_the_imagenet_graph_mints_no_node_program(imagenet_toy_config):
+    """Build and fit the north-star graph twice in one process: the
+    second fit's nodes are new objects with the first's (class,
+    params()), so none of them mints a wrapper — not the five of the
+    featurizer inside the fit, not the build's eager label node before
+    it — and the sampling rule prices its shared ``PixelScaler`` from
+    the memo of the first fit's (``profiling.py § _priced_by_shape``):
+    the second fit asks the compiler for nothing (``fit_programs`` 0)."""
+    build_and_fit, _, _ = _imagenet_toy_fitter(imagenet_toy_config)
+    build_and_fit()
+    _, records, asked = build_and_fit()
     mints = [r for r in records if r.name == "transformer.jit_mint"]
-    applied = [
-        r for r in mints
-        if getattr(by_id.get(r.parent_id), "name", None) != "optimizer.rule"
-    ]
-    assert [r.attrs for r in applied] == []
-    assert all(r.attrs["shared"] is False for r in mints)  # the priced ones
-    (root,) = [r for r in records if r.name == "pipeline.fit"]
-    assert all(r.root_id == root.span_id for r in mints)
-    assert (asked["requests"] or asked["backend_compiles"]) == len(mints)
+    assert [r.attrs for r in mints] == []
+    assert (asked["requests"], asked["backend_compiles"]) == (0, 0)
+    assert len([r for r in records if r.name == "pipeline.fit"]) == 1
+
+
+def test_the_sampling_rules_span_says_how_its_pass_went(imagenet_toy_config):
+    """``to_place`` (shared nodes with no barrier yet), ``sampled`` (the
+    sampled run happened), ``priced`` (programs compiled to price) and
+    ``price_hits`` (prices from the memo) on the ``optimizer.rule`` span of
+    ``ProfiledMaterialize``: a second fit samples its shared nodes and
+    prices them from the memo; a scoring call of the fitted pipeline finds
+    its fan-out behind the fit's ``Cacher`` and does neither."""
+    build_and_fit, train, log = _imagenet_toy_fitter(imagenet_toy_config)
+
+    def passes(records):
+        return [
+            {k: r.attrs[k] for k in ("to_place", "sampled", "priced", "price_hits")}
+            for r in records
+            if r.name == "optimizer.rule" and r.attrs["rule"] == "ProfiledMaterialize"
+        ]
+
+    build_and_fit()
+    fitted, records, _ = build_and_fit()
+    (fit_pass,) = passes(records)  # the whole entry's graph: four shared nodes
+    assert fit_pass["to_place"] >= 1 and fit_pass["sampled"] == 1
+    assert fit_pass["priced"] == 0 and 1 <= fit_pass["price_hits"] <= fit_pass["to_place"]
+    images = np.asarray(train.data.numpy()[:8])
+    fitted(Dataset(images)).get().numpy()
+    mark, before = _mark(), log.snapshot()
+    fitted(Dataset(images)).get().numpy()
+    records = _since(mark)
+    assert passes(records) == [{"to_place": 0, "sampled": 0, "priced": 0, "price_hits": 0}]
+    assert compile_log.delta(log.snapshot(), before)["requests"] == 0
+    assert not [r for r in records if r.name == "transformer.jit_mint"]
+
+
+def test_obs_report_prints_how_the_sampling_rules_passes_went(tmp_path):
+    from keystone_tpu.ops import LinearRectifier
+    from tools.obs_report import render, summarize
+
+    x = Dataset(np.random.default_rng(0).normal(size=(32, 6)).astype(np.float32))
+    shared = Pipeline.gather([
+        Pipeline.of(LinearRectifier(0.0)) | LinearRectifier(0.5),
+        Pipeline.of(LinearRectifier(0.0)) | LinearRectifier(1.0),
+    ])
+    run = ledger.start_run(str(tmp_path))
+    try:
+        shared(x).get()  # one shared node: sampled, priced or taken from the memo
+        (Pipeline.of(LinearRectifier(0.0)) | LinearRectifier(0.5))(x).get()  # none
+    finally:
+        ledger.stop_run()
+    (line,) = [ln for ln in render(summarize(run.path)).splitlines()
+               if "ProfiledMaterialize" in ln]
+    assert "to_place=1  sampled=1" in line
+    priced, hits = (int(line.split(f"{k}=")[1].split()[0]) for k in ("priced", "price_hits"))
+    assert priced + hits == 1
 
 
 # ---------------------------------------------------------------- the readers

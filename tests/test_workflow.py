@@ -668,6 +668,68 @@ def test_degrading_node_shares_its_plain_twins_program():
     assert degrading.fallback is not None
 
 
+def _price_cases():
+    from keystone_tpu.ops import GrayScaler, PixelScaler
+
+    return [
+        pytest.param(lambda: PixelScaler(only_if_integer=True), np.uint8, True, id="PixelScaler"),
+        pytest.param(GrayScaler, np.float32, True, id="GrayScaler"),
+        pytest.param(lambda: transformer(lambda v: v * 2.0), np.float32, False, id="params-None"),
+        pytest.param(lambda: _Shift(jnp.arange(3, dtype=jnp.float32)), np.float32, False,
+                     id="undeclared-array"),
+    ]
+
+
+@pytest.mark.parametrize("make, dtype, kept", _price_cases())
+def test_a_nodes_price_is_kept_by_kind_shapes_and_matmul_mode(make, dtype, kept, monkeypatch):
+    """The sampling rule's one pricing function (``profiling._priced_by_shape``)
+    compiles a node's program at the full batch shape to read XLA's cost
+    counters.  The number depends on the node's kind, its input and
+    parameter shapes and the matmul mode, so it is kept by those: a new
+    object of the kind — the next fit's — is priced from the memo and
+    nothing is compiled.  A node that promises no identity (params() None,
+    or an array it did not declare) is priced every time, as its apply is
+    minted per object."""
+    from keystone_tpu.utils import precision
+    from keystone_tpu.workflow import profiling
+
+    compiled = []
+    orig = profiling.hlo_stage_cost
+    monkeypatch.setattr(profiling, "hlo_stage_cost",
+                        lambda *a: compiled.append(1) or orig(*a))
+    monkeypatch.setattr(profiling, "_PRICED", {})
+    at = lambda n: jax.ShapeDtypeStruct((n, 8, 8, 3), dtype)  # noqa: E731
+    first, hit = profiling._priced_by_shape(make(), at(64), None)
+    assert first is not None and first > 0 and hit is False and len(compiled) == 1
+    again, hit = profiling._priced_by_shape(make(), at(64), None)  # a NEW object
+    assert again == first and hit is kept and len(compiled) == (1 if kept else 2)
+    assert len(profiling._PRICED) == (1 if kept else 0)
+    if not kept:
+        return
+    _, hit = profiling._priced_by_shape(make(), at(128), None)  # another shape
+    assert hit is False and len(compiled) == 2
+    with precision.matmul("bf16" if precision.matmul_mode() != "bf16" else "f32"):
+        _, hit = profiling._priced_by_shape(make(), at(64), None)  # another mode
+        assert hit is False and len(compiled) == 3
+    assert profiling._priced_by_shape(make(), at(64), None) == (first, True)
+    assert len(profiling._PRICED) == 3 and len(compiled) == 3
+
+
+def test_the_price_memo_is_bounded(monkeypatch):
+    from keystone_tpu.ops import ClassLabelIndicators
+    from keystone_tpu.workflow import profiling
+
+    monkeypatch.setattr(profiling, "_PRICED", {})
+    monkeypatch.setattr(profiling, "_PRICED_MAX", 3)
+    labels = jax.ShapeDtypeStruct((16,), jnp.int32)
+    for k in range(2, 8):
+        profiling._priced_by_shape(ClassLabelIndicators(k), labels, None)
+    assert [key[0][2] for key in profiling._PRICED] == [(5,), (6,), (7,)]
+    # an evicted kind is just priced again
+    assert profiling._priced_by_shape(ClassLabelIndicators(2), labels, None)[1] is False
+    assert len(profiling._PRICED) == 3
+
+
 def test_shared_sift_program_is_the_per_object_wrappers_text():
     """The lowered module of the shared apply is, text for text, what
     the per-object ``(xs, mask)`` wrapper lowered to, so the persistent

@@ -114,7 +114,7 @@ ATTR_KEY_RE = re.compile(r"^[a-z][a-z0-9_]*$")
 #: bound active-ledger variable) and flight-recorder emit methods
 #: (receiver must look like a recorder binding) whose literal keyword
 #: names the ``attr`` rule checks against the registered vocabulary
-_LEDGER_EMITS = frozenset({"span", "event"})
+_LEDGER_EMITS = frozenset({"span", "event", "annotate"})
 _LEDGER_RECEIVERS = frozenset({"ledger", "led", "_ledger"})
 _RECORDER_EMITS = frozenset({"annotate", "finish", "batch", "batch_update", "ops"})
 _RECORDER_RECEIVERS = frozenset({"rec", "recorder"})

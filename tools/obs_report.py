@@ -14,7 +14,11 @@ Sections (each only when the run recorded it):
 - **stages**: top executor stages by total span seconds, with attempt /
   retry counts and failed-attempt time;
 - **optimizer**: seconds and runs per optimizer rule (``optimizer.rule``
-  spans), and what the nodes' signatures cost inside the
+  spans); for the sampling rule also how its passes went, summed —
+  ``to_place`` (shared nodes with no barrier yet), ``sampled`` (passes
+  that sliced the input and ran a sample), ``priced`` (programs compiled
+  to price a node) and ``price_hits`` (prices from the memo); and what
+  the nodes' signatures cost inside the
   ``pipeline.optimize`` spans: bytes of weights copied to the host to be
   digested (``sig_bytes_hashed``) and nodes that signed with the recipe
   of a seeded draw instead (``sig_by_recipe``);
@@ -56,6 +60,10 @@ import sys
 from typing import Dict, List, Optional
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+#: what ``workflow/profiling.py § ProfilingAutoCacheRule`` says of a pass
+#: on the ``optimizer.rule`` span around it
+_PASS_ATTRS = ("to_place", "sampled", "priced", "price_hits")
 
 
 def resolve_ledger_path(path: str) -> str:
@@ -152,6 +160,9 @@ def summarize(path: str, top_k: int = 10) -> dict:
             st = optimizer.setdefault(rule, {"seconds": 0.0, "count": 0})
             st["seconds"] += float(e.get("seconds") or 0.0)
             st["count"] += 1
+            for key in _PASS_ATTRS:  # how the sampling rule's passes went
+                if key in attrs:
+                    st[key] = st.get(key, 0) + int(attrs[key] or 0)
         elif e.get("name") == "pipeline.optimize" and "sig_bytes_hashed" in attrs:
             signatures["optimizes"] = signatures.get("optimizes", 0) + 1
             for key in ("sig_bytes_hashed", "sig_by_recipe"):
@@ -437,7 +448,8 @@ def render(summary: dict) -> str:
         for rule, st in sorted(
             summary["optimizer"].items(), key=lambda kv: -kv[1]["seconds"]
         ):
-            out.append(f"  {st['seconds']:>9.3f}  {st['count']:>4}  {rule}")
+            how = [f"{k}={st[k]}" for k in _PASS_ATTRS if k in st]
+            out.append("  ".join([f"  {st['seconds']:>9.3f}  {st['count']:>4}  {rule}"] + how))
         sg = summary.get("signatures")
         if sg:
             out.append(
