@@ -447,7 +447,7 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             w, p = _bcd_epoch(xb, yc, nf, self.lam, w, p)
             # required sync (the gathers below read w); metered as
             # device-busy either way
-            ledger.device_wait(w, force=True)
+            ledger.device_wait(w)
             # the gathers are COLLECTIVES: every process must run them
             w_host = gather_to_host(w)
             p_host = gather_to_host(p)
@@ -709,7 +709,7 @@ def _oc_bcd_fit(
         # window only bounds in-flight TRANSFERS; transfers are not
         # ordered behind compute, so without this the Python loop races
         # the whole sweep into the queue.  The wait is device-busy time.
-        ledger.device_wait(x, force=True)
+        ledger.device_wait(x)
 
     if fit_intercept:
         # double-buffered device feed: block b+1's host→device transfer
@@ -857,7 +857,7 @@ def _oc_bcd_fit(
             if ckpt_path is not None:
                 # required sync (the gathers below read p); metered as
                 # device-busy either way
-                ledger.device_wait(p, force=True)
+                ledger.device_wait(p)
                 # collectives first (every process participates) …
                 w_host = np.stack([_mh.gather_to_host(x) for x in w])
                 p_host = _mh.gather_to_host(p)

@@ -745,7 +745,7 @@ def _oc_krr_fit(
         # bounds transfers, not the dispatch queue
         pending.append(tick)
         if len(pending) > 2:
-            ledger.device_wait(pending.popleft(), force=True)
+            ledger.device_wait(pending.popleft())
         if pos == per_epoch - 1:
             save_seconds = None
             if ckpt_path is not None:
@@ -753,7 +753,7 @@ def _oc_krr_fit(
 
                 # required sync (the host reads below consume α/F);
                 # metered as device-busy either way
-                ledger.device_wait((ab, fb), force=True)
+                ledger.device_wait((ab, fb))
                 a_host = np.stack([np.asarray(x) for x in ab])  # lint: allow-host-sync
                 f_host = np.stack([np.asarray(x) for x in fb])  # lint: allow-host-sync
                 t_save = _time.perf_counter()
@@ -872,7 +872,7 @@ class OutOfCoreKernelBlockLinearMapper(Transformer):
             )
             pending.append(out)
             if len(pending) > 2:
-                ledger.device_wait(pending.popleft(), force=True)
+                ledger.device_wait(pending.popleft())
         return out
 
     def apply_one(self, x):

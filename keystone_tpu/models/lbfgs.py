@@ -288,7 +288,7 @@ def lbfgs_minimize_resumable(
             # the DEVICE carry is handed over: at d·k·(2m+2) scale the
             # host copy is GBs, and non-writer processes must not pay it
             # (save_cb converts after its process-index check)
-            ledger.device_wait(carry, force=True)
+            ledger.device_wait(carry)
             t_save = _time.perf_counter()
             save_cb(it, carry)
             save_seconds = _time.perf_counter() - t_save

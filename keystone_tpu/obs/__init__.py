@@ -15,7 +15,14 @@ primitives every subsystem reports through:
   (Dapper-style) activated by ``KEYSTONE_OBS_DIR`` or
   ``ledger.start_run``, which also samples HBM/RSS watermarks at the
   end of root spans.  Long-lived runs rotate past
-  ``KEYSTONE_OBS_MAX_BYTES`` into keep-N numbered segments.
+  ``KEYSTONE_OBS_MAX_BYTES`` into keep-N numbered segments.  The
+  program's forced waits go through ``ledger.device_wait`` /
+  ``ledger.waiting`` (a ``device.wait`` span each, also charged to the
+  ``device.busy_seconds`` account); what runs on without the host — a
+  host-to-device put — is handed to ``ledger.watch``, whose one daemon
+  watcher thread closes a record (``dataset.transfer``) in the same
+  three sinks when the array is ready, so that the calling thread never
+  waits for an observation.
 - :mod:`keystone_tpu.obs.recorder` — the serving path's flight
   recorder: a bounded in-memory ring of recent request traces with
   tail-based retention (shed/error/slow traces pinned), ON by default

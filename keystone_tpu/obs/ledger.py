@@ -1,5 +1,6 @@
 """Spans, and the run ledger that exports them: one span primitive for
-the whole program, always on, with three sinks.
+the whole program, always on, with three sinks; and the one thread that
+closes a span for what the calling thread must not wait for.
 
 :class:`span` is the one way to time a region.  Every span
 
@@ -18,8 +19,28 @@ the whole program, always on, with three sinks.
 3. only while a JSONL **run ledger** is active, writes ``span_start`` /
    ``span_end`` lines to it.
 
-Observing never synchronises: nothing here waits for the device unless the
-program needs that wait anyway (``device_wait(x, force=True)``).
+Observing never synchronises: nothing here makes the calling thread wait
+for the device.  A wait that the program needs anyway is made through
+:func:`device_wait` (or inside :func:`waiting`) and so has a name: a
+``device.wait`` span, whose parent says whose wait it is.
+
+What runs on without the host — a host-to-device put — gets its real
+duration from :func:`watch`: the caller hands the device array over and goes
+on; one daemon thread (the **watcher**, started by the first hand-off) waits
+for it and closes a record that starts where the caller's span started and
+ends when the array is ready.  Such a record reaches the same sinks as a
+span: the ring, the JSONL ledger while one is active (a ``span_end`` line;
+it has no ``span_start``, nothing ran on a thread of the program), and a
+profiler session's host plane, on the watcher's own line (the
+``TraceAnnotation`` is held around the watcher's wait, so it starts when the
+watcher took the array up, not at the put).  It closes AFTER its parent and
+its root: a reader selects it by ``parent_id`` / ``root_id`` / ``t0_ns``,
+never by its place in the ring.  Its end is the watcher's wake-up: some
+40 µs after the array is ready while the calling thread waits or sleeps, up
+to the interpreter's switch interval (5 ms) after it while the calling
+thread runs bytecode without releasing the GIL (CPU micro-measurement,
+PR 34).  The watcher holds an array only until it is ready; at exit it is
+told to stop and joined for a bounded time.
 
 One **run** = one JSONL file ``run_<run_id>.jsonl`` under the ledger
 directory.  Every line is one event::
@@ -58,9 +79,11 @@ from __future__ import annotations
 
 import atexit
 import collections
+import contextlib
 import itertools
 import json
 import os
+import queue
 import threading
 import time
 from typing import Any, Dict, List, NamedTuple, Optional
@@ -107,6 +130,7 @@ ATTR_VOCABULARY = {
     "d",
     "degraded",
     "depth",
+    "dtype",
     "epoch",
     "epoch_seconds",
     "error",
@@ -159,6 +183,7 @@ ATTR_VOCABULARY = {
     "rule",
     "sampled",
     "seconds",
+    "shape",
     "shared",
     "shared_bytes",
     "shared_nodes",
@@ -312,7 +337,8 @@ class span:
     ``sp.set(**attrs)`` merges attrs reported at close."""
 
     __slots__ = (
-        "name", "attrs", "span_id", "parent_id", "root_id", "t0_ns", "_ann", "_led",
+        "name", "attrs", "span_id", "parent_id", "root_id", "t0_ns", "dur_ns", "_ann",
+        "_led",
     )
 
     def __init__(self, name: str, **attrs):
@@ -344,7 +370,7 @@ class span:
         return self
 
     def __exit__(self, exc_type=None, exc=None, tb=None) -> None:
-        dur_ns = time.perf_counter_ns() - self.t0_ns
+        self.dur_ns = dur_ns = time.perf_counter_ns() - self.t0_ns
         self._ann.__exit__(exc_type, exc, tb)
         st = _stack()
         if st and st[-1] is self:
@@ -530,6 +556,7 @@ class RunLedger:
     def close(self, snapshot: bool = True) -> None:
         if self._closed:
             return
+        drain_watches(WATCHER_EXIT_SECONDS)  # records still open belong to this run
         _sample_memory()
         if snapshot:
             self.metrics_snapshot()
@@ -631,31 +658,121 @@ def restore_context(token) -> None:
         _TLS.stack = list(token)
 
 
-def device_wait(x, account: str = "device.busy_seconds", force: bool = False):
-    """Returns ``x``; with ``force=True``, after waiting for it (any
-    pytree of device values).
+@contextlib.contextmanager
+def waiting():
+    """The program's one forced wait: wrap the call that stands still until
+    the device has results the program needs NOW (a ``Cacher``'s or the
+    sampling pass's sync, flow control of a dispatch queue, a checkpoint
+    gather).  The wait is a ``device.wait`` span — its parent says whose it
+    is: an ``executor.stage`` under ``pipeline.fit`` / ``pipeline.apply`` is
+    a node's sync, one under ``optimizer.rule`` the sampling pass's, a
+    ``solver.fit`` the solver's flow control — and its seconds go to the
+    ``device.busy_seconds`` account (a host-side measure: seconds the host
+    was BLOCKED on device results; with ``blockstore.stage_wait_seconds``
+    it is ``tools/obs_report.py``'s ``dataflow`` summary) and to this
+    thread's running total (:func:`waited_seconds`)."""
+    sp = span("device.wait")
+    try:
+        with sp:
+            yield
+    finally:
+        seconds = sp.dur_ns / 1e9
+        metrics.observe("device.busy_seconds", seconds)
+        _TLS.waited = getattr(_TLS, "waited", 0.0) + seconds
 
-    Observing never synchronises: without ``force`` this is the identity,
-    ledger or no ledger, so a traced run dispatches exactly as an
-    untraced one.  ``force=True`` is for call sites where the wait is
-    REQUIRED by the program itself (checkpoint gathers, dispatch-queue
-    flow control); the metering rides along: a ``device.wait`` span and
-    the seconds blocked charged to ``account``.
 
-    The account is a host-side measure: seconds the host spent BLOCKED
-    on device results at those waits.  Together with
-    ``blockstore.stage_wait_seconds`` (time blocked on host→device
-    staging) it is what ``tools/obs_report.py`` folds into the
-    ``dataflow`` summary the bench artifact embeds."""
-    if not force:
-        return x
+def device_wait(x):
+    """Wait for ``x`` (any pytree of device values) inside :func:`waiting`,
+    and return it."""
     import jax
 
-    with span("device.wait"):
-        t0 = time.perf_counter()
+    with waiting():
         jax.block_until_ready(x)
-        metrics.observe(account, time.perf_counter() - t0)
     return x
+
+
+def waited_seconds() -> float:
+    """Seconds the calling thread has stood in ``device.wait`` spans so far
+    (a running total: take the difference around a region)."""
+    return getattr(_TLS, "waited", 0.0)
+
+
+# -------------------------------------------------------------- the watcher
+
+_WATCHED: "queue.SimpleQueue" = queue.SimpleQueue()
+_WATCHER: Optional[threading.Thread] = None
+#: seconds the exit hook gives the watcher to close what is pending
+WATCHER_EXIT_SECONDS = 5.0
+
+
+def watch(name: str, array, parent: span, **attrs) -> None:
+    """Close a record ``name`` when ``array`` is ready, without the caller
+    waiting: it starts at ``parent``'s start (the put's), is ``parent``'s
+    child and shares its root.  ``array`` is one device array (a sharded one
+    is ready when every shard is); the watcher drops it as soon as it is
+    ready, and a deleted or donated one ends its record with
+    ``outcome="deleted"``."""
+    _check_attrs(attrs)
+    global _WATCHER
+    if _WATCHER is None or not _WATCHER.is_alive():
+        with _LOCK:
+            if _WATCHER is None or not _WATCHER.is_alive():
+                _WATCHER = threading.Thread(
+                    target=_watch_loop, name="keystone-obs-watcher", daemon=True
+                )
+                _WATCHER.start()
+    _WATCHED.put((name, array, parent.span_id, parent.root_id, parent.t0_ns, attrs))
+
+
+def _watch_loop() -> None:
+    while True:
+        item = _WATCHED.get()
+        if item is None:
+            return
+        if isinstance(item, threading.Event):  # drain_watches: all before it closed
+            item.set()
+            continue
+        name, array, parent_id, root_id, t0_ns, attrs = item
+        del item
+        with TraceAnnotation(name):
+            try:
+                array.block_until_ready()
+            except Exception:  # deleted or donated under the watcher, or the put failed
+                attrs = {**attrs, "outcome": "deleted" if array.is_deleted() else "error"}
+            end_ns = time.perf_counter_ns()
+        del array
+        span_id = next(_SPAN_IDS)
+        _RING.append(SpanRecord(span_id, parent_id, root_id, name, t0_ns, end_ns - t0_ns, attrs))
+        led = active()
+        if led is not None:
+            led._emit(
+                "span_end", name, span=span_id, parent=parent_id, attrs=attrs,
+                seconds=(end_ns - t0_ns) / 1e9,
+            )
+
+
+def drain_watches(timeout: Optional[float] = None) -> bool:
+    """Wait until the watcher has closed every record handed to it before
+    this call; False when ``timeout`` seconds did not suffice.  For readers
+    that need a finished window (tests, a report at the end of a run): the
+    program itself never calls it on a timed path."""
+    if _WATCHER is None or not _WATCHER.is_alive():
+        return True
+    done = threading.Event()
+    _WATCHED.put(done)
+    return done.wait(timeout)
+
+
+@atexit.register
+def _stop_watcher() -> None:
+    """Tell the watcher to stop and give it ``WATCHER_EXIT_SECONDS`` to
+    close what is pending: a daemon thread still inside the runtime's wait
+    when the interpreter finalises is the one way this could end a process
+    badly."""
+    watcher = _WATCHER
+    if watcher is not None and watcher.is_alive():
+        _WATCHED.put(None)
+        watcher.join(WATCHER_EXIT_SECONDS)
 
 
 def solver_obs() -> bool:

@@ -30,13 +30,21 @@ from keystone_tpu.parallel import mesh as _mesh
 def _to_device(arr, shard: bool = True):
     """``arr`` on the mesh (or as one device array).  A HOST array's put is
     a ``dataset.upload`` span: the host's time in it (staging copy and
-    enqueue — the transfer itself is asynchronous)."""
+    enqueue — the transfer itself is asynchronous), and a
+    ``dataset.transfer`` record under it that the ledger's watcher closes
+    when the array is ready: the transfer's own duration, from the put's
+    start, at no wait of this thread's."""
     put = _mesh.shard_batch if shard else jnp.asarray
     if isinstance(arr, jax.Array):
         return put(arr)
     arr = np.asarray(arr)
-    with ledger.span("dataset.upload", bytes=arr.nbytes):
-        return put(arr)
+    with ledger.span("dataset.upload", bytes=arr.nbytes) as upload:
+        out = put(arr)
+        ledger.watch(
+            "dataset.transfer", out, upload, bytes=out.nbytes, dtype=str(out.dtype),
+            shape=list(out.shape),
+        )
+        return out
 
 
 def _to_host(arr) -> np.ndarray:
@@ -136,7 +144,7 @@ class Dataset:
         # under a trace (the frozen apply lowered as one program) there
         # is nothing to wait for: a Cacher is an identity there
         if self._array is not None and not isinstance(self._array, jax.core.Tracer):
-            self._array.block_until_ready()
+            ledger.device_wait(self._array)
         return self
 
     def __repr__(self):

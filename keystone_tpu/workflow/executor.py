@@ -470,13 +470,18 @@ def _sync_expr(result) -> None:
     timings charge each node its own device time.  Fit nodes return a
     Transformer (not a pytree) — block on every array it holds (including
     nested model state), else the async solve would be misattributed to
-    the next dataset-producing node."""
+    the next dataset-producing node.  Every branch waits inside a
+    ``device.wait`` span."""
+    from keystone_tpu.obs import ledger
+
     if isinstance(result, DatasetExpr):
-        result.dataset.cache()
+        result.dataset.cache()  # its own ``device.wait`` span
     elif isinstance(result, DatumExpr):
-        block_on_arrays(result.value)
+        with ledger.waiting():
+            block_on_arrays(result.value)
     elif isinstance(result, TransformerExpr):
-        block_on_arrays(result.transformer)
+        with ledger.waiting():
+            block_on_arrays(result.transformer)
 
 
 def _apply_transformer(t: Transformer, deps):

@@ -365,7 +365,10 @@ class ProfilingAutoCacheRule(Rule):
             # nothing to place — a linear graph, or one whose fan-out
             # already stands behind a Cacher (a fitted pipeline's, in every
             # call): no slice of the input, no sampled run, no price
-            ledger.annotate("optimizer.rule", to_place=0, sampled=0, priced=0, price_hits=0)
+            ledger.annotate(
+                "optimizer.rule", to_place=0, sampled=0, priced=0, price_hits=0,
+                waited_seconds=0.0,
+            )
             return graph
         import os
 
@@ -374,6 +377,7 @@ class ProfilingAutoCacheRule(Rule):
         # it; the shared-only pass is in `fit_optimize_s`, 0.032 s of a
         # 0.447 s ImageNet fit: my chip run, PR 33)
         profile_all = os.environ.get("KEYSTONE_CACHE_PROFILE_ALL", "") == "1"
+        waited = ledger.waited_seconds()
         profiles = profile_graph(
             graph,
             self.sample_size,
@@ -386,6 +390,8 @@ class ProfilingAutoCacheRule(Rule):
             sampled=1,
             priced=sum(p.price_hit is False for p in profiles.values()),
             price_hits=sum(p.price_hit is True for p in profiles.values()),
+            # the sampled nodes' syncs: for the first, the wait for the upload
+            waited_seconds=ledger.waited_seconds() - waited,
         )
         seconds = _comparable_seconds(profiles)
         # most compute saved per byte pinned, first

@@ -168,7 +168,7 @@ def test_fit_without_ledger_leaves_one_tree_and_no_file(tmp_path, monkeypatch):
             assert by_id[r.parent_id].name == "pipeline.optimize"
             how_it_went = set(r.attrs) - {"rule", "batch"}
             assert how_it_went == (
-                {"to_place", "sampled", "priced", "price_hits"}
+                {"to_place", "sampled", "priced", "price_hits", "waited_seconds"}
                 if r.attrs["rule"] == "ProfiledMaterialize" else set()
             )
         if r.name == "solver.fit":
@@ -258,11 +258,211 @@ def test_tracing_never_synchronises(tmp_path, monkeypatch):
     jax.effects_barrier()
     ledger.stop_run()
     assert with_ledger == without
-    seen = len(calls)
-    assert ledger.device_wait(x) is x and len(calls) == seen  # unforced: the identity
+    # the program's one way to wait: it returns what it waited for, as a span
+    seen, mark, ones = len(calls), _mark(), jnp.ones(3)
+    assert ledger.device_wait(ones) is ones and len(calls) == seen + 1
+    assert [r.name for r in _since(mark)] == ["device.wait"]
+
+
+@pytest.fixture
+def waits_by_thread(monkeypatch):
+    """``{thread id: waits for the device}``: every ``block_until_ready`` of
+    an array, by method or batched."""
+    from jax._src import api
+
+    waits = collections.Counter()
+    array_type = type(jnp.ones(1))
+    real, real_batched = array_type.block_until_ready, api.xc.batched_block_until_ready
+
+    def method(self):
+        waits[threading.get_ident()] += 1
+        return real(self)
+
+    def batched(arrays):
+        waits[threading.get_ident()] += 1
+        return real_batched(arrays)
+
+    monkeypatch.setattr(array_type, "block_until_ready", method)
+    monkeypatch.setattr(api.xc, "batched_block_until_ready", batched)
+    return waits
+
+
+def test_the_calling_thread_waits_for_nothing_new(waits_by_thread):
+    """A put hands its array to the watcher and goes on; a toy fit with a
+    ``Cacher`` and a toy scoring call wait on the calling thread as often as
+    before the waits had names (4 and 2: counted on the parent commit)."""
+    me = threading.get_ident()
+    rng = np.random.default_rng(0)
+    assert ledger.drain_watches(30)  # earlier tests' puts
+    waits_by_thread.clear()
     mark = _mark()
-    ledger.device_wait(jnp.ones(3), force=True)
-    assert len(calls) == seen + 1 and [r.name for r in _since(mark)] == ["device.wait"]
+    Dataset(rng.normal(size=(32, 8)).astype(np.float32))
+    assert waits_by_thread[me] == 0
+    assert ledger.drain_watches(30)
+    assert [r.name for r in _since(mark)] == ["dataset.upload", "dataset.transfer"]
+    assert sum(waits_by_thread.values()) == 1  # the watcher's
+
+    _cacher_fit(rng)  # mints and prices
+    before = waits_by_thread[me]
+    fitted = _cacher_fit(rng)
+    assert waits_by_thread[me] - before == 4
+    held = rng.normal(size=(16, 24)).astype(np.float32)
+    fitted(Dataset(held)).get().numpy()
+    before = waits_by_thread[me]
+    fitted(Dataset(held)).get().numpy()
+    assert waits_by_thread[me] - before == 2
+
+
+# ------------------------------------------- the waits and the transfers
+def _cacher_fit(rng, n=96, d=24, k=3):
+    """A toy fit whose two branches share a node (the sampling pass has one
+    to place) and whose features stand behind a ``Cacher``."""
+    from keystone_tpu.models import BlockWeightedLeastSquaresEstimator
+    from keystone_tpu.ops import LinearRectifier
+    from keystone_tpu.workflow.transformer import Cacher
+
+    x = Dataset(rng.normal(size=(n, d)).astype(np.float32))
+    y = Dataset(np.where(rng.random((n, k)) < 0.3, 1.0, -1.0).astype(np.float32))
+    features = Pipeline.gather([
+        Pipeline.of(LinearRectifier(0.0)) | LinearRectifier(0.5),
+        Pipeline.of(LinearRectifier(0.0)) | LinearRectifier(1.0),
+    ]) | Cacher()
+    return features.and_then(
+        BlockWeightedLeastSquaresEstimator(block_size=8, num_iter=2, lam=1e-3,
+                                           mixture_weight=0.5), x, y
+    ).fit()
+
+
+def test_a_cachers_sync_is_a_device_wait_under_its_stage():
+    """A ``Cacher``'s sync is one ``device.wait`` under its own
+    ``executor.stage`` (the pipeline's, and the one the rule placed behind the
+    shared node), which the stage's self time leaves out; the sampling pass's
+    syncs are ``device.wait`` spans under ``optimizer.rule``, and the rule's
+    span carries their seconds."""
+    mark = _mark()
+    _cacher_fit(np.random.default_rng(2)).block_until_ready()
+    recs = _since(mark)
+    (fit,) = [r for r in recs if r.name == "pipeline.fit"]
+    tree = {r.span_id: r for r in recs if r.root_id == fit.span_id}
+    waits = [r for r in tree.values() if r.name == "device.wait"]
+    walk = [w for w in waits if tree[w.parent_id].parent_id == fit.span_id]
+    cachers = [r for r in tree.values() if r.name == "executor.stage"
+               and r.parent_id == fit.span_id and r.attrs["node"] == "Cacher"]
+    assert len(cachers) == 2 and [tree[w.parent_id] for w in walk] == cachers
+    own = ledger.self_seconds(list(tree.values()))
+    for stage, sync in zip(cachers, walk):
+        assert own[stage.span_id] == pytest.approx((stage.dur_ns - sync.dur_ns) / 1e9)
+    (rule,) = [r for r in tree.values() if r.name == "optimizer.rule"
+               and r.attrs["rule"] == "ProfiledMaterialize"]
+    assert rule.attrs["sampled"] == 1
+
+    def under_rule(r):
+        while r.parent_id is not None:
+            r = tree[r.parent_id]
+            if r.span_id == rule.span_id:
+                return True
+        return False
+
+    sampled = [w for w in waits if under_rule(w)]
+    assert sampled and len(sampled) + len(walk) == len(waits)
+    assert rule.attrs["waited_seconds"] == pytest.approx(sum(w.dur_ns for w in sampled) / 1e9)
+
+
+def test_a_host_arrays_put_leaves_one_transfer_with_its_real_end():
+    mark = _mark()
+    with ledger.span("caller") as caller:
+        data = Dataset(np.zeros((40, 6, 2), np.uint8))
+    assert ledger.drain_watches(30)
+    recs = _since(mark)
+    (upload,) = [r for r in recs if r.name == "dataset.upload"]
+    (transfer,) = [r for r in recs if r.name == "dataset.transfer"]
+    assert (upload.parent_id, upload.root_id) == (caller.span_id, caller.span_id)
+    assert (transfer.parent_id, transfer.root_id) == (upload.span_id, caller.span_id)
+    assert transfer.t0_ns == upload.t0_ns and transfer.dur_ns > 0
+    assert transfer.attrs == {"bytes": data.array.nbytes, "dtype": "uint8",
+                              "shape": list(data.array.shape)}
+    # an array that is on the device already is no put
+    mark = _mark()
+    Dataset(jnp.ones((8, 2)))
+    assert ledger.drain_watches(30) and not _since(mark)
+
+
+def test_a_deleted_array_ends_its_record_and_raises_nothing():
+    gate = threading.Event()
+
+    class Held:  # the watcher is busy with this one while the next is deleted
+        def block_until_ready(self):
+            gate.wait(30)
+
+    mark = _mark()
+    with ledger.span("dataset.upload", bytes=0) as upload:
+        ledger.watch("dataset.transfer", Held(), upload, bytes=0)
+        gone = jnp.ones(4) + 1
+        ledger.watch("dataset.transfer", gone, upload, bytes=16)
+    gone.delete()
+    gate.set()
+    assert ledger.drain_watches(30)
+    first, second = [r for r in _since(mark) if r.name == "dataset.transfer"]
+    assert first.attrs == {"bytes": 0}
+    assert second.attrs == {"bytes": 16, "outcome": "deleted"}
+    assert ledger._WATCHER.is_alive()
+
+
+def test_many_threads_hand_off_to_one_watcher_and_lose_nothing():
+    """Threads that put at once (serving replicas do) start one watcher
+    between them, and every hand-off closes exactly one record."""
+    threads, each = 32, 40
+    ledger.drain_watches(30)
+    watcher, ledger._WATCHER = ledger._WATCHER, None  # the start is raced too
+    if watcher is not None:
+        ledger._WATCHED.put(None)
+        watcher.join(30)
+    ready = jnp.ones(2).block_until_ready()
+    mark, go = _mark(), threading.Event()
+
+    def work(k):
+        go.wait(30)
+        with ledger.span("dataset.upload", bytes=k) as upload:
+            for i in range(each):
+                ledger.watch("dataset.transfer", ready, upload, bytes=k * each + i)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        pool = [threading.Thread(target=work, args=(k,)) for k in range(threads)]
+        for t in pool:
+            t.start()
+        go.set()
+        for t in pool:
+            t.join(60)
+        assert not any(t.is_alive() for t in pool)
+        assert ledger.drain_watches(60)
+    finally:
+        sys.setswitchinterval(interval)
+    closed = [r for r in _since(mark) if r.name == "dataset.transfer"]
+    assert sorted(r.attrs["bytes"] for r in closed) == list(range(threads * each))
+    assert len({r.span_id for r in closed}) == threads * each
+    uploads = {r.span_id: r for r in _since(mark) if r.name == "dataset.upload"}
+    assert all(uploads[r.parent_id].attrs["bytes"] == r.attrs["bytes"] // each for r in closed)
+    watchers = [t for t in threading.enumerate() if t.name == "keystone-obs-watcher"]
+    assert watchers == [ledger._WATCHER]
+
+
+def test_a_process_with_a_pending_transfer_exits_cleanly():
+    import subprocess
+
+    code = (
+        "import numpy as np\n"
+        "from keystone_tpu.workflow import Dataset\n"
+        "from keystone_tpu.obs import ledger\n"
+        "Dataset(np.zeros((4096, 1024), np.float32))\n"
+        "print(sum(r.name == 'dataset.upload' for r in ledger.recent_spans()))\n"
+    )
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": ROOT}
+    done = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True,
+                          text=True, timeout=120)
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert done.stdout.strip() == "1"
 
 
 # ---------------------------------------------------- the profiler's clock
@@ -270,8 +470,12 @@ def test_profiler_session_holds_the_programs_spans(tmp_path):
     from jax.profiler import ProfileData
 
     _toy_fit(seed=1)  # compiled before the session
+    _cacher_fit(np.random.default_rng(3))
+    assert ledger.drain_watches(30)  # their puts are closed before the session opens
     with jax.profiler.trace(str(tmp_path)):
         _toy_fit(seed=1)
+        _cacher_fit(np.random.default_rng(3)).block_until_ready()
+        assert ledger.drain_watches(30)
     (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
     host = [
         ev for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host")
@@ -279,10 +483,17 @@ def test_profiler_session_holds_the_programs_spans(tmp_path):
     ]
     fits = [ev for ev in host if ev.name == "pipeline.fit"]
     stages = [ev for ev in host if ev.name == "executor.stage"]
-    assert len(fits) == 1 and len(stages) >= 3
-    lo, hi = fits[0].start_ns, fits[0].start_ns + fits[0].duration_ns
+    assert len(fits) == 2 and len(stages) >= 3
+    inside = 0
     for ev in stages:
-        assert lo <= ev.start_ns and ev.start_ns + ev.duration_ns <= hi
+        inside += any(
+            f.start_ns <= ev.start_ns and ev.start_ns + ev.duration_ns <= f.start_ns + f.duration_ns
+            for f in fits
+        )
+    assert inside == len(stages)
+    # the waits on the fit's thread, the four puts' transfers on the watcher's
+    assert len([ev for ev in host if ev.name == "device.wait"]) >= 2
+    assert len([ev for ev in host if ev.name == "dataset.transfer"]) == 4
 
 
 # ------------------------------------------------------------ program names
@@ -465,12 +676,57 @@ def _call_tree(base: int, t0: float):
     ]
 
 
+def _fit_with_waits_and_puts(base: int, t0: float):
+    """``_fit_tree`` with what the host waited for and what the link carried:
+    two puts before the fit, 10 and 7 ms ahead of it, whose transfers take 30
+    and 10 ms (the second inside the first: a union of 30 ms, not 40), a put
+    late in the fit whose transfer closes 4 ms after the fit's root (9 ms); a
+    sync of 8 ms under the walk's first stage and one of 1 ms under the
+    sampling rule's."""
+    fit = base
+    tree = _fit_tree(base, t0)
+    return [
+        _rec(base + 20, None, base + 20, "dataset.upload", t0 - 10, 2, bytes=800),
+        _rec(base + 22, None, base + 22, "dataset.upload", t0 - 7, 1, bytes=80),
+        _rec(base + 23, base + 22, base + 22, "dataset.transfer", t0 - 7, 10, bytes=80),
+        _rec(base + 24, base + 8, fit, "device.wait", t0 + 2.5, 1),
+    ] + tree[:3] + [
+        _rec(base + 21, base + 20, base + 20, "dataset.transfer", t0 - 10, 30, bytes=800),
+        _rec(base + 25, base + 3, fit, "device.wait", t0 + 20, 8),
+    ] + tree[3:-1] + [
+        _rec(base + 26, fit, fit, "dataset.upload", t0 + 95, 1, bytes=8),
+        tree[-1],
+        _rec(base + 27, base + 26, fit, "dataset.transfer", t0 + 95, 9, bytes=8),
+    ]
+
+
+def _call_with_waits_and_puts(base: int, t0: float):
+    """``_call_tree`` whose images take 60 ms to arrive and whose inner put
+    takes 30 ms from 40 ms on (a union of 70 ms), with a ``Cacher``'s sync of
+    25 ms under the apply's stage."""
+    apply, tree = base + 1, _call_tree(base, t0)
+    return tree[:4] + [
+        _rec(base + 8, base + 3, apply, "device.wait", t0 + 45, 25),
+        _rec(base + 6, base, base, "dataset.transfer", t0, 60, bytes=1000),
+        _rec(base + 7, base + 4, apply, "dataset.transfer", t0 + 40, 30, bytes=10),
+    ] + tree[4:]
+
+
 _FIT_RING = (
     _fit_tree(100, 0) + [_rec(150, None, 150, "dataset.upload", 100, 1, bytes=8)]  # set-up
     + _fit_tree(200, 200) + _fit_tree(300, 400)  # the window's two fits
     + _call_tree(400, 600)  # the check's apply, after the window
 )
 _SCORE_RING = _call_tree(100, 0) + _call_tree(200, 300) + _call_tree(300, 600)
+_FIT_RING_NAMED = (
+    _fit_with_waits_and_puts(100, 0) + _fit_with_waits_and_puts(200, 200)
+    + _fit_with_waits_and_puts(300, 400)
+    + _call_tree(400, 600)  # the check's apply: its puts are no fit's, open or closed
+)
+_SCORE_RING_NAMED = (
+    _call_with_waits_and_puts(100, 0) + _call_with_waits_and_puts(200, 300)
+    + _call_with_waits_and_puts(300, 600)
+)
 _TRACE = {
     "devices": 1,
     "module_s": {"jit_fused_PixelScaler_GrayScaler_SIFTExtractor": 0.8,
@@ -491,6 +747,10 @@ READERS = [
     ("lcs_device_us_per_image", _SCORE_RING, _SCORE_COUNTERS, 250.0),
     ("score_optimize_s", _SCORE_RING, _SCORE_COUNTERS, 0.012),
     ("score_upload_host_s", _SCORE_RING, _SCORE_COUNTERS, 0.023),
+    ("upload_transfer_s.score", _SCORE_RING_NAMED, _SCORE_COUNTERS, 0.070),
+    ("upload_transfer_s.fit", _FIT_RING_NAMED, _FIT_COUNTERS, 0.030 + 0.009),
+    ("host_wait_s.score", _SCORE_RING_NAMED, _SCORE_COUNTERS, 0.025),
+    ("host_wait_s.fit", _FIT_RING_NAMED, _FIT_COUNTERS, 0.008 + 0.001),
 ]
 _FROM_TRACE = {"fit_node_launches", "sift_device_us_per_image", "lcs_device_us_per_image"}
 
@@ -530,12 +790,61 @@ def test_reader_finds_nothing_to_read(monkeypatch, metric, ring, counters, want)
     assert read(_ctx(counters)) is None
 
 
+def test_stage_self_time_leaves_the_named_waits_out(monkeypatch):
+    """``fit_stage_host_s`` on the ring with named waits: the first stage's
+    30 ms less its mint (6) and its sync (8), the second's 15."""
+    monkeypatch.setattr(ledger, "_RING",
+                        collections.deque(_FIT_RING_NAMED, maxlen=ledger.RING_SIZE))
+    read = harness.load_reader("fit_stage_host_s").read
+    assert read(_ctx(_FIT_COUNTERS)) == pytest.approx(0.016 + 0.015)
+
+
+@pytest.mark.parametrize("metric,ring,counters", [
+    ("upload_transfer_s.fit", _FIT_RING_NAMED, _FIT_COUNTERS),
+    ("upload_transfer_s.score", _SCORE_RING_NAMED, _SCORE_COUNTERS),
+])
+def test_transfer_reader_reads_nothing_while_a_transfer_is_open(monkeypatch, metric, ring,
+                                                                counters):
+    read = harness.load_reader(metric).read
+    closed = [r for r in ring if r.name == "dataset.transfer"]
+    for missing in (closed[-1:], closed):  # one still under way; a parent commit has none
+        left = [r for r in ring if r not in missing]
+        monkeypatch.setattr(ledger, "_RING", collections.deque(left, maxlen=ledger.RING_SIZE))
+        assert read(_ctx(counters)) is None
+
+
+@pytest.mark.parametrize("metric,ring,counters", [
+    ("host_wait_s.fit", _FIT_RING, _FIT_COUNTERS),
+    ("host_wait_s.score", _SCORE_RING, _SCORE_COUNTERS),
+])
+def test_wait_reader_tells_no_wait_from_no_name_for_it(monkeypatch, metric, ring, counters):
+    monkeypatch.setattr(ledger, "_RING", collections.deque(ring, maxlen=ledger.RING_SIZE))
+    read = harness.load_reader(metric).read
+    assert read(_ctx(counters)) == 0.0  # a fit or call that never stood still
+    monkeypatch.delattr(ledger, "waiting")  # the parent commit: its waits are bare
+    assert read(_ctx(counters)) is None
+
+
 def test_new_metrics_are_declared_with_their_readers():
     bench = harness.load_json(ROOT, "BENCHMARK.json")
     declared = {m["name"]: m for m in bench["per_layer"]}
+    layers = os.path.join(ROOT, "benchmark", "layers")
     for metric, *_ in READERS:
-        assert metric in declared and os.path.isfile(
-            os.path.join(ROOT, "benchmark", "layers", metric + ".py")
-        )
+        # where ``harness.load_reader`` looks: the metric's own file, or for a
+        # quantity split by cells (``<quantity>.<cells>``) the quantity's
+        files = [metric + ".py", metric.rsplit(".", 1)[0] + ".py"]
+        assert metric in declared and any(os.path.isfile(os.path.join(layers, f)) for f in files)
+        assert harness.load_reader(metric).read is not None
     assert declared["score_upload_host_s"]["layer"] == "host link"
     assert declared["sift_device_us_per_image"]["unit"] == "us"
+    fit_cells = ["imagenet-fv.fit-given-vocab", "timit-rf.fit", "timit-krr.fit", "cifar-rp.fit"]
+    for quantity, layer in (("upload_transfer_s", "host link"),
+                            ("host_wait_s", "executor / transformer")):
+        assert os.path.isfile(os.path.join(layers, quantity + ".py"))
+        fit, score = declared[quantity + ".fit"], declared[quantity + ".score"]
+        assert (fit["moves"], fit["workloads"]) == ("fit_s", fit_cells)
+        assert (score["moves"], score["workloads"]) == (
+            "score_images_per_s", ["imagenet-fv.score-bulk"])
+        for m in (fit, score):
+            assert (m["layer"], m["unit"], m["better"], m["source"]) == (
+                layer, "s", "lower", "program_span")

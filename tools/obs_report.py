@@ -17,7 +17,9 @@ Sections (each only when the run recorded it):
   spans); for the sampling rule also how its passes went, summed —
   ``to_place`` (shared nodes with no barrier yet), ``sampled`` (passes
   that sliced the input and ran a sample), ``priced`` (programs compiled
-  to price a node) and ``price_hits`` (prices from the memo); and what
+  to price a node), ``price_hits`` (prices from the memo) and
+  ``waited_seconds`` (the sampled nodes' ``device.wait`` seconds: the host
+  stood still that long of the rule's seconds); and what
   the nodes' signatures cost inside the
   ``pipeline.optimize`` spans: bytes of weights copied to the host to be
   digested (``sig_bytes_hashed``) and nodes that signed with the recipe
@@ -163,6 +165,10 @@ def summarize(path: str, top_k: int = 10) -> dict:
             for key in _PASS_ATTRS:  # how the sampling rule's passes went
                 if key in attrs:
                     st[key] = st.get(key, 0) + int(attrs[key] or 0)
+            if "waited_seconds" in attrs:
+                st["waited_seconds"] = st.get("waited_seconds", 0.0) + float(
+                    attrs["waited_seconds"] or 0.0
+                )
         elif e.get("name") == "pipeline.optimize" and "sig_bytes_hashed" in attrs:
             signatures["optimizes"] = signatures.get("optimizes", 0) + 1
             for key in ("sig_bytes_hashed", "sig_by_recipe"):
@@ -231,9 +237,9 @@ def summarize(path: str, top_k: int = 10) -> dict:
     # -------------------------------------------------------- dataflow
     # the fit-path dataflow accounts (host-side measures): seconds the
     # host spent BLOCKED on device results at the waits the program needs
-    # anyway (ledger.device_wait(force=True): flow control, checkpoint
-    # gathers — observing adds no wait) vs blocked on host→device staging
-    # (blockstore.iter_device_blocks).  The fraction is the blocked share
+    # anyway (ledger.device_wait: flow control, checkpoint gathers, a
+    # Cacher's and the sampling pass's sync — observing adds no wait) vs
+    # blocked on host→device staging (blockstore.iter_device_blocks).  The fraction is the blocked share
     # of the ledger's wall time; the DEVICE's busy and idle share come
     # from a device trace (the benchmark's device_idle_pct.*), not from
     # here.
@@ -449,6 +455,8 @@ def render(summary: dict) -> str:
             summary["optimizer"].items(), key=lambda kv: -kv[1]["seconds"]
         ):
             how = [f"{k}={st[k]}" for k in _PASS_ATTRS if k in st]
+            if "waited_seconds" in st:
+                how.append(f"waited_seconds={st['waited_seconds']:.4f}")
             out.append("  ".join([f"  {st['seconds']:>9.3f}  {st['count']:>4}  {rule}"] + how))
         sg = summary.get("signatures")
         if sg:
