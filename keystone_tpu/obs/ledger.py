@@ -127,6 +127,7 @@ ATTR_VOCABULARY = {
     "canary_fraction",
     "checkpoint_save_seconds",
     "chunk_seconds",
+    "chunks",
     "d",
     "degraded",
     "depth",
@@ -794,7 +795,10 @@ def solver_epoch(solver: str, **series) -> None:
 
 def fold_stage_spans(ledger_path: str) -> Dict[str, dict]:
     """Aggregate a ledger's ``executor.stage`` span_end lines into
-    ``{key: {seconds, count, retries, failed_attempt_seconds}}``.
+    ``{key: {seconds, count, retries, failed_attempt_seconds, chunks}}``
+    (``chunks``: the applies the node's stages were made of, summed — equal
+    to ``count`` where every apply was whole; 0 for stages that apply no
+    transformer to a device array).
 
     The ONE reader of this part of the schema — ``tools/obs_report.py``
     and ``workflow/viz.ledger_overlay`` both fold through here, so a
@@ -826,6 +830,7 @@ def fold_stage_spans(ledger_path: str) -> Dict[str, dict]:
                     "count": 0,
                     "retries": 0,
                     "failed_attempt_seconds": 0.0,
+                    "chunks": 0,
                 },
             )
             st["seconds"] += float(e.get("seconds") or 0.0)
@@ -834,6 +839,7 @@ def fold_stage_spans(ledger_path: str) -> Dict[str, dict]:
             st["failed_attempt_seconds"] += float(
                 attrs.get("failed_attempt_seconds") or 0.0
             )
+            st["chunks"] += int(attrs.get("chunks") or 0)
     return out
 
 

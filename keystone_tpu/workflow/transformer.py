@@ -211,39 +211,49 @@ def program_share_key(t: "Transformer"):
     return share_key(t)
 
 
-#: canonical apply chunk (rows); 0 = whole-batch applies.
-#: Chunking pins the compiled programs' shapes so they stop scaling
-#: with dataset size.  DEFAULT ON since r5, decided by program COUNT
-#: (round-4 review item 4 — wall clock drifted too much to decide it;
-#: rounds 1–5, not re-measured): at a NEW dataset size n=8192, the
-#: chunked fit ran 88/88 programs from the persistent compile cache
-#: (ZERO cold compiles; wall 44.6 s → 11.5 s) where the unchunked fit
-#: paid 9 cold full-shape compiles; n=4096 cold-shape: 29 (one-time
-#: chunk plumbing) vs 46 misses and 79.5 s → 50.7 s.  The warm bench-fit path
-#: (n=2048 ≤ chunk) takes the whole-batch branch and is unaffected.
-#: Bit-parity with whole-batch applies is pinned by
-#: tests/test_workflow.py; multi-device meshes still disable chunking
-#: (per-chunk resharding collectives — see _apply_chunk_rows).
+#: The chunk rule (``_chunk_rows_for``): how many rows of its input a
+#: device apply takes at a time.  It reads the input's shape and bytes
+#: and nothing else, and it is asked once per apply.
+#:
+#: 1. An input within ``_APPLY_CHUNK_BYTES`` — what one chunk's input may
+#:    be anyway — is ONE chunk: one program over the whole array, no
+#:    slice, no pad, no concatenate.  (8192 frames of 440 floats are
+#:    14 MB; cut into four 2048-row applies by each of sixteen cosine
+#:    nodes they were 144 launches a fit more, with the chip idle while
+#:    the host made them: my chip runs, PR 35.  A label vector is one
+#:    apply.)
+#: 2. A larger input is cut into chunks of ``_APPLY_CHUNK_DEFAULT`` rows,
+#:    the ragged tail padded up, so that the compiled programs' shapes
+#:    stop scaling with the dataset's size and a heavy item (an image's
+#:    SIFT descriptors are 0.4 MB) bounds the program's memory.  On by
+#:    default since r5, decided by program COUNT (rounds 1–5, not
+#:    re-measured): at a new n = 8192 the chunked fit ran 88 of 88
+#:    programs from the persistent compile cache where the unchunked one
+#:    paid 9 cold full-shape compiles.  Bit-parity with whole-batch
+#:    applies is pinned by tests/test_workflow.py.
+#: 3. A LONG dataset of NARROW rows takes larger chunks: the chunk
+#:    doubles while more than ``_APPLY_MAX_CHUNKS`` chunks remain and the
+#:    chunk's input stays within ``_APPLY_CHUNK_BYTES`` (so the shapes
+#:    compiled grow with log2 n, and items already heavy at the canonical
+#:    chunk — images, descriptor sets, Fisher vectors — never grow).
+#:    196,608 rows of 440 floats are 12 chunks of 16,384 where 96 of
+#:    2048, for each of two nodes, were 0.20 s of host time a fit with
+#:    the chip idle (my chip runs, PR 25).
+#: 4. No chunk where its outputs could not exist.  A chunked apply holds
+#:    its chunks' outputs beside their concatenation, twice the output:
+#:    an input over ``_APPLY_WHOLE_BYTES``, a quarter of a 16 GB device,
+#:    is applied whole (80,000 features of 16,384 rows are 5.2 GB; their
+#:    scaled copy in eight chunks and again in one piece would be 10.5 GB
+#:    beside them), and so is the input of a node that tiles its rows
+#:    itself (``Transformer.owns_tiling``).  The largest input a benchmark
+#:    cell chunks is 1.6 GB (4096 images' SIFT descriptors).
+#: 5. No chunk on a data mesh of more than one device (a row slice of a
+#:    sharded array pays resharding collectives per chunk, and per-shard
+#:    shapes are already smaller; ``_apply_chunk_rows``), and a forced
+#:    ``KEYSTONE_APPLY_CHUNK`` is taken as it is, whatever the input.
 _APPLY_CHUNK_DEFAULT = 2048
-#: A long dataset of NARROW rows takes larger chunks: the canonical
-#: chunk doubles while more than ``_APPLY_MAX_CHUNKS`` chunks remain and
-#: the chunk's input stays within ``_APPLY_CHUNK_BYTES`` (so the shapes
-#: compiled grow with log2 n, and items that are already heavy at the
-#: canonical chunk — images, descriptor sets, Fisher vectors — never
-#: grow).  At n = 196,608 rows of 440 floats the 2048-row loop was 192
-#: chunk applies of two nodes, 0.20 s of host time a fit with the chip
-#: idle (0.25–0.29 s beside busy neighbours); at 16,384 rows it is 24
-#: (my chip runs, PR 25).  Up to 32,768 rows nothing changes.
 _APPLY_MAX_CHUNKS = 16
 _APPLY_CHUNK_BYTES = 32 << 20
-#: A chunked apply holds its chunks' outputs beside their concatenation:
-#: twice the output.  No chunk is offered where that cannot exist — to a
-#: dataset that is itself over a quarter of a 16 GB device (80,000
-#: features of 16,384 rows are 5.2 GB; their scaled copy in eight chunks
-#: and again in one piece would be 10.5 GB beside them), nor to a node
-#: that tiles its own input (``Transformer.owns_tiling``).  Such an apply
-#: is one program over the whole array.  The largest input a benchmark
-#: cell chunks is 1.6 GB (4096 images' SIFT descriptors).
 _APPLY_WHOLE_BYTES = 4 << 30
 
 
@@ -286,16 +296,18 @@ def _apply_chunk_rows() -> int:
 
 
 def _chunk_rows_for(arr) -> int:
-    """Row-chunk size for a device apply of ``arr``; 0 disables.  The
-    default chunk grows for long datasets of narrow rows (see
-    ``_APPLY_MAX_CHUNKS``); a forced ``KEYSTONE_APPLY_CHUNK`` is taken
+    """Row-chunk size for a device apply of ``arr``; 0 = one program over
+    the whole array.  The rule is stated above ``_APPLY_CHUNK_DEFAULT``:
+    whole where the input is within one chunk's bytes or over what a
+    chunked apply can hold, else the canonical chunk, grown for long
+    datasets of narrow rows; a forced ``KEYSTONE_APPLY_CHUNK`` is taken
     as it is."""
     import os
 
     chunk = _apply_chunk_rows()
     if not chunk or os.environ.get("KEYSTONE_APPLY_CHUNK", "").strip():
         return chunk
-    if arr.nbytes > _APPLY_WHOLE_BYTES:
+    if arr.nbytes <= _APPLY_CHUNK_BYTES or arr.nbytes > _APPLY_WHOLE_BYTES:
         return 0
     n = arr.shape[0]
     row_bytes = arr.nbytes // max(1, n)
@@ -494,8 +506,14 @@ class Transformer(Chainable):
             base, stages = getattr(ds, "_host_chain", None) or (ds, ())
             res._host_chain = (base, stages + (self,))
             return res
+        from keystone_tpu.obs import ledger
+
         chunk = 0 if self.owns_tiling else _chunk_rows_for(ds.array)
-        if chunk and ds.array.shape[0] > chunk:
+        n = ds.array.shape[0]
+        chunked = bool(chunk) and n > chunk
+        # the applies this node's stage is made of: 1 = whole
+        ledger.annotate("executor.stage", chunks=-(-n // chunk) if chunked else 1)
+        if chunked:
             return self._apply_dataset_chunked(ds, chunk)
         result = self._apply_batch_jitted(ds.array, ds.mask)
         if isinstance(result, tuple):  # (values, mask) for ragged producers

@@ -219,10 +219,18 @@ class _Rows:
 @pytest.mark.parametrize("n,row_bytes,want", [
     (4096, 3 * 128 * 128, 2048),        # the ImageNet cells' images
     (4096, 784 * 128 * 4, 2048),        # their SIFT descriptors, 1.6 GB
-    (8192, 440 * 4, 2048),              # timit-rf.fit
+    (8192, 440 * 4, 0),                 # timit-rf.fit: 14 MB fit in one chunk, so they are one
     (196608, 440 * 4, 16384),           # timit-krr.fit: long and narrow
     (16384, 3072, 2048),                # CIFAR images
     (16384, 80000 * 4, 0),              # their 5.2 GB of features: no chunk
+    (4096, 4, 0),                       # int32 labels: the ImageNet cells'
+    (16384, 4, 0),                      # ... CIFAR's
+    (196608, 4, 0),                     # ... timit-krr.fit's, 0.8 MB
+    (8192, 4096, 0),                    # exactly one chunk's bytes: whole
+    (8193, 4096, 2048),                 # a row over them: as before
+    (1, (32 << 20) + 1, 2048),          # a byte over them: as before
+    (65536, 512, 0),                    # long and narrow, and still one chunk's bytes
+    (65537, 512, 8192),                 # a row over: the grown chunk, as before
 ])
 def test_the_chunk_rule_offers_no_chunk_whose_output_cannot_exist(n, row_bytes, want, monkeypatch):
     monkeypatch.setattr(tr, "_apply_chunk_rows", lambda: tr._APPLY_CHUNK_DEFAULT)

@@ -21,7 +21,7 @@ sys.path.insert(0, ROOT)
 
 from benchmark import compile_log, harness  # noqa: E402
 from keystone_tpu.obs import ledger  # noqa: E402
-from keystone_tpu.workflow import Dataset, Pipeline  # noqa: E402
+from keystone_tpu.workflow import Dataset, Pipeline, Transformer  # noqa: E402
 
 pytestmark = pytest.mark.obs
 
@@ -368,6 +368,106 @@ def test_a_cachers_sync_is_a_device_wait_under_its_stage():
     assert rule.attrs["waited_seconds"] == pytest.approx(sum(w.dur_ns for w in sampled) / 1e9)
 
 
+# ------------------------------------------------- the stage's ``chunks`` attr
+class AddConst(Transformer):
+    def __init__(self, c):
+        self.c = float(c)
+
+    def params(self):
+        return (self.c,)
+
+    def apply_batch(self, xs, mask=None):
+        return xs + self.c
+
+
+def _timit_rehearsal_fit(seed=7):
+    """``timit-rf.fit``'s own graph (the adapter's) at the cell's rehearsal
+    sizes — 256 frames of 24 floats, three cosine blocks of 32 — fitted
+    once; (a function that scores the held-out rows through the fitted
+    pipeline's own call, the spans the fit left, n)."""
+    _, cell, cfg = harness.find_cell("timit-rf.fit")
+    cfg.update(cell["rehearse"]["config"])
+    cell.update(cell["rehearse"]["cell"])
+    adapter = harness.load_module("adapters", cfg["adapter"])
+    fit_loop = harness.load_module("drivers", cell["kind"])
+    data = adapter.make_data(cfg, cell, seed)
+    x, labels = adapter.fit_inputs(data, cell, 0)
+    mark = _mark()
+    fitted = adapter.build(cfg, cell, seed, *fit_loop.upload(x, labels, "chunks")).fit()
+    fitted.block_until_ready()
+    records = _since(mark)
+    return lambda: adapter.held_out_answers(fitted, data["held_x"]), records, x.shape[0]
+
+
+def _applies_by_class(monkeypatch):
+    T = sys.modules["keystone_tpu.workflow.transformer"]
+    calls = collections.Counter()
+    real = T.Transformer._apply_batch_jitted
+
+    def counted(self, xs, mask):
+        calls[type(self).__name__] += 1
+        return real(self, xs, mask)
+
+    monkeypatch.setattr(T.Transformer, "_apply_batch_jitted", counted)
+    return calls
+
+
+def test_an_input_that_fits_in_one_chunk_is_one_apply_a_node(monkeypatch):
+    """The cell's frames are within one chunk's bytes, so each of its nodes
+    is ONE program over all of them though n is four canonical chunks: one
+    ``_apply_batch_jitted`` a ``CosineRandomFeatures`` node, ``chunks`` 1 on
+    every stage that applies a node; and the fit predicts what the same fit
+    cut into canonical chunks (``KEYSTONE_APPLY_CHUNK`` forces one) does."""
+    T = sys.modules["keystone_tpu.workflow.transformer"]
+    monkeypatch.delenv("KEYSTONE_APPLY_CHUNK", raising=False)
+    monkeypatch.setattr(T, "_apply_chunk_rows", lambda: 64)
+    calls = _applies_by_class(monkeypatch)
+    score, records, n = _timit_rehearsal_fit()
+    assert n == 256 > 64 and n * 24 * 4 <= T._APPLY_CHUNK_BYTES
+    (fit,) = [r for r in records if r.name == "pipeline.fit"]
+    stages = [r for r in records if r.name == "executor.stage" and r.root_id == fit.span_id]
+    cosine = [r for r in stages if r.attrs["node"] == "CosineRandomFeatures"]
+    assert len(cosine) == 3 and calls["CosineRandomFeatures"] == 3
+    applied = [r for r in stages if "chunks" in r.attrs]
+    assert len(applied) > len(cosine) and all(r.attrs["chunks"] == 1 for r in applied)
+    whole = score()
+    x = np.random.default_rng(3).normal(size=(205, 6)).astype(np.float32)
+    added = np.asarray(AddConst(1.5).apply_dataset(Dataset(x)).array)
+
+    calls.clear()
+    monkeypatch.setenv("KEYSTONE_APPLY_CHUNK", "64")
+    score, records, _ = _timit_rehearsal_fit()
+    cosine = [r for r in records if r.name == "executor.stage"
+              and r.attrs["node"] == "CosineRandomFeatures" and r.attrs.get("chunks")]
+    assert [r.attrs["chunks"] for r in cosine] == [4, 4, 4]
+    assert calls["CosineRandomFeatures"] == 12
+    cut = score()
+    np.testing.assert_allclose(whole, cut, rtol=1e-4, atol=1e-4 * float(np.std(cut)))
+    np.testing.assert_array_equal(
+        added, np.asarray(AddConst(1.5).apply_dataset(Dataset(x)).array))
+
+
+def test_an_input_over_one_chunks_bytes_still_reports_its_chunks(monkeypatch):
+    T = sys.modules["keystone_tpu.workflow.transformer"]
+    monkeypatch.delenv("KEYSTONE_APPLY_CHUNK", raising=False)
+    monkeypatch.setattr(T, "_apply_chunk_rows", lambda: 64)
+    monkeypatch.setattr(T, "_APPLY_CHUNK_BYTES", 205 * 6 * 4 - 1)  # the input, less a byte
+    calls = _applies_by_class(monkeypatch)
+    x = np.random.default_rng(5).normal(size=(205, 6)).astype(np.float32)
+    mark = _mark()
+    out = Pipeline.of(AddConst(1.5))(Dataset(x, shard=False)).get().numpy()
+    (stage,) = [r for r in _since(mark) if r.name == "executor.stage"
+                and r.attrs["node"] == "AddConst"]
+    assert stage.attrs["chunks"] == -(-205 // 64) == 4 == calls["AddConst"]
+    np.testing.assert_array_equal(out, x + np.float32(1.5))
+    monkeypatch.setattr(T, "_APPLY_CHUNK_BYTES", 205 * 6 * 4)
+    mark = _mark()
+    Pipeline.of(AddConst(1.5))(Dataset(x, shard=False)).get().numpy()
+    (stage,) = [r for r in _since(mark) if r.name == "executor.stage"
+                and r.attrs["node"] == "AddConst"]
+    assert stage.attrs["chunks"] == 1 and calls["AddConst"] == 5
+
+
 def test_a_host_arrays_put_leaves_one_transfer_with_its_real_end():
     mark = _mark()
     with ledger.span("caller") as caller:
@@ -636,6 +736,29 @@ def test_obs_report_prints_how_the_sampling_rules_passes_went(tmp_path):
     assert "to_place=1  sampled=1" in line
     priced, hits = (int(line.split(f"{k}=")[1].split()[0]) for k in ("priced", "price_hits"))
     assert priced + hits == 1
+
+
+def test_obs_report_prints_a_stages_chunks_beside_its_seconds(tmp_path, monkeypatch):
+    from tools.obs_report import render, summarize
+
+    T = sys.modules["keystone_tpu.workflow.transformer"]
+    monkeypatch.delenv("KEYSTONE_APPLY_CHUNK", raising=False)
+    monkeypatch.setattr(T, "_apply_chunk_rows", lambda: 8)
+    x = np.random.default_rng(0).normal(size=(30, 6)).astype(np.float32)
+    run = ledger.start_run(str(tmp_path))
+    try:
+        Pipeline.of(AddConst(0.5))(Dataset(x, shard=False)).get()  # within a chunk's bytes
+        monkeypatch.setattr(T, "_APPLY_CHUNK_BYTES", 64)
+        Pipeline.of(AddConst(0.5))(Dataset(x, shard=False)).get()  # over them: four of 8 rows
+    finally:
+        ledger.stop_run()
+    summary = summarize(run.path)
+    (stage,) = [st for st in summary["stage_top"] if st["node"] == "AddConst"]
+    assert (stage["count"], stage["chunks"]) == (2, 1 + 4)
+    lines = render(summary).splitlines()
+    head = next(i for i, ln in enumerate(lines) if ln.split()[:3] == ["seconds", "chunks", "runs"])
+    row = next(ln for ln in lines[head + 1:] if ln.split()[-1] == "AddConst")
+    assert row.split()[1:3] == ["5", "2"]
 
 
 # ---------------------------------------------------------------- the readers

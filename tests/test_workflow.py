@@ -312,13 +312,16 @@ def test_chunked_apply_ragged_producer_and_sampler(monkeypatch):
         (205, 6, 16),  # narrow rows: doubled until 16 chunks or fewer remain
         (205, 32, 8),  # ... and no further than the chunk's bytes allow
         (205, 64, 4),  # rows already heavy at the canonical chunk never grow
+        (40, 6, 0),  # the whole input is within one chunk's bytes: one apply
     ],
 )
 def test_default_chunk_grows_for_long_narrow_datasets(monkeypatch, n, row_floats, want):
     """The default chunk doubles for a long dataset of narrow rows (the
     host loop of 2048-row applies idled the chip for a tenth of a
-    196,608-row fit), bounded by the chunk's bytes; the applies stay
-    bit-identical to the whole batch, and a forced chunk is taken as is."""
+    196,608-row fit), bounded by the chunk's bytes; an input that is
+    itself within those bytes is never cut (``iter_row_chunks`` not
+    called); the applies stay bit-identical to the whole batch, and a
+    forced chunk is taken as is."""
     import importlib
 
     tr = importlib.import_module("keystone_tpu.workflow.transformer")
@@ -334,7 +337,7 @@ def test_default_chunk_grows_for_long_narrow_datasets(monkeypatch, n, row_floats
         tr, "iter_row_chunks", lambda a, m, c: seen.append(c) or real(a, m, c)
     )
     out = AddConst(1.5).apply_dataset(ds)
-    assert seen == ([want] if n > want else [])
+    assert seen == ([want] if 0 < want < n else [])
     np.testing.assert_array_equal(np.asarray(out.array)[:n], x + np.float32(1.5))
     monkeypatch.setenv("KEYSTONE_APPLY_CHUNK", "4")
     assert tr._chunk_rows_for(ds.array) == 4

@@ -12,7 +12,10 @@ operator actually asks::
 Sections (each only when the run recorded it):
 
 - **stages**: top executor stages by total span seconds, with attempt /
-  retry counts and failed-attempt time;
+  retry counts, failed-attempt time and ``chunks`` — the applies the
+  stage's node made of its input, summed over its runs (equal to the runs
+  where every apply was one program over the whole array; more where the
+  chunk rule, ``workflow/transformer.py § _chunk_rows_for``, cut it);
 - **optimizer**: seconds and runs per optimizer rule (``optimizer.rule``
   spans); for the sampling rule also how its passes went, summed —
   ``to_place`` (shared nodes with no barrier yet), ``sampled`` (passes
@@ -146,6 +149,7 @@ def summarize(path: str, top_k: int = 10) -> dict:
             "count": st["count"],
             "retries": st["retries"],
             "failed_attempt_seconds": st["failed_attempt_seconds"],
+            "chunks": st["chunks"],
         }
         for st in top
     ]
@@ -439,12 +443,12 @@ def render(summary: dict) -> str:
     if summary.get("stage_top"):
         out.append("\n== top stages by time ==")
         out.append(
-            f"  {'seconds':>9}  {'runs':>4}  {'retries':>7}  "
+            f"  {'seconds':>9}  {'chunks':>6}  {'runs':>4}  {'retries':>7}  "
             f"{'failed_s':>8}  stage"
         )
         for st in summary["stage_top"]:
             out.append(
-                f"  {st['seconds']:>9.3f}  {st['count']:>4}  "
+                f"  {st['seconds']:>9.3f}  {st['chunks']:>6}  {st['count']:>4}  "
                 f"{st['retries']:>7}  {st['failed_attempt_seconds']:>8.3f}  "
                 f"{st['node']}"
             )
