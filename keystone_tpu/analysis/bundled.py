@@ -21,7 +21,7 @@ def _mnist():
     from keystone_tpu.loaders.mnist import MnistLoader
     from keystone_tpu.pipelines.mnist_random_fft import MnistRandomFFT
 
-    cfg = MnistRandomFFT.Config(num_ffts=2, synthetic_n=128)
+    cfg = MnistRandomFFT.Config(num_ffts=2, block_size=1024, synthetic_n=128)
     train = MnistLoader.synthetic(cfg.synthetic_n, seed=1)
     return MnistRandomFFT.build(cfg, train.data, train.labels), train.data
 

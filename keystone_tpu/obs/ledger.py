@@ -119,6 +119,7 @@ ATTR_VOCABULARY = {
     "batch",
     "block_size",
     "blocks",
+    "branches",
     "bucket",
     "budget_bytes",
     "budget_seconds",

@@ -7,6 +7,7 @@ compile into one XLA stage.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Callable, Optional
 
 import jax
@@ -89,6 +90,15 @@ class CosineRandomFeatures(Transformer):
         return jnp.cos(self.w @ x + self.b)
 
 
+@partial(jax.jit, static_argnums=(1,))
+def _draw_signs(seed, num_features: int):
+    """``RandomSignNode``'s signs as one program: the key, the draw and the
+    map to +-1 (eager, these were five launches a node, about a thousand in
+    the build of a 200-branch MnistRandomFFT)."""
+    bits = jax.random.bernoulli(jax.random.PRNGKey(seed), 0.5, (num_features,))
+    return bits.astype(jnp.float32) * 2.0 - 1.0
+
+
 class RandomSignNode(Transformer):
     """Elementwise Rademacher sign flip (nodes/stats/RandomSignNode.scala);
     paired with PaddedFFT for fastfood-style random features.
@@ -103,9 +113,11 @@ class RandomSignNode(Transformer):
 
     @classmethod
     def init(cls, num_features: int, seed: int = 0) -> "RandomSignNode":
-        bits = jax.random.bernoulli(jax.random.PRNGKey(seed), 0.5, (num_features,))
-        node = cls(bits.astype(jnp.float32) * 2.0 - 1.0)
-        # ``draw`` names the lines above: change it with them
+        # one program for every seed (its argument, an int32)
+        if not -(2**31) <= seed < 2**31:
+            raise ValueError(f"RandomSignNode seed {seed} does not fit in 32 bits")
+        node = cls(_draw_signs(seed, num_features))
+        # ``draw`` names the lines of ``_draw_signs``: change it with them
         pin_recipe(
             node, "_fp", (node.signs,), draw="2*bernoulli(PRNGKey(seed),0.5)-1",
             seed=int(seed),
@@ -123,15 +135,16 @@ class RandomSignNode(Transformer):
 
 
 class PaddedFFT(Transformer):
-    """Zero-pad to the next power of two and take a real FFT
-    (nodes/stats/PaddedFFT.scala — MNIST's featurizer).
+    """Zero-pad to the next power of two and keep the real parts of the
+    first half of the Fourier transform (nodes/stats/PaddedFFT.scala —
+    MNIST's featurizer): ``padded // 2`` numbers, 512 for MNIST's 784
+    pixels, as upstream emits them.
 
-    Output = [Re(rfft), Im(rfft)] of the positive-frequency half (the
-    reference emits the complex spectrum's components as a real vector;
-    concatenation keeps full information with static shapes).  The FFT is
-    unitary (norm="ortho") so feature magnitudes stay at the input's
-    scale — important for the f32 normal-equation solvers downstream
-    (the f64-everywhere reference didn't need this).
+    The transform is unitary (``norm="ortho"``): every feature is upstream's
+    divided by ``sqrt(padded)``, one constant for all of them, which a
+    least-squares solve downstream takes as a rescaled lambda; it keeps
+    feature magnitudes at the input's scale for the float32 solvers (the
+    f64-everywhere reference didn't need this).
     """
 
     def params(self):
@@ -142,7 +155,7 @@ class PaddedFFT(Transformer):
         padded = 1 << (d - 1).bit_length()
         xs = jnp.pad(xs, [(0, 0)] * (xs.ndim - 1) + [(0, padded - d)])
         spec = jnp.fft.rfft(xs, axis=-1, norm="ortho")
-        return jnp.concatenate([jnp.real(spec), jnp.imag(spec)], axis=-1)
+        return jnp.real(spec[..., : padded // 2])
 
     def apply_one(self, x):
         return self.apply_batch(x[None])[0]
@@ -453,10 +466,7 @@ class ColumnSampler(Transformer):
         raise TypeError("ColumnSampler operates on datasets")
 
 
-from functools import partial as _partial
-
-
-@_partial(jax.jit, static_argnames=("k", "n_true"))
+@partial(jax.jit, static_argnames=("k", "n_true"))
 def _sample_descriptors_flat(arr, mask, k, seed, n_true):
     """In-memory sampler fast path: mask default, PRNG key derivation,
     sampling, true-row slice, and the flat reshape fused into one jit
@@ -468,7 +478,7 @@ def _sample_descriptors_flat(arr, mask, k, seed, n_true):
     return out[:n_true].reshape(n_true * k, arr.shape[-1])
 
 
-@_partial(jax.jit, static_argnames=("k",))
+@partial(jax.jit, static_argnames=("k",))
 def _sample_descriptors(arr, mask, k, key, offset=0):
     n, max_k, d = arr.shape
     # Per-item keys fold in the GLOBAL item index (offset for stream

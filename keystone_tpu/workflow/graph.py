@@ -269,18 +269,29 @@ class Graph:
 
     # ---------------------------------------------------------- analysis
     def dependents(self, target: GraphId) -> Tuple[GraphId, ...]:
-        out = [n for n, ds in self.dependencies.items() if target in ds]
-        out += [k for k, d in self.sink_dependencies.items() if d == target]
-        return tuple(out)
+        """The nodes, then the sinks, that read ``target``.  From an index
+        of every edge made on the first call: a graph is never edited in
+        place (every edit above returns a new one), and a scan of all edges
+        per call made the passes over a 600-node gather quadratic."""
+        index = self.__dict__.get("_dependents")
+        if index is None:
+            index = {}
+            for n, ds in self.dependencies.items():
+                for d in dict.fromkeys(ds):
+                    index.setdefault(d, []).append(n)
+            for k, d in self.sink_dependencies.items():
+                index.setdefault(d, []).append(k)
+            self._dependents = index
+        return tuple(index.get(target, ()))
 
     def ancestors(self, target: GraphId) -> Tuple[GraphId, ...]:
-        seen = []
+        seen: Dict[GraphId, None] = {}  # insertion-ordered set
 
         def walk(i):
             if isinstance(i, NodeId):
                 for d in self.dependencies[i]:
                     if d not in seen:
-                        seen.append(d)
+                        seen[d] = None
                         walk(d)
 
         walk(target)

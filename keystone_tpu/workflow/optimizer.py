@@ -432,41 +432,54 @@ class FusedTransformer(Transformer):
 
 
 class StageFusionRule(Rule):
-    """Fuse consecutive single-consumer device TransformerOperators."""
+    """Fuse consecutive single-consumer device TransformerOperators: each
+    maximal chain becomes one node, at the place of its last, in one pass
+    over the graph (a 200-branch gather has 600 nodes; fusing pair by pair,
+    each pair a new scan for consumers, took 47 s of a fit)."""
 
     name = "StageFusion"
 
     def apply(self, graph: G.Graph) -> G.Graph:
-        changed = True
-        while changed:
-            changed = False
-            for n in graph.topological_nodes():
-                op = graph.operators.get(n)
-                if not _fusable(op):
-                    continue
-                deps_on_n = graph.dependents(n)
-                if len(deps_on_n) != 1 or isinstance(deps_on_n[0], G.SinkId):
-                    continue
-                m = deps_on_n[0]
-                mop = graph.operators.get(m)
-                if not _fusable(mop) or graph.dependencies[m] != (n,):
-                    continue
-                stages = _stages(op) + _stages(mop)
-                fused_op = G.TransformerOperator(FusedTransformer(stages))
-                # the fused node's OUTPUT is m's output: if the cache rule
-                # flagged m over-HBM-budget (no_memoize → recompute per
-                # consumer), the fused replacement must carry the flag or
-                # the executor pins the very output the device can't
-                # afford.  (n's flag needs no propagation: fusing a
-                # single-consumer n eliminates its output entirely.)
-                if getattr(mop, "no_memoize", False):
-                    fused_op.no_memoize = True
-                graph = graph.set_operator(m, fused_op)
-                graph = graph.set_dependencies(m, graph.dependencies[n])
-                graph = graph.remove_node(n)
-                changed = True
-                break
-        return graph
+        ops, deps = dict(graph.operators), dict(graph.dependencies)
+        consumers: dict = {}
+        for n, ds in deps.items():
+            for d in set(ds):
+                consumers.setdefault(d, []).append(n)
+        for d in graph.sink_dependencies.values():
+            consumers.setdefault(d, []).append(None)  # a sink: never fused into
+
+        def successor(n):
+            """The node ``n`` fuses into, or None."""
+            after = consumers.get(n, ())
+            if not _fusable(ops[n]) or len(after) != 1 or after[0] is None:
+                return None
+            m = after[0]
+            return m if _fusable(ops[m]) and deps[m] == (n,) else None
+
+        nxt = {n: successor(n) for n in graph.topological_nodes()}
+        inner = set(nxt.values())
+        for first in nxt:
+            if first in inner or nxt[first] is None:
+                continue  # not the head of a chain of two or more
+            chain = [first]
+            while nxt[chain[-1]] is not None:
+                chain.append(nxt[chain[-1]])
+            last = ops[chain[-1]]
+            fused_op = G.TransformerOperator(
+                FusedTransformer([s for n in chain for s in _stages(ops[n])])
+            )
+            # the fused node's OUTPUT is the last node's: if the cache rule
+            # flagged it over-HBM-budget (no_memoize → recompute per
+            # consumer), the fused replacement must carry the flag or the
+            # executor pins the very output the device can't afford.  (The
+            # others' flags need no propagation: fusing a single-consumer
+            # node eliminates its output entirely.)
+            if getattr(last, "no_memoize", False):
+                fused_op.no_memoize = True
+            ops[chain[-1]], deps[chain[-1]] = fused_op, deps[first]
+            for n in chain[:-1]:
+                del ops[n], deps[n]
+        return G.Graph(graph.sources, ops, deps, graph.sink_dependencies)
 
 
 def _fusable(op) -> bool:

@@ -10,17 +10,19 @@ PipelineModel), triggering optimization + execution.
 Typical usage (cf. pipelines/images/mnist/MnistRandomFFT.scala):
 
     featurizer = Pipeline.gather([
-        RandomSignNode.init(d, key) | PaddedFFT() | LinearRectifier(0.0)
-        for key in keys
+        RandomSignNode.init(d, seed) | PaddedFFT() | LinearRectifier(0.0)
+        for seed in seeds
     ])
     predictor = (featurizer
-                 .and_then(LinearMapEstimator(lam), train_x, train_labels)
+                 .and_then(BlockLeastSquaresEstimator(2048, 1, lam),
+                           train_x, train_labels)
                  .and_then(MaxClassifier()))
     test_pred = predictor(test_x).get()
 """
 
 from __future__ import annotations
 
+import itertools
 import os
 import pickle
 from typing import List, Optional, Sequence, Union
@@ -180,20 +182,33 @@ class Pipeline(Chainable):
         branches = [Pipeline.of(b) for b in branches]
         if not branches:
             raise ValueError("gather of zero branches")
-        g = G.Graph()
-        g, src = g.add_source()
-        outs = []
+        # one pass over every branch's graph, each id renumbered once (a
+        # union and an edge rewrite per branch made 200 branches quadratic)
+        ids = itertools.count()
+        src = G.SourceId(next(ids))
+        sources, ops, deps, sinks, outs = [src], {}, {}, {}, []
         for b in branches:
-            g, mapping = g.union(b.graph)
-            b_src = mapping[b.source]
-            g = g.replace_dependency(b_src, src)
-            g = g.remove_source(b_src)
-            out_dep = g.sink_dependencies[mapping[b.sink]]
-            g = g.remove_sink(mapping[b.sink])
-            outs.append(out_dep)
-        g, gather_node = g.add_node(G.GatherOperator(), tuple(outs))
-        g, sink = g.add_sink(gather_node)
-        return Pipeline(g, src, sink)
+            mapping = {b.source: src}
+
+            def remap(i, mapping=mapping):
+                if i not in mapping:
+                    mapping[i] = type(i)(next(ids))
+                return mapping[i]
+
+            sources += [remap(s) for s in b.graph.sources if s != b.source]
+            for n, op in b.graph.operators.items():
+                ops[remap(n)] = op
+            for n, ds in b.graph.dependencies.items():
+                deps[remap(n)] = tuple(remap(d) for d in ds)
+            for k, d in b.graph.sink_dependencies.items():
+                if k == b.sink:
+                    outs.append(remap(d))
+                else:
+                    sinks[remap(k)] = remap(d)
+        gather_node, sink = G.NodeId(next(ids)), G.SinkId(next(ids))
+        ops[gather_node], deps[gather_node] = G.GatherOperator(), tuple(outs)
+        sinks[sink] = gather_node
+        return Pipeline(G.Graph(tuple(sources), ops, deps, sinks), src, sink)
 
     # ------------------------------------------------------- composition
     def then_pipeline(self, other: "Pipeline") -> "Pipeline":
@@ -1243,16 +1258,12 @@ def _prune_unreachable(
     keep = set(keep_sources)
     keep.add(g.sink_dependencies[sink])
     keep.update(g.ancestors(g.sink_dependencies[sink]))
-    for n in list(g.operators):
-        if n not in keep:
-            g = g.remove_node(n)
-    for s in list(g.sources):
-        if s not in keep:
-            g = g.remove_source(s)
-    for k in list(g.sink_dependencies):
-        if k != sink:
-            g = g.remove_sink(k)
-    return g
+    return G.Graph(
+        tuple(s for s in g.sources if s in keep),
+        {n: op for n, op in g.operators.items() if n in keep},
+        {n: ds for n, ds in g.dependencies.items() if n in keep},
+        {sink: g.sink_dependencies[sink]},
+    )
 
 
 def _is_batchlike(x) -> bool:

@@ -41,7 +41,7 @@ DIM = 16
 def _head_weights(classes, seed):
     rng = np.random.default_rng(seed)
     padded = 1 << (DIM - 1).bit_length()
-    feat_dim = 2 * (padded // 2 + 1) * 2
+    feat_dim = (padded // 2) * 2
     return jnp.asarray(rng.normal(size=(feat_dim, classes)).astype(np.float32))
 
 

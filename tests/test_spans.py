@@ -960,7 +960,8 @@ def test_new_metrics_are_declared_with_their_readers():
         assert harness.load_reader(metric).read is not None
     assert declared["score_upload_host_s"]["layer"] == "host link"
     assert declared["sift_device_us_per_image"]["unit"] == "us"
-    fit_cells = ["imagenet-fv.fit-given-vocab", "timit-rf.fit", "timit-krr.fit", "cifar-rp.fit"]
+    fit_cells = ["imagenet-fv.fit-given-vocab", "timit-rf.fit", "timit-krr.fit", "cifar-rp.fit",
+                 "mnist-fft.fit"]
     for quantity, layer in (("upload_transfer_s", "host link"),
                             ("host_wait_s", "executor / transformer")):
         assert os.path.isfile(os.path.join(layers, quantity + ".py"))

@@ -146,7 +146,7 @@ def _fft_gather_feat(dim: int, branches: int):
         ]
     )
     padded = 1 << (dim - 1).bit_length()
-    return feat, branches * (padded // 2 + 1) * 2
+    return feat, branches * (padded // 2)
 
 
 def build_tenant_models(tenants: int = 3, dim: int = 64, branches: int = 6):
