@@ -7,7 +7,8 @@ compile into one XLA stage.
 
 from __future__ import annotations
 
-from functools import partial
+import math
+from functools import lru_cache, partial
 from typing import Callable, Optional
 
 import jax
@@ -134,6 +135,29 @@ class RandomSignNode(Transformer):
         return x * self.signs
 
 
+#: Widest padded transform that ``PaddedFFT`` computes as a product with
+#: its cosine table; wider inputs take XLA's FFT.  The product's work grows
+#: as ``d * padded / 2``, the FFT's as ``padded * log2(padded)``.  Measured
+#: on a TPU v5e, one branch (sign, scaling, transform, rectifier) over
+#: 12,288 rows, with inputs 0.77 and 1.0 times the padded width: the FFT
+#: takes 3.5x / 3.2x the product's time at 1024 padded points, 2.3x / 1.9x
+#: at 2048, 1.7x / 1.3x at 4096; at 8192 the product takes 1.1x / 1.5x
+#: the FFT's.
+PRODUCT_MAX_PADDED = 4096
+
+
+@lru_cache(maxsize=None)
+def _cosine_table(d: int, padded: int) -> np.ndarray:
+    """``(d, padded // 2)`` float32: ``cos(2 pi (j k mod padded) / padded) /
+    sqrt(padded)``, the real part of the unitary DFT's first half over the
+    ``d`` inputs that are not padding.  Built in float64 with ``j k``
+    reduced in integers, so each entry is float32's nearest."""
+    j = np.arange(d, dtype=np.int64)[:, None]
+    k = np.arange(padded // 2, dtype=np.int64)[None, :]
+    angle = (2.0 * np.pi / padded) * ((j * k) % padded)
+    return (np.cos(angle) / np.sqrt(padded)).astype(np.float32)
+
+
 class PaddedFFT(Transformer):
     """Zero-pad to the next power of two and keep the real parts of the
     first half of the Fourier transform (nodes/stats/PaddedFFT.scala —
@@ -145,6 +169,14 @@ class PaddedFFT(Transformer):
     least-squares solve downstream takes as a rescaled lambda; it keeps
     feature magnitudes at the input's scale for the float32 solvers (the
     f64-everywhere reference didn't need this).
+
+    Up to ``PRODUCT_MAX_PADDED`` padded points the transform is one product
+    of the input with its ``(d, padded // 2)`` cosine table (named scope
+    ``fft.product``): the padding adds nothing to a sum and only the real
+    half is kept, so on the MXU this is one gemm with nothing written
+    between stages.  Wider inputs take XLA's FFT (``fft.xla``).  Both are
+    true float32: the product multiplies at ``Precision.HIGHEST`` with
+    float32 accumulation, and no matmul policy or solver setting lowers it.
     """
 
     def params(self):
@@ -153,9 +185,25 @@ class PaddedFFT(Transformer):
     def apply_batch(self, xs, mask=None):
         d = xs.shape[-1]
         padded = 1 << (d - 1).bit_length()
-        xs = jnp.pad(xs, [(0, 0)] * (xs.ndim - 1) + [(0, padded - d)])
-        spec = jnp.fft.rfft(xs, axis=-1, norm="ortho")
-        return jnp.real(spec[..., : padded // 2])
+        if padded <= PRODUCT_MAX_PADDED:
+            with jax.named_scope("fft.product"):
+                lead = xs.shape[:-1]
+                rows = xs.reshape(-1, d)
+                if rows.shape[0] == 1:
+                    # one row alone is a matrix-vector product, whose sums
+                    # run in another order on the CPU: as two rows it gets
+                    # the numbers it gets in a batch
+                    rows = jnp.concatenate([rows, rows])
+                out = jnp.matmul(
+                    rows, _cosine_table(d, padded),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32,
+                )
+                return out[: math.prod(lead)].reshape(lead + (padded // 2,))
+        with jax.named_scope("fft.xla"):
+            xs = jnp.pad(xs, [(0, 0)] * (xs.ndim - 1) + [(0, padded - d)])
+            spec = jnp.fft.rfft(xs, axis=-1, norm="ortho")
+            return jnp.real(spec[..., : padded // 2])
 
     def apply_one(self, x):
         return self.apply_batch(x[None])[0]

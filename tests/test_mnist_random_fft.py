@@ -29,17 +29,48 @@ CFG = {"num_pixels": 784, "padded": 1024, "fft_features": 512, "num_ffts": 3,
        "block_size": 512, "num_iter": 1, "num_classes": 10, "lam": 1e-3}
 
 
-@pytest.mark.parametrize("pixels", [10, 100, 784])
+# an input that pads past the widest product: XLA's FFT computes it
+WIDER_THAN_PRODUCT = stats.PRODUCT_MAX_PADDED + 300
+
+
+@pytest.mark.parametrize("pixels", [10, 100, 784, WIDER_THAN_PRODUCT])
 def test_padded_fft_is_the_cosine_product(pixels):
     padded = 1 << (pixels - 1).bit_length()
     x = np.random.default_rng(pixels).normal(size=(6, pixels)).astype(np.float32)
     got = np.asarray(PaddedFFT().apply_batch(jnp.asarray(x)))
     want = x.astype(np.float64) @ np.asarray(reference.cosine_matrix(pixels, padded), np.float64)
     assert got.shape == (6, padded // 2)  # upstream's width: 512 for MNIST's 784
-    # a float32 FFT of sums of up to 784 terms against the float64 product
+    # a float32 transform of sums of up to `pixels` terms against the float64 product
     np.testing.assert_allclose(got, want, atol=2e-5 * np.abs(want).max())
     np.testing.assert_allclose(np.asarray(PaddedFFT().apply_one(jnp.asarray(x[0]))), got[0],
                                atol=1e-6)
+
+
+@pytest.mark.parametrize("pixels", [784, WIDER_THAN_PRODUCT])
+def test_padded_fft_path_and_precision(pixels, monkeypatch):
+    """Up to the crossover the transform is one true-f32 product and no
+    FFT; past it, XLA's FFT.  No matmul policy or solver setting changes
+    the product's program."""
+    from keystone_tpu.utils import precision
+
+    def lowered():
+        x = jax.ShapeDtypeStruct((8, pixels), jnp.float32)
+        return jax.jit(PaddedFFT().apply_batch).lower(x).as_text()
+
+    text = lowered()
+    if pixels > stats.PRODUCT_MAX_PADDED:
+        assert "stablehlo.fft" in text and "dot_general" not in text
+        return
+    dots = [line for line in text.splitlines() if "dot_general" in line]
+    assert len(dots) == 1 and "precision = [HIGHEST, HIGHEST]" in dots[0]
+    assert "stablehlo.fft" not in text
+    with precision.matmul("bf16_apply"), precision.force_bf16_apply():
+        assert precision.matmul_mode() == "bf16_apply"
+        assert lowered() == text
+    with jax.default_matmul_precision("bfloat16"):
+        assert lowered() == text
+    monkeypatch.setattr(precision, "_SOLVER_PRECISION", "default")
+    assert lowered() == text
 
 
 @pytest.mark.parametrize("seed", [0, 7, 2**31 - 1, -3, -(2**31)])
